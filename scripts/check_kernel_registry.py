@@ -3,7 +3,7 @@
 
 The serving-kernel forge (r21) declares every hand-written Pallas
 kernel in ``sntc_tpu.kernels.registry`` — name, owning module,
-fit-guard, twin tolerance, fallback.  Four things must stay in
+fit-guard, twin tolerance, fallback.  Five things must stay in
 lockstep or the kernel tier silently rots:
 
 1. **code → registry**: every module under ``sntc_tpu/`` containing a
@@ -19,7 +19,13 @@ lockstep or the kernel tier silently rots:
    every row must name a registered kernel;
 4. **registry → tests**: every registered kernel name must appear in
    ``tests/test_kernels.py`` — the interpret-mode twin-equality matrix
-   must exercise every kernel on every tier-1 run.
+   must exercise every kernel on every tier-1 run;
+5. **registry → TPU lowering**: every registered kernel must carry a
+   ``smoke_case`` and ``tests/test_kernels.py`` must hold the test
+   that walks them through ``lowering_platforms=("tpu",)`` — the
+   interpreter applies none of Mosaic's block-shape rules, so a kernel
+   without a lowering case can pass tier-1 and still be refused (and
+   silently poisoned onto its twin) on the chip.
 
 Wired as a tier-1 test (``tests/test_kernels.py``), the same
 discipline as ``check_metric_names.py`` / ``check_fault_sites.py``.
@@ -39,6 +45,7 @@ DOC = "docs/PERFORMANCE.md"
 TABLE_BEGIN = "<!-- kernel-forge:begin -->"
 TABLE_END = "<!-- kernel-forge:end -->"
 TESTS = "tests/test_kernels.py"
+LOWERING_TEST = "def test_every_registered_kernel_lowers_for_tpu("
 
 _CALL_RE = re.compile(r"\bpl\.pallas_call\b|\bpallas_call\(")
 
@@ -149,6 +156,16 @@ def check() -> list:
                 f"registered kernel {name!r} never named in {TESTS} — "
                 "every kernel needs an interpret-mode tier-1 test"
             )
+        if kernels[name].smoke_case is None:
+            problems.append(
+                f"registered kernel {name!r} has no smoke_case — "
+                "nothing cross-lowers it for TPU in tier-1"
+            )
+    if tests and LOWERING_TEST not in tests:
+        problems.append(
+            f"{TESTS} lost the TPU cross-lowering walk "
+            f"({LOWERING_TEST.strip('(')})"
+        )
     return problems
 
 

@@ -8,10 +8,10 @@ resolve to one of those names, every registry key must be backed by a
 ``*_AXIS = "<name>"`` constant in the substrate module, and the
 marker-delimited axis table in ``docs/PERFORMANCE.md`` must list
 exactly the registry, both directions.  The check also enforces the
-substrate boundary itself: no module outside ``parallel/mesh.py`` /
-``parallel/compat.py`` may reach for ``shard_map`` or ``pmap``
-directly — sharded dispatch goes through ``map_at``/``map_reduce_at``
-so placement, evidence metrics, and elastic resize stay in one place.
+substrate boundary itself: no module outside ``parallel/mesh.py`` may
+reach for ``shard_map`` or ``pmap`` directly — sharded dispatch goes
+through ``map_at``/``map_reduce_at`` so placement, evidence metrics,
+and elastic resize stay in one place.
 
 Wired as a tier-1 test (``tests/test_mesh.py``) so code, registry, and
 docs cannot diverge silently.  Exit 0 when consistent; exit 1 with a
@@ -27,7 +27,6 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SUBSTRATE = os.path.join("parallel", "mesh.py")
-_COMPAT = os.path.join("parallel", "compat.py")
 
 # axis-name string literals at sharding call sites
 _AXIS_LITERAL_RES = (
@@ -106,7 +105,7 @@ def forbidden_call_sites() -> list:
     offenders = []
     for path in _py_files():
         rel = os.path.relpath(path, os.path.join(REPO, "sntc_tpu"))
-        if rel in (_SUBSTRATE, _COMPAT):
+        if rel == _SUBSTRATE:
             continue
         with open(path) as f:
             text = f.read()

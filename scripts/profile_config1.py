@@ -1,5 +1,5 @@
-"""Stage-by-stage profile of bench config 1 (VERDICT r2 item 6), plus
-the LR-FIT decomposition VERDICT r4 item 3 asked for: shard/upload,
+"""Stage-by-stage profile of bench config 1, plus the LR-FIT
+decomposition: shard/upload,
 summarizer pass, LBFGS optimize program (with iteration counts), and the
 same numbers for sklearn measured in THIS invocation (drift-proof) —
 scaler fit, lbfgs fit, n_iter_.  Per-iteration costs on both sides turn
@@ -81,7 +81,7 @@ def main():
     for row in rec:
         print(json.dumps(row), flush=True)
 
-    # ---- LR-fit decomposition (VERDICT r4 item 3) ----------------------
+    # ---- LR-fit decomposition ------------------------------------------
     # Re-derive the feature frame once, then time the fit's internals:
     # extract, shard/upload, summarizer treeAggregate, LBFGS program.
     import jax.numpy as jnp

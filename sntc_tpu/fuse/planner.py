@@ -379,7 +379,9 @@ class FusedSegment(Transformer):
         # the finalize closure below may run on the delivery thread —
         # capturing here keeps attribution correct across threads
         ledgers = active_ledgers()
-        kreg.begin_trace_capture()  # kernels armed by THIS trace
+        args_dev = self._place_args(args)
+        # kernels armed by THIS trace (none when its rows are sharded)
+        kreg.begin_trace_capture(sharded=args_dev is not args)
         try:
             if fresh:
                 # the DEVICE fault boundary for the fused-program
@@ -393,7 +395,6 @@ class FusedSegment(Transformer):
             up_bytes = sum(a.nbytes for a in args)
             for led in ledgers:
                 led.record_uploads(len(args), up_bytes)
-            args_dev = self._place_args(args)
             with span("fuse.dispatch", args=len(args)):
                 # async dispatch; finalize materializes.  For a fresh
                 # signature THIS call triggers the XLA compile, so the
@@ -425,13 +426,16 @@ class FusedSegment(Transformer):
             kind = classify_device_error(e)
             # the kernel-scope classifier widens to Pallas/Mosaic
             # lowering failures that are not XLA-runtime-shaped (e.g.
-            # pallas forced on a CPU backend); it only matters when
-            # this trace actually armed kernels — poison_traced()
-            # returns 0 otherwise and the strict ladder below rules
+            # a compiled kernel on a CPU backend); it only matters when
+            # this trace actually armed kernels — otherwise the strict
+            # ladder below rules
             if (
                 kreg.classify_kernel_error(e) == "compile_error"
-                and kreg.poison_traced(repr(e))
+                and kreg.traced_kernels()
             ):
+                if kreg.serve_kernels_forced():
+                    raise  # asked for by name: no twin behind its back
+                kreg.poison_traced(repr(e))
                 # a Pallas kernel INSIDE this fused trace failed to
                 # compile: the segment itself is healthy, so poison
                 # exactly those kernel signatures (done above), evict
@@ -455,6 +459,8 @@ class FusedSegment(Transformer):
             self.invocations += 1
             self.uploads += len(args)
         head, live = self._head, self._live_writes
+        if head is not None:
+            inc("sntc_predict_head_dispatch_total", path="device")
         seg_index, sig_repr = self.segment_index, repr(sig[0])
 
         def finalize() -> Frame:

@@ -5,14 +5,18 @@ dispatch (``serve/transform.py``): the frame's columns are padded to
 the bucket by repeating the last row (``Frame.pad_rows``) and a
 ``VALID_COL`` mask marking the real rows is threaded through the
 transform.  This module gives that step a kernel twin:
-:func:`pad_assemble` pads each float column with a one-hot
+:func:`pad_assemble` pads each float32 column with a one-hot
 gather-matmul — ``out[r] = a[min(r, N-1)]`` expressed as
-``onehot(min(row, N-1)) @ a``, exact per element, so the result is
-bitwise identical to the numpy repeat-last-row twin — and assembles the
-bucketed frame with the validity mask attached.
+``onehot(min(row, N-1)) @ a`` under the fp32 contract precision (one
+nonzero term per output element; the default precision would feed the
+MXU bf16-rounded values) — and assembles the bucketed frame with the
+validity mask attached.  The pin against the numpy repeat-last-row twin
+is bitwise in the interpreter and checked on the chip by
+``chip_smoke.py``.
 
-Non-float columns (ints, bools, strings) and anything the
-``pad_fits_pallas`` guard rejects take the numpy twin column-by-column;
+Everything that is not float32 (f64, ints, bools, strings — Mosaic
+carries no f64) and anything the ``pad_fits_pallas`` guard rejects
+takes the numpy twin column-by-column;
 a compile failure poisons exactly this kernel's (shape, dtype, bucket)
 signature through the shared ladder and the batch is served on the
 twin.  Registered as ``pad_assemble`` in ``sntc_tpu.kernels.registry``.
@@ -42,7 +46,7 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def pad_fits_pallas(n_rows: int, n_cols: int, itemsize: int = 8) -> bool:
+def pad_fits_pallas(n_rows: int, n_cols: int, itemsize: int = 4) -> bool:
     """True when one output row-block's working set — the gather
     one-hot against the whole (padded) input plus the input and output
     blocks — fits the VMEM budget.  Serve buckets are small (the
@@ -61,7 +65,8 @@ def _pad_kernel(x_ref, o_ref, *, bb, n_in, np_in):
     cols = jax.lax.broadcasted_iota(jnp.int32, (bb, np_in), 1)
     onehot = (cols == src).astype(x_ref.dtype)
     o_ref[...] = jnp.dot(
-        onehot, x_ref[...], preferred_element_type=x_ref.dtype
+        onehot, x_ref[...], preferred_element_type=x_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -102,30 +107,22 @@ def pad_assemble(frame, target: int, valid: np.ndarray):
     ``VALID_COL`` mask — the kernel-tier twin of
     ``frame.pad_rows(target).with_column(VALID_COL, valid)``.
 
-    Float columns route through :func:`pad_rows_pallas` behind the
+    Float32 columns route through :func:`pad_rows_pallas` behind the
     shared registry ladder (guard reject / kernels-off / poisoned →
     numpy twin, counted); everything else pads on the host."""
     from sntc_tpu.core.frame import Frame
     from sntc_tpu.serve.transform import VALID_COL
 
-    import jax
-
     n = frame.num_rows
     cols = {}
-    # f64 columns may only ride the kernel when jax carries f64
-    # natively — without jax_enable_x64 the upload would downcast and
-    # break the bitwise contract (same gate as fuse.registry's F64
-    # read policy)
-    f64_ok = bool(jax.config.jax_enable_x64)
     for name in frame.columns:
         a = frame[name]
+        # the gather is a matmul: one Inf/NaN in a column would turn
+        # every padded row of it into NaN (0 * inf), so such columns
+        # pad on the host
         if (
-            (
-                a.dtype == np.float32
-                or (a.dtype == np.float64 and f64_ok)
-            )
-            and a.ndim in (1, 2)
-            and n > 0
+            a.dtype == np.float32 and a.ndim in (1, 2) and n > 0
+            and np.isfinite(a).all()
         ):
             a2 = a if a.ndim == 2 else a[:, None]
             padded = serve_kernel_call(
@@ -154,13 +151,26 @@ def pad_assemble(frame, target: int, valid: np.ndarray):
     return Frame._wrap(cols, int(target))
 
 
+def _smoke_case(rows: int):
+    """A ragged 78-feature micro-batch padded up to its bucket."""
+    rng = np.random.default_rng(0)
+    n = rows - rows // 4
+    return (
+        functools.partial(pad_rows_pallas, target=rows),
+        lambda a: _pad_column_np(np.asarray(a), rows),
+        (rng.normal(size=(n, 78)).astype(np.float32),),
+        0.0,
+    )
+
+
 register_kernel(
     KernelSpec(
         name="pad_assemble",
         module="sntc_tpu/kernels/assemble.py",
         guard_name="pad_fits_pallas",
         guard=pad_fits_pallas,
-        tolerance="bitwise (exact one-hot gather)",
+        tolerance="bitwise (one-hot gather, fp32 contract), float32 only",
         fallback="numpy Frame.pad_rows twin, column-by-column",
+        smoke_case=_smoke_case,
     )
 )

@@ -23,9 +23,13 @@ Selection and survival are shared, not per-kernel ad hoc:
   kernel calls: a fresh (kernel, signature) crosses the
   ``kernel.compile`` fault boundary; a compile failure — injected or
   genuine — poisons exactly that signature onto the XLA twin path and
-  serves the batch there, so a kernel that cannot compile NEVER
-  surfaces an error to the serving engine (zero quarantines, zero
-  tenant strikes; the r18 platform-fault contract).  Under an active
+  serves the batch there, so under ``auto`` a kernel that cannot
+  compile never surfaces an error to the serving engine (zero
+  quarantines, zero tenant strikes; the r18 platform-fault contract).
+  The poison is counted (``sntc_kernel_poisoned_signatures``,
+  ``sntc_kernel_fallback_total{reason=compile_error}``) and
+  ``chip_smoke.py`` fails on it; with ``SNTC_SERVE_KERNELS=pallas``
+  asked for by name the failure raises instead.  Under an active
   trace (a kernel embedded in a fused program) the decision is made at
   trace time and the in-flight kernel signatures are logged so
   ``FusedSegment.transform_async`` can poison them and recompile the
@@ -48,6 +52,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 #: appears outside a registered kernel's module (or a registered
 #: kernel's module has no Pallas call site)
 _SERVE_ENV = "SNTC_SERVE_KERNELS"
+#: spellings of the env value that ask for the compiled kernels by name
+_FORCED = ("pallas", "on", "1", "true")
 
 
 @dataclass(frozen=True)
@@ -69,6 +75,13 @@ class KernelSpec:
     #: optional kernel-specific resolver (the tree_hist historical
     #: semantics); None = the shared serve-tier resolution
     resolver: Optional[Callable[..., str]] = None
+    #: ``smoke_case(rows) -> (kernel_fn, twin_fn, args, rtol)``: the
+    #: kernel (``kernel_fn(*args, interpret=...)``) and its twin
+    #: (``twin_fn(*args)``) over the same seeded inputs at the widths
+    #: ``chip_smoke.py`` serves.  Tier-1 cross-lowers ``kernel_fn`` for
+    #: TPU from it (tests/test_kernels.py); the smoke runs both on the
+    #: chip and compares at ``rtol`` (0 = bitwise).
+    smoke_case: Optional[Callable[[int], tuple]] = None
 
 
 _KERNELS: Dict[str, KernelSpec] = {}
@@ -130,11 +143,18 @@ def resolve_serve_kernels() -> str:
         return "off"
     if raw == "interpret":
         return "interpret"
-    if raw in ("pallas", "on", "1", "true"):
+    if raw in _FORCED:
         return "pallas"
     import jax
 
     return "pallas" if jax.default_backend() == "tpu" else "off"
+
+
+def serve_kernels_forced() -> bool:
+    """True when ``SNTC_SERVE_KERNELS`` names ``pallas`` outright: the
+    caller asked for the compiled kernels, so one that cannot compile
+    is an error to raise, not a signature to poison onto its twin."""
+    return os.environ.get(_SERVE_ENV, "auto").strip().lower() in _FORCED
 
 
 def resolve_impl(name: str, **guard_kwargs) -> str:
@@ -229,10 +249,18 @@ def _under_trace(args) -> bool:
     return any(isinstance(a, jax.core.Tracer) for a in args)
 
 
-def begin_trace_capture() -> None:
+def begin_trace_capture(sharded: bool = False) -> None:
     """Planner hook: start logging kernels armed inside the fused
-    trace about to run on this thread."""
+    trace about to run on this thread.  ``sharded`` says the dispatch
+    placed its rows over a serve mesh: Mosaic kernels cannot be
+    partitioned by GSPMD (a ``pallas_call`` must sit inside a
+    per-shard map — ``parallel.mesh.map_at``), and the kernel tier is
+    single-device, so kernels
+    in such a trace take their twins — counted ``reason="mesh"``, a
+    declared path rather than a compile failure for the poison ladder
+    to absorb."""
     _trace_log.entries = []
+    _trace_log.sharded = bool(sharded)
 
 
 def traced_kernels() -> List[Tuple[str, Any]]:
@@ -270,12 +298,13 @@ def classify_kernel_error(exc: Optional[BaseException]) -> Optional[str]:
     Pallas/Mosaic lowering failure is a compile error even when it is
     not XLA-runtime-shaped — e.g. the CPU backend raises a plain
     ``ValueError("Only interpret mode is supported on CPU backend.")``
-    when ``SNTC_SERVE_KERNELS=pallas`` is forced off-TPU.  Such a
-    failure must poison the signature and serve the twin, never strike
-    the tenant.  The strict classifier keeps its shape rules for every
-    other scope (a user ``ValueError`` mentioning "pallas" outside the
-    kernel tier must never flip serving paths), which is why this
-    widening lives here and not in ``resilience.device``."""
+    for a compiled kernel off-TPU.  Such a failure poisons the
+    signature and serves the twin (or raises when the kernels were
+    forced), never strikes the tenant.  The strict classifier keeps its
+    shape rules for every other scope (a user ``ValueError`` mentioning
+    "pallas" outside the kernel tier must never flip serving paths),
+    which is why this widening lives here and not in
+    ``resilience.device``."""
     from sntc_tpu.resilience.device import classify_device_error
 
     kind = classify_device_error(exc)
@@ -307,11 +336,12 @@ def kernel_dispatch(
     Host-level calls get the full try/poison/fallback arc: a compile
     failure (injected at ``kernel.compile`` or genuine) poisons exactly
     (kernel, signature) and serves THIS call on the twin — nothing
-    escapes to the engine's strike ladder.  Calls under an active jit
-    trace decide at trace time and log the armed signature for the
-    planner's compile-failure handler; OOM/device-lost errors re-raise
-    (they belong to the predictor's r18 response ladder, not the
-    kernel tier)."""
+    escapes to the engine's strike ladder — unless the kernels were
+    forced (:func:`serve_kernels_forced`), where it raises.  Calls
+    under an active jit trace decide at trace time and log the armed
+    signature for the planner's compile-failure handler;
+    OOM/device-lost errors re-raise (they belong to the predictor's r18
+    response ladder, not the kernel tier)."""
     from sntc_tpu.obs.metrics import inc
     from sntc_tpu.resilience.faults import fault_point
 
@@ -337,7 +367,7 @@ def kernel_dispatch(
         out = kernel_fn(impl)
     except Exception as e:
         kind = classify_kernel_error(e)
-        if kind != "compile_error" or traced:
+        if kind != "compile_error" or traced or serve_kernels_forced():
             raise
         poison(name, signature, repr(e))
         inc(
@@ -368,8 +398,13 @@ def serve_kernel_call(
         for a in args
     ) + tuple(static)
     if _under_trace(args):
+        from sntc_tpu.obs.metrics import inc
+
         impl = resolve_impl(name, **(guard_kwargs or {}))
         if impl not in ("pallas", "interpret") or poisoned(name, sig):
+            return twin_fn()
+        if getattr(_trace_log, "sharded", False):
+            inc("sntc_kernel_fallback_total", kernel=name, reason="mesh")
             return twin_fn()
         _note_trace(name, sig)
         with _lock:
@@ -379,8 +414,6 @@ def serve_kernel_call(
             from sntc_tpu.resilience.faults import fault_point
 
             fault_point("kernel.compile")
-        from sntc_tpu.obs.metrics import inc
-
         inc("sntc_kernel_dispatch_total", kernel=name, impl=impl)
         return kernel_fn(impl)
     return kernel_dispatch(

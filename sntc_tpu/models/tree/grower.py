@@ -680,9 +680,8 @@ def grow_forest(
 
     ``hist_impl``: "pallas" (MXU one-hot matmul kernel; requires ``mesh``)
     or "segment" (XLA scatter-add).  Default: pallas on TPU, segment
-    elsewhere — profiled on a real v5e chip (RF 20×d5, 200k×78 rows, warm):
-    pallas 5.6 s vs segment 15.5 s (2.75×; GBT OvR 13.1 s vs 48.1 s;
-    scatter-adds serialize on TPU, the one-hot contraction rides the MXU).
+    elsewhere (scatter-adds serialize on TPU, the one-hot contraction
+    rides the MXU; pallas-vs-segment on the local v5e is not measured).
     Resolved PER LEVEL: deep levels whose node×bin width would overflow
     the kernel's VMEM budget fall back to segment_sum while shallow levels
     keep the MXU path.  Overridable via the ``SNTC_TREE_HIST`` env var.
@@ -724,9 +723,9 @@ def grow_forest(
     ):
         # on TPU a group whose node×bin width overflows the kernel's
         # VMEM budget would silently fall back to segment_sum — and
-        # scatter-adds SERIALIZE there (profiled 2.75–15× slower), which
-        # costs far more than extra group passes.  Shrink the group until
-        # every level rides the MXU.
+        # scatter-adds SERIALIZE there, which is assumed to cost more
+        # than extra group passes (not measured on the local chip).
+        # Shrink the group until every level rides the MXU.
         while group > 1 and not hist_fits_pallas(group, n_bins):
             group //= 2
     hist_impls = tuple(

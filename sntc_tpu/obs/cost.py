@@ -5,8 +5,8 @@ that stashed XLA's own per-program FLOPs/bytes estimate next to each
 compiled signature.  This module promotes that hook into a shared
 plane: :func:`extract` pulls the cost estimate from any compiled jit
 program, and :func:`roofline` combines it with measured wall time and
-the probed peaks (``utils.backend_probe.probed_peaks``) into
-achieved-vs-peak numbers —
+the device's peaks (:func:`probed_peaks`) into achieved-vs-peak
+numbers —
 
     achieved FLOP/s  = flops x invocations / seconds
     MFU              = achieved FLOP/s / peak FLOP/s
@@ -31,6 +31,53 @@ from typing import Any, Dict, Optional
 
 #: the cost_analysis() keys worth keeping (XLA emits dozens)
 _KEYS = ("flops", "bytes accessed", "transcendentals")
+
+#: ``device_kind`` -> (peak FLOP/s, peak memory bytes/s, source) — the
+#: roofline denominators.  The TPU row is the published peak of one
+#: v5e chip (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM; JAX reports the chip as "TPU v5 lite"); the CPU row is
+#: an order-of-magnitude ESTIMATE so CPU figures are honest about their
+#: provenance (``peak_source`` travels with every number).  A device
+#: that is not here is an error, not a default.
+_PEAK_TABLE = {
+    "TPU v5 lite": (1.97e14, 8.19e11, "datasheet"),
+    "cpu": (2.0e11, 5.0e10, "estimate"),
+}
+
+
+def probed_peaks(device_kind: Optional[str] = None) -> dict:
+    """Peak FLOP/s and memory bandwidth for ``device_kind`` (default:
+    ``jax.devices()[0].device_kind``).
+
+    ``SNTC_PEAK_FLOPS`` / ``SNTC_PEAK_BW`` override the static table
+    (measured numbers from a real chip beat any datasheet); overrides
+    flip ``peak_source`` to ``"env"``.  An unknown device raises — a
+    roofline against another chip's peak is worse than none."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in _PEAK_TABLE:
+        raise KeyError(
+            f"no peak FLOP/s / bandwidth row for device_kind "
+            f"{device_kind!r}: add it, with its source, to "
+            "sntc_tpu.obs.cost._PEAK_TABLE"
+        )
+    flops, bw, source = _PEAK_TABLE[device_kind]
+    env_f = os.environ.get("SNTC_PEAK_FLOPS")
+    env_b = os.environ.get("SNTC_PEAK_BW")
+    if env_f:
+        flops = float(env_f)
+        source = "env"
+    if env_b:
+        bw = float(env_b)
+        source = "env"
+    return {
+        "device_kind": device_kind,
+        "flops": flops,
+        "bw": bw,
+        "peak_source": source,
+    }
 
 
 def enabled() -> bool:
@@ -59,7 +106,7 @@ def roofline(
     cost: Optional[Dict[str, float]],
     seconds: float = 0.0,
     invocations: int = 0,
-    platform: Optional[str] = None,
+    device_kind: Optional[str] = None,
 ) -> Optional[Dict[str, Any]]:
     """Achieved-vs-peak accounting for one compiled program.
 
@@ -70,9 +117,7 @@ def roofline(
     fields appear once there is a nonzero measurement."""
     if not cost:
         return None
-    from sntc_tpu.utils.backend_probe import probed_peaks
-
-    peaks = probed_peaks(platform)
+    peaks = probed_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     out: Dict[str, Any] = {
@@ -82,7 +127,7 @@ def roofline(
         "peak_flops": peaks["flops"],
         "peak_bw": peaks["bw"],
         "peak_source": peaks["peak_source"],
-        "platform": peaks["platform"],
+        "device_kind": peaks["device_kind"],
         "invocations": int(invocations),
         "seconds": float(seconds),
     }

@@ -202,6 +202,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         type=COUNTER, labels=(),
         help="Wasted rows shape-bucket padding cost.",
     ),
+    "sntc_predict_head_dispatch_total": dict(
+        type=COUNTER, labels=("path",),
+        help="Classifier-head predicts by where they ran: path=device "
+        "(a jitted program, fused into a segment or the head's own) or "
+        "path=host (the numpy predict at or below "
+        "SNTC_SERVE_HOST_ROWS, and every eager fallback of a head "
+        "that has one).  chip_smoke.py asserts every served batch "
+        "took path=device.",
+    ),
     "sntc_fuse_compile_events_total": dict(
         type=COUNTER, labels=(),
         help="Distinct input signatures compiled across FusedSegments.",
@@ -447,7 +456,8 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         type=COUNTER, labels=("kernel", "reason"),
         help="Kernel-tier calls served on the lowered-jnp/numpy twin "
         "path, by reason (off / guard / poisoned / compile_error / "
-        "segment).",
+        "segment / mesh — a dispatch sharded over the serve mesh: the "
+        "kernel tier is single-device).",
     ),
     "sntc_kernel_poisoned_signatures": dict(
         type=GAUGE, labels=(),

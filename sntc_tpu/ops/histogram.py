@@ -49,9 +49,8 @@ def binned_contingency_onehot(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """MXU path for :func:`binned_contingency` — the pallas level-histogram
-    kernel with a single "node" (profiled on a real v5e chip: the
-    segment_sum form scatter-adds 200k×78 elements and takes ~59 s; this
-    one-hot contraction takes well under a second)."""
+    kernel with a single "node" (the segment_sum form scatter-adds
+    N×F elements, which serialize on TPU)."""
     from sntc_tpu.ops.pallas_histogram import level_histogram_pallas
 
     yoh = jax.nn.one_hot(y, n_classes, dtype=jnp.float32) * w[:, None]

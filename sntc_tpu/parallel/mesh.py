@@ -41,8 +41,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sntc_tpu.parallel.compat import _CHECK_KW, _shard_map
-
 #: Axis-name registry — every mesh axis the framework may declare, with
 #: its role.  ``scripts/check_mesh_axes.py`` enforces that every
 #: ``PartitionSpec``/``psum`` axis literal in ``sntc_tpu/`` names a key
@@ -61,6 +59,18 @@ MESH_AXES = {
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+
+
+def device_report() -> dict:
+    """The devices this process runs on, as JAX reports them.  Every
+    result line the CLI and the bench print carries these fields, so a
+    number from a CPU run can never pass for a chip's."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def default_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -162,25 +172,6 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
 # ---------------------------------------------------------------------------
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the modern signature on any supported jax.
-
-    On legacy jax the replication check is DISABLED outright: the old
-    ``check_rep`` machinery has no rule for ``while`` (every
-    ``lax.while_loop``/``scan`` body trips ``NotImplementedError``), and
-    the check is advisory — out-spec correctness here is guaranteed by
-    the psum-before-return convention of every call site, which the
-    modern ``check_vma`` validates where available."""
-    check = check_vma if _CHECK_KW == "check_vma" else False
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check},
-    )
-
-
 def map_at(
     mesh: Mesh,
     fn: Callable,
@@ -200,7 +191,7 @@ def map_at(
     bare mapped callable for call sites already inside a traced context
     or that rebuild per call (the tree grower's per-level histogram).
     """
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=check_vma,
     )

@@ -31,9 +31,10 @@ module is the missing fault domain:
     failures → the whole predictor flips **HOST_DEGRADED**: every
     dispatch takes the host path, the model component reports DEGRADED,
     the ``sntc_device_state`` gauge flips to 1, and a probe-gated
-    recovery tick re-runs the backend probe OFF the hot path until the
-    device answers again — then serving returns to the device with the
-    compile ledger intact (no churn on re-entry).
+    recovery tick dispatches a tiny program (:func:`probe_device`) OFF
+    the hot path until the device answers again — then serving returns
+    to the device with the compile ledger intact (no churn on
+    re-entry).
 
   Device-attributed errors are PLATFORM faults: the serving engine
   routes them here instead of into the per-batch poison machinery, so
@@ -196,6 +197,25 @@ def _metrics():
     return metrics
 
 
+def probe_device() -> bool:
+    """The recovery probe: one tiny dispatch on the default device, in
+    THIS process.  A chip belongs to one process at a time — the
+    degraded server still holds it, so a child ``python -c "import jax;
+    jax.devices()"`` could never succeed on a local chip; the only
+    process that can ask the device anything is this one.  Called from
+    the domain's background thread, so a dispatch that hangs never
+    wedges serving; any error is a still-dead verdict (the caller
+    catches).  ``probe.init`` is the fault-injection site."""
+    import jax
+    import jax.numpy as jnp
+
+    from sntc_tpu.resilience.faults import fault_point
+
+    fault_point("probe.init")
+    jax.block_until_ready(jnp.zeros((8, 128), jnp.float32) + 1.0)
+    return True
+
+
 class DeviceFaultDomain:
     """The compute-plane survival state machine (module docstring).
 
@@ -205,12 +225,12 @@ class DeviceFaultDomain:
     once, not once per tenant.  Thread-safe: predictors dispatch from
     engine AND delivery threads.
 
-    ``probe_fn`` (default: :func:`sntc_tpu.utils.backend_probe
-    .probe_for_recovery`) decides recovery; with ``probe_async=True``
-    (the default) it runs on a background daemon thread so a hung
-    backend init can never stall the serving loop — the verdict is
-    applied at the next :meth:`tick`.  Tests inject a synchronous
-    ``probe_fn`` and a fake clock for deterministic arcs."""
+    ``probe_fn`` (default: :func:`probe_device`, an in-process
+    dispatch) decides recovery; with ``probe_async=True`` (the default)
+    it runs on a background daemon thread so a hung dispatch can never
+    stall the serving loop — the verdict is applied at the next
+    :meth:`tick`.  Tests inject a synchronous ``probe_fn`` and a fake
+    clock for deterministic arcs."""
 
     def __init__(
         self,
@@ -438,11 +458,7 @@ class DeviceFaultDomain:
         )
 
     def _run_probe(self) -> None:
-        probe = self._probe_fn
-        if probe is None:
-            from sntc_tpu.utils.backend_probe import probe_for_recovery
-
-            probe = probe_for_recovery
+        probe = self._probe_fn or probe_device
         try:
             verdict = bool(probe())
         except Exception:
@@ -457,8 +473,8 @@ class DeviceFaultDomain:
         DEVICE_OK).  While degraded: apply a finished probe's verdict
         (recover on success), and launch the next probe once
         ``probe_interval_s`` has passed — on a background thread by
-        default, so a backend init that HANGS (the exact failure the
-        probe subprocess exists for) never wedges serving."""
+        default, so a probe dispatch that HANGS never wedges
+        serving."""
         if self._state != HOST_DEGRADED:
             return
         with self._lock:

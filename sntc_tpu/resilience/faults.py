@@ -19,7 +19,7 @@ Wired sites:
                         a :func:`fault_data` site taking the DATA kinds
 ``ckpt.save``           ``mlio.save_model`` (before the atomic publish)
 ``ckpt.load``           ``mlio.load_model`` (before manifest verification)
-``probe.init``          ``utils.backend_probe`` backend-liveness attempt
+``probe.init``          ``resilience.device.probe_device`` recovery probe
 ``collective.dispatch`` ``parallel.collectives`` aggregate dispatch
 ``cv.fit``              ``CrossValidator`` per-(fold, grid-point) fit
 ``model.publish``       ``lifecycle.ModelPromoter`` before the candidate
@@ -187,7 +187,7 @@ IO_KINDS = ("enospc", "io_error", "torn_write")
 # ``device_oom`` = RESOURCE_EXHAUSTED allocation failure, the per-batch
 # OOM the dispatch splitter responds to; ``compile_error`` = a failed
 # XLA compilation, the per-signature poisoning trigger;
-# ``device_lost`` = the backend disappeared mid-run (tunnel drop,
+# ``device_lost`` = the backend disappeared mid-run (chip reset,
 # preemption), the HOST_DEGRADED trigger.  Armable at the compute
 # sites ``predict.compile`` / ``fuse.compile`` / ``device.dispatch``.
 DEVICE_KINDS = ("device_oom", "compile_error", "device_lost")

@@ -221,8 +221,8 @@ class ChiSquareTest:
 @lru_cache(maxsize=None)
 def _contingency_count_agg(mesh, n_bins, n_classes, impl, interpret):
     """Same impl dispatch as ``chisq_selector._contingency_agg``: the
-    one-hot MXU kernel on TPU (scatter-adds serialize there — profiled
-    2.75–15× slower), ``segment_sum`` elsewhere."""
+    one-hot MXU kernel on TPU (scatter-adds serialize there),
+    ``segment_sum`` elsewhere."""
 
     def contingency(binned, ys, w):
         if impl == "pallas":

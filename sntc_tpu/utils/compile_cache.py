@@ -6,77 +6,38 @@ JAX's persistent compilation cache closes most of that gap: compiled
 executables are written to a directory keyed by (HLO, flags, platform),
 so the SECOND process's "cold" fit only pays trace + cache lookup.
 
-That key does NOT include the host CPU feature set, and XLA:CPU
-executables are AOT-compiled for the build host's features — serving an
-entry compiled on a differently-featured host is a latent SIGILL (the
-exact "Compile machine features ... vs host machine features" warning
-observed after a mid-round host change, VERDICT r4 weak #4).  The cache
-is therefore partitioned into per-host subdirectories keyed by a digest
-of ``/proc/cpuinfo`` flags: a foreign-host artifact is a clean miss, not
-a potential crash.  (TPU executables don't depend on host features, so
-the partition only costs a one-time recompile after a host change.)
+The directory is placed from OUTSIDE the program: where
+``JAX_COMPILATION_CACHE_DIR`` is set, exactly that directory is used —
+nothing is appended to it and nothing in code rewrites the variable —
+so whoever runs the program decides whether a cache outlives the
+machine.  Unset, the cache lives at a fixed path inside the checkout
+(``<repo>/.jax_cache``, git-ignored), derived from the package's own
+location: the path is part of what makes an entry findable again, so it
+never comes from ``$HOME``, a temp name, a pid or the time.
 
-Opt-out with ``SNTC_NO_COMPILE_CACHE=1``; the base directory defaults to
-``~/.cache/sntc_tpu_xla`` and can be moved with
-``JAX_COMPILATION_CACHE_DIR``.  The per-host partition is applied BENEATH
-whichever base is chosen — including a user-set env dir, since a shared
-pre-warmed cache from a differently-featured host is exactly the SIGILL
-hazard the partition exists for; ``SNTC_CACHE_NO_HOST_KEY=1`` restores
-the single shared dir (pre-r5 behavior).
+Opt-out with ``SNTC_NO_COMPILE_CACHE=1``.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform as _platform
+
+#: the in-checkout default: <repo>/.jax_cache, beside the package
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 
-def host_feature_signature() -> str:
-    """Stable 12-hex digest of this host's CPU feature flags.
-
-    Reads the first ``flags``/``Features`` line of ``/proc/cpuinfo``
-    (x86/arm spellings) and hashes the sorted flag set, so reordering or
-    duplicate processor blocks don't change the signature but any
-    added/removed ISA feature does.  Falls back to the machine arch when
-    cpuinfo is unreadable (non-Linux), which still separates
-    cross-architecture caches.
-    """
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                key = line.split(":", 1)[0].strip().lower()
-                if key in ("flags", "features"):
-                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
-                    return hashlib.sha1(flags.encode()).hexdigest()[:12]
-    except OSError:
-        pass
-    return (_platform.machine() or "unknown-arch")[:12]
-
-
-def resolve_cache_dir(cache_dir: str | None = None) -> str | None:
-    """The directory the cache will use, without touching jax.config.
-
-    None when the cache is disabled.  Separated from
-    :func:`enable_persistent_cache` so tests can check the host-key
-    partition without initializing a backend.
-    """
+def resolve_cache_dir() -> str | None:
+    """The directory the cache uses, without touching jax.config: the
+    ``JAX_COMPILATION_CACHE_DIR`` value verbatim when set, else the
+    fixed in-checkout default; None when the cache is disabled."""
     if os.environ.get("SNTC_NO_COMPILE_CACHE"):
         return None
-    base = (
-        cache_dir
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(os.path.expanduser("~"), ".cache", "sntc_tpu_xla")
-    )
-    if os.environ.get("SNTC_CACHE_NO_HOST_KEY"):
-        return base
-    part = f"host-{host_feature_signature()}"
-    if os.path.basename(os.path.normpath(base)) == part:
-        # base is ALREADY the per-host partition — e.g. the env var was
-        # rewritten by a prior enable_persistent_cache(); nesting a
-        # second host-<sig> level would orphan every cached entry
-        return base
-    return os.path.join(base, part)
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_DIR
 
 
 def fsck_compile_cache(
@@ -175,23 +136,17 @@ def fsck_compile_cache(
     return report
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Turn on JAX's on-disk compilation cache; returns the dir (or None
-    when disabled).  Safe to call more than once and before/after other
-    jax.config updates; must run before the first compilation to help."""
-    resolved = resolve_cache_dir(cache_dir)
+def enable_persistent_cache() -> str | None:
+    """Turn on JAX's on-disk compilation cache at
+    :func:`resolve_cache_dir`; returns the dir (or None when disabled).
+    Safe to call more than once; must run before the first compilation
+    to help.  Never writes ``JAX_COMPILATION_CACHE_DIR``."""
+    resolved = resolve_cache_dir()
     if resolved is None:
         return None
     import jax
 
     os.makedirs(resolved, exist_ok=True)
-    # ADVICE r5: when JAX_COMPILATION_CACHE_DIR is set, jax enables the
-    # cache at the UNpartitioned base at import time — rewrite the env
-    # var to the per-host path so compiles that consult the env (pre- or
-    # post-enable, this process or subprocesses inheriting the env)
-    # can never read/write foreign-host entries from the shared base,
-    # the exact SIGILL hazard the partition exists to prevent
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = resolved
     jax.config.update("jax_compilation_cache_dir", resolved)
     # default min compile time is 1s, which skips most of the small
     # per-stage programs (binning, scaler aggregates) whose compiles

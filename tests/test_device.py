@@ -125,7 +125,7 @@ def test_classifies_real_xla_shapes_and_rejects_others():
         "INTERNAL: during XLA compilation: something broke"
     )) == "compile_error"
     assert classify_device_error(XlaRuntimeError(
-        "UNAVAILABLE: device lost: tunnel dropped"
+        "UNAVAILABLE: device lost: chip reset"
     )) == "device_lost"
     # the chain walks through wrappers
     try:
@@ -650,20 +650,30 @@ def test_delivery_thread_device_error_redispatches_and_commits(
     assert dom.stats()["faults"].get("device_lost", 0) >= 1
 
 
-def test_recovery_probe_bypasses_success_marker(tmp_path, monkeypatch):
-    """probe_for_recovery must run a REAL probe: a success marker
-    written minutes before the device died would otherwise answer the
-    recovery question from stale evidence and flap the domain."""
-    import sntc_tpu.utils.backend_probe as bp
+def test_recovery_probe_runs_in_this_process(monkeypatch):
+    """The default recovery probe is an in-process dispatch: the
+    degraded server still holds the chip, so no second process may be
+    needed to ask whether the device came back."""
+    from sntc_tpu.resilience.device import probe_device
 
-    marker = tmp_path / "probe_ok"
-    marker.write_text("")
-    monkeypatch.setattr(bp, "_ok_marker", lambda: str(marker))
-    # the cached path trusts the fresh marker without a subprocess
-    assert bp.probe_default_backend(0.05) is True
-    # the recovery path bypasses it: a 50 ms budget cannot complete a
-    # real backend-init subprocess, so the honest answer is False
-    assert bp.probe_for_recovery(0.05) is False
+    def no_children(*a, **k):
+        raise AssertionError("the recovery probe spawned a process")
+
+    monkeypatch.setattr(subprocess, "run", no_children)
+    monkeypatch.setattr(subprocess, "Popen", no_children)
+    dom = DeviceFaultDomain(probe_async=False)  # default probe_fn
+    dom.note_fault("device_lost", site="device.dispatch")
+    assert dom.host_degraded
+    dom.tick()
+    assert not dom.host_degraded and dom.stats()["probes"] == 1
+    # probe.init is its fault site: an armed fault is a still-dead
+    # verdict, not an exception in the serving loop
+    dom.note_fault("device_lost", site="device.dispatch")
+    R.arm("probe.init", times=1)
+    dom.tick()
+    assert dom.host_degraded and dom.stats()["probes"] == 2
+    R.clear()
+    assert probe_device() is True
 
 
 def test_consecutive_segment_compile_errors_degrade(mesh8):
@@ -845,8 +855,8 @@ def test_fsck_compile_cache_quarantines_and_serving_recompiles(
     # dir recompiles cleanly (a fresh process with the cache armed)
     fsck_compile_cache(str(cache))
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=str(cache),
-               SNTC_CACHE_NO_HOST_KEY="1")
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    env.pop("SNTC_NO_COMPILE_CACHE", None)  # conftest turns it off
     proc = subprocess.run(
         [sys.executable, "-c",
          "from sntc_tpu.utils.compile_cache import "

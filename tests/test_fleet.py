@@ -831,3 +831,42 @@ def _load_script(name):
 def test_fleet_flags_consistent_cli_coordinator_docs():
     checker = _load_script("check_fleet_flags")
     assert checker.check() == []
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: fleet-serve gives each worker its own chip
+# ---------------------------------------------------------------------------
+
+
+def test_fleet_serve_refuses_more_workers_than_chips(tmp_path, monkeypatch):
+    """A chip serves one process: on a TPU host the coordinator counts
+    the chips (through a child — it must not open a backend itself) and
+    more workers than chips is an error at start, before any spawn."""
+    import subprocess
+
+    import sntc_tpu.app as app
+
+    tenants = tmp_path / "tenants.json"
+    tenants.write_text(json.dumps({"tenants": [
+        {"id": "t0", "model": "m", "watch": "in", "out": "out"},
+    ]}))
+    monkeypatch.setattr(app, "_local_tpu_chips", lambda: 1)
+    monkeypatch.setattr(
+        subprocess, "Popen",
+        lambda *a, **k: pytest.fail("a worker was spawned"),
+    )
+    with pytest.raises(SystemExit, match="2 workers but 1 TPU chip"):
+        app.main([
+            "fleet-serve", "--tenants", str(tenants),
+            "--root", str(tmp_path / "root"), "--workers", "2",
+        ])
+
+
+def test_one_chip_env_and_chip_count_off_tpu():
+    import sntc_tpu.app as app
+
+    env = app._one_chip_env(2)
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    # the CPU backend is not exclusive: nothing to count, nothing to pin
+    assert app._local_tpu_chips() == 0

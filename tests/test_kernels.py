@@ -124,7 +124,7 @@ def test_forest_traversal_matches_twin_f32(T, N, F, S, max_depth):
 
 
 def test_forest_traversal_matches_twin_f64_bitwise():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(7)
         feat, thr, leaf = _random_forest(rng, 3, 4, 5, 3, np.float64)
         X = rng.normal(size=(23, 5))
@@ -268,13 +268,11 @@ def test_host_level_kernel_compile_fault_serves_twin_bitwise():
     )
 
 
-def test_forced_pallas_on_cpu_poisons_and_serves_twin():
-    """``SNTC_SERVE_KERNELS=pallas`` forced on a CPU backend: the
-    Pallas lowering failure is a plain ValueError (not XLA-shaped), yet
-    the kernel-scope classifier treats it as a compile error — the
-    signature poisons and the batch serves bitwise on the twin instead
-    of striking the tenant (the silent-defer regression found driving
-    the serve CLI on a chipless host)."""
+def test_forced_pallas_on_cpu_raises():
+    """``SNTC_SERVE_KERNELS=pallas`` asked for by name on a backend
+    that cannot compile the kernel: the lowering failure raises —
+    nothing is poisoned and no twin is served behind the caller's
+    back (``auto`` keeps the poison ladder)."""
     from sntc_tpu.models.tree.random_forest import RandomForestClassifier
 
     rng = np.random.default_rng(3)
@@ -285,14 +283,52 @@ def test_forced_pallas_on_cpu_poisons_and_serves_twin():
     )
     Xs = rng.normal(size=(21, 5)).astype(np.float32)
     os.environ["SNTC_SERVE_KERNELS"] = "pallas"
-    out = np.asarray(model._predict_all_dev(Xs))
-    st = kernel_stats()
-    assert st["poisoned_signatures"] == 1
-    reason = next(iter(st["poisoned"].values()))
-    assert "interpret mode" in reason.lower()
-    os.environ["SNTC_SERVE_KERNELS"] = "off"
-    ref = np.asarray(model._predict_all_dev(Xs))
-    np.testing.assert_array_equal(out, ref)
+    with pytest.raises(ValueError, match="(?i)interpret mode"):
+        model._predict_all_dev(Xs)
+    assert kernel_stats()["poisoned_signatures"] == 0
+
+
+def test_forced_pallas_in_fused_trace_raises():
+    """Same contract inside a fused segment: the enclosing compile's
+    kernel failure propagates instead of retracing on the twin."""
+    rng = np.random.default_rng(12)
+    pm, serve = _head_pipeline("rf", rng)
+    os.environ["SNTC_SERVE_KERNELS"] = "pallas"
+    bp = BatchPredictor(
+        compile_serving(pm), bucket_rows=16,
+        device_domain=DeviceFaultDomain(),
+    )
+    with pytest.raises(Exception, match="(?i)interpret mode"):
+        bp.predict_frame(serve.slice(0, 13))
+    assert kernel_stats()["poisoned_signatures"] == 0
+
+
+def test_every_registered_kernel_lowers_for_tpu():
+    """Cross-lower every registered kernel through the Pallas TPU
+    lowering at the shapes ``chip_smoke.py`` serves (``smoke_case``):
+    block-shape and layout rules the interpreter never applies — e.g. a
+    ``(1, Mp)`` block over ``[T, Mp]`` for T > 1 — fail here, on CPU,
+    instead of being poisoned onto the twin on the chip."""
+    import functools
+
+    for name, spec in registered_kernels().items():
+        assert spec.smoke_case is not None, name
+        kernel_fn, _twin, args, _rtol = spec.smoke_case(2048)
+        shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+        jax.jit(functools.partial(kernel_fn, interpret=False)).trace(
+            *shapes
+        ).lower(lowering_platforms=("tpu",))
+
+
+def test_pad_assemble_nonfinite_column_pads_on_host():
+    """An Inf in a float32 column must not ride the one-hot matmul
+    (0 * inf = NaN would poison every padded row of the column)."""
+    f = Frame({"x": np.array([1.0, np.inf, 3.0], np.float32)})
+    valid = np.array([True] * 3 + [False] * 5)
+    out = pad_assemble(f, 8, valid)
+    np.testing.assert_array_equal(
+        np.asarray(out["x"]), np.asarray(f.pad_rows(8)["x"])
+    )
 
 
 def test_classify_kernel_error_scope():
@@ -393,15 +429,20 @@ def test_tree_hist_selection_preserved_through_registry(monkeypatch):
 
 
 def test_probed_peaks_sources(monkeypatch):
-    from sntc_tpu.utils.backend_probe import probed_peaks
+    from sntc_tpu.obs.cost import probed_peaks
 
     monkeypatch.delenv("SNTC_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("SNTC_PEAK_BW", raising=False)
     cpu = probed_peaks("cpu")
     assert cpu["peak_source"] == "estimate"  # honest CPU labeling
-    tpu = probed_peaks("tpu")
+    assert probed_peaks() == cpu  # default: this process's device_kind
+    tpu = probed_peaks("TPU v5 lite")
     assert tpu["peak_source"] == "datasheet"
     assert tpu["flops"] > cpu["flops"]
+    # keyed on device_kind: an unknown chip is an error, never the CPU
+    # row (or another chip's) standing in
+    with pytest.raises(KeyError, match="TPU v9"):
+        probed_peaks("TPU v9")
     monkeypatch.setenv("SNTC_PEAK_FLOPS", "1e12")
     over = probed_peaks("cpu")
     assert over["flops"] == 1e12 and over["peak_source"] == "env"
@@ -412,7 +453,7 @@ def test_roofline_math():
 
     r = roofline(
         {"flops": 1e9, "bytes accessed": 1e8},
-        seconds=2.0, invocations=4, platform="cpu",
+        seconds=2.0, invocations=4, device_kind="cpu",
     )
     assert r["arithmetic_intensity"] == pytest.approx(10.0)
     assert r["achieved_flops"] == pytest.approx(2e9)

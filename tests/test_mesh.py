@@ -139,13 +139,11 @@ def _agg_over(size, x):
 
 def test_tree_aggregate_bitwise_f64_across_mesh_sizes():
     """f64 + integer-valued rows: the psum tree is EXACT, so every mesh
-    size must agree bit for bit (jax.experimental.enable_x64 scopes the
-    f64 leg to this test)."""
+    size must agree bit for bit (jax.enable_x64 scopes the f64 leg to
+    this test)."""
     rng = np.random.default_rng(7)
     x = rng.integers(-50, 50, size=(512, 6)).astype(np.float64)
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         results = {s: _agg_over(s, x) for s in MESH_SIZES}
     base = results[1]
     assert base["sum"].dtype == np.float64

@@ -1,4 +1,4 @@
-"""Round-2 parity closures (VERDICT item 8): LR bound-constrained fit,
+"""Round-2 parity closures: LR bound-constrained fit,
 per-class ``thresholds``, multiclass-evaluator ``weightCol``."""
 
 import numpy as np

@@ -752,91 +752,6 @@ def test_env_faults_cv_grid_completes(monkeypatch):
     assert R.recent_events(site="cv.fit", event="retry_success")
 
 
-# ---------------------------------------------------------------------------
-# backend probe retries
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture
-def _probe_env(monkeypatch, tmp_path):
-    import subprocess as sp
-
-    import sntc_tpu.utils.backend_probe as bp
-
-    calls = {"n": 0, "fail_first": 0}
-
-    def fake_run(cmd, timeout=None, **kw):
-        calls["n"] += 1
-        rc = 1 if calls["n"] <= calls["fail_first"] else 0
-        return sp.CompletedProcess(cmd, rc)
-
-    monkeypatch.setattr(bp.subprocess, "run", fake_run)
-    monkeypatch.setattr(bp, "_ok_marker", lambda: str(tmp_path / "marker"))
-    monkeypatch.setattr(
-        bp, "_probe_policy",
-        lambda **kw: RetryPolicy(
-            max_attempts=3, base_delay_s=0.0, jitter=0.0
-        ),
-    )
-    return bp, calls
-
-
-def test_probe_retries_transient_init_failure(_probe_env):
-    bp, calls = _probe_env
-    calls["fail_first"] = 2  # two bad handshakes, third succeeds
-    assert bp.probe_default_backend(timeout_s=5) is True
-    assert calls["n"] == 3
-    assert R.recent_events(site="probe.init", event="retry_success")
-
-
-def test_probe_exhaustion_returns_false_no_marker(_probe_env, tmp_path):
-    bp, calls = _probe_env
-    calls["fail_first"] = 99
-    assert bp.probe_default_backend(timeout_s=5) is False
-    assert calls["n"] == 3  # policy budget, not single-shot
-    assert not os.path.exists(str(tmp_path / "marker"))
-    assert R.recent_events(site="probe.init", event="retry_exhausted")
-
-
-def test_probe_injected_fault_retried(_probe_env):
-    bp, calls = _probe_env
-    R.arm("probe.init", times=1)
-    assert bp.probe_default_backend(timeout_s=5) is True
-    assert R.recent_events(site="probe.init", event="fault_injected")
-
-
-def test_probe_attempts_env_parse(monkeypatch):
-    import sntc_tpu.utils.backend_probe as bp
-
-    monkeypatch.setenv("SNTC_PROBE_ATTEMPTS", "5")
-    assert bp._probe_policy().max_attempts == 5
-    monkeypatch.setenv("SNTC_PROBE_ATTEMPTS", "garbage")
-    assert bp._probe_policy().max_attempts == 2  # fallback, no crash
-
-
-def test_probe_total_budget_split_across_attempts(monkeypatch, tmp_path):
-    """SNTC_PROBE_TIMEOUT_S stays the TOTAL stall bound: per-attempt
-    subprocess timeouts divide it, and the policy deadline caps the
-    whole retry loop — more attempts never multiply the worst case."""
-    import subprocess as sp
-
-    import sntc_tpu.utils.backend_probe as bp
-
-    seen = []
-
-    def fake_run(cmd, timeout=None, **kw):
-        seen.append(timeout)
-        return sp.CompletedProcess(cmd, 1)  # always failing
-
-    monkeypatch.setattr(bp.subprocess, "run", fake_run)
-    monkeypatch.setattr(bp, "_ok_marker", lambda: str(tmp_path / "mk"))
-    monkeypatch.setenv("SNTC_PROBE_ATTEMPTS", "4")
-    assert bp.probe_default_backend(timeout_s=8.0) is False
-    assert all(t == pytest.approx(2.0) for t in seen)  # 8s / 4 attempts
-    policy = bp._probe_policy(deadline_s=8.0)
-    assert policy.deadline_s == 8.0 and policy.max_attempts == 4
-
-
 def test_malformed_faults_env_warns_not_raises(monkeypatch, capsys):
     """A typo'd SNTC_FAULTS must fail loud ONCE on stderr and arm
     nothing — raising from fault_point would be misclassified as a

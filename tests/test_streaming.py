@@ -148,7 +148,7 @@ def test_file_source_and_csv_sink(model, tmp_path, mesh8):
 
 
 # ---------------------------------------------------------------------------
-# pipelined (async-dispatch) engine — VERDICT r1 item 3 / config 5
+# pipelined (async-dispatch) engine — config 5
 # ---------------------------------------------------------------------------
 
 
