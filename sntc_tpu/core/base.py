@@ -16,10 +16,23 @@ stages are plain Python objects whose numeric inner loops dispatch to JAX/XLA.
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, Dict, List, Optional
 
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import NO_DEFAULT, Param, Params
+from sntc_tpu.obs import module_of, span
+
+_MODULE = module_of(__name__)
+#: per-process count of ``Pipeline.fit`` / ``PipelineModel.transform``
+#: calls: the ``run=`` attribute that tells one root span from the next
+_RUNS = itertools.count(1)
+
+
+def _stage_span(op: str, stage, index: int):
+    """``stage.fit`` / ``stage.transform`` around one stage's call."""
+    cls = type(stage)
+    return span(op, stage=cls.__name__, index=index, module=module_of(cls))
 
 
 class PipelineStage(Params):
@@ -157,16 +170,17 @@ class Pipeline(Estimator):
         )
         fitted: List[Transformer] = []
         current = frame
-        for i, stage in enumerate(stages):
-            if isinstance(stage, Estimator):
-                model = stage.fit(current)
+        with span("pipeline.fit", stages=len(stages), run=next(_RUNS),
+                  module=_MODULE):
+            for i, stage in enumerate(stages):
+                model = stage
+                if isinstance(stage, Estimator):
+                    with _stage_span("stage.fit", stage, i):
+                        model = stage.fit(current)
                 fitted.append(model)
                 if i < last_est:
-                    current = model.transform(current)
-            else:
-                fitted.append(stage)
-                if i < last_est:
-                    current = stage.transform(current)
+                    with _stage_span("stage.transform", model, i):
+                        current = model.transform(current)
         return PipelineModel(stages=fitted)
 
 
@@ -182,8 +196,12 @@ class PipelineModel(Model):
 
     def transform(self, frame: Frame) -> Frame:
         current = frame
-        for stage in self.getStages():
-            current = stage.transform(current)
+        stages = self.getStages()
+        with span("pipeline.transform", stages=len(stages),
+                  run=next(_RUNS), module=_MODULE):
+            for i, stage in enumerate(stages):
+                with _stage_span("stage.transform", stage, i):
+                    current = stage.transform(current)
         return current
 
     def transform_async(self, frame: Frame):

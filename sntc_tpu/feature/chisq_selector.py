@@ -25,6 +25,7 @@ from sntc_tpu.core.base import Estimator, Model
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
 from sntc_tpu.feature.selection import select_features_by_mode
+from sntc_tpu.obs import module_of, span
 from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
 from sntc_tpu.ops.histogram import (
     binned_contingency,
@@ -33,6 +34,8 @@ from sntc_tpu.ops.histogram import (
 )
 from sntc_tpu.parallel.collectives import make_tree_aggregate, shard_batch
 from sntc_tpu.parallel.context import get_default_mesh
+
+_MODULE = module_of(__name__)
 
 
 @lru_cache(maxsize=None)
@@ -109,15 +112,16 @@ def chi2_scores(X: np.ndarray, y: np.ndarray, mesh, n_bins: int):
 
     y = np.asarray(y).astype(np.int32)
     n_classes = int(y.max()) + 1 if len(y) else 1
-    edges = quantile_bin_edges(X, max_bins=n_bins)
+    with span("chi2.bin_edges", module=_MODULE):
+        edges = quantile_bin_edges(X, max_bins=n_bins)
     xs, ys, w = shard_batch(mesh, X, y)
     on_tpu = jax.default_backend() == "tpu"
     impl = resolve_hist_impl(1, n_bins, mesh)
-    observed = np.asarray(
-        _contingency_agg(mesh, n_bins, n_classes, impl, not on_tpu)(
-            xs, ys, w, jnp.asarray(edges)
-        )
+    observed = _contingency_agg(mesh, n_bins, n_classes, impl, not on_tpu)(
+        xs, ys, w, jnp.asarray(edges)
     )
+    with span("d2h.fetch", what="contingency", module=_MODULE):
+        observed = np.asarray(observed)
     stats, p_values, _ = chi_square(observed)
     return stats, p_values
 
@@ -129,8 +133,9 @@ class ChiSqSelector(_SelectorParams, Estimator):
 
     def _fit(self, frame: Frame) -> "ChiSqSelectorModel":
         mesh = self._mesh or get_default_mesh()
-        X = frame[self.getFeaturesCol()].astype(np.float32)
-        y = frame[self.getLabelCol()]
+        with span("chi2.extract", module=_MODULE):
+            X = frame[self.getFeaturesCol()].astype(np.float32)
+            y = frame[self.getLabelCol()]
         stats, p_values = chi2_scores(X, y, mesh, self.getMaxBins())
 
         mode = self.getSelectorType()

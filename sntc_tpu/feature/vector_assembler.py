@@ -17,6 +17,9 @@ import numpy as np
 from sntc_tpu.core.base import Transformer
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
+from sntc_tpu.obs import module_of, span
+
+_MODULE = module_of(__name__)
 
 # assembly memo, keyed on the IDENTITY of the input column arrays (Frames
 # are immutable and share column arrays across with_column/rename, so the
@@ -73,29 +76,32 @@ class VectorAssembler(Transformer):
             _ASSEMBLE_CACHE.move_to_end(key)
             X, invalid = hit[1], hit[2]
         else:
-            if cols and all(c.ndim == 1 for c in cols):
-                # all-1-D-columns fast path: ONE C-level stack+cast (4×
-                # the per-column assign loop — this runs per micro-batch
-                # on the serving hot path [B:11]); the transposed view
-                # multiplies/converts downstream at full speed, so no
-                # contiguity copy.  (N, 1) 2-D columns must take the
-                # assign loop: np.array would stack them to 3-D
-                X = np.array(cols, dtype=np.float32).T
-            else:
-                # single allocation, cast-on-assign — no per-column
-                # intermediate copies
-                X = np.empty((frame.num_rows, sum(widths)), np.float32)
-                off = 0
-                for col, w in zip(cols, widths):
-                    if col.ndim == 1:
-                        X[:, off] = col
-                    else:
-                        X[:, off : off + w] = col
-                    off += w
+            with span("assemble.stack", columns=len(cols), module=_MODULE):
+                if cols and all(c.ndim == 1 for c in cols):
+                    # all-1-D-columns fast path: ONE C-level stack+cast
+                    # (4× the per-column assign loop — this runs per
+                    # micro-batch on the serving hot path [B:11]); the
+                    # transposed view multiplies/converts downstream at
+                    # full speed, so no contiguity copy.  (N, 1) 2-D
+                    # columns must take the assign loop: np.array would
+                    # stack them to 3-D
+                    X = np.array(cols, dtype=np.float32).T
+                else:
+                    # single allocation, cast-on-assign — no per-column
+                    # intermediate copies
+                    X = np.empty((frame.num_rows, sum(widths)), np.float32)
+                    off = 0
+                    for col, w in zip(cols, widths):
+                        if col.ndim == 1:
+                            X[:, off] = col
+                        else:
+                            X[:, off : off + w] = col
+                        off += w
 
             invalid = None
             if mode != "keep":
-                bad = ~np.isfinite(X).all(axis=1)
+                with span("assemble.finite_check", module=_MODULE):
+                    bad = ~np.isfinite(X).all(axis=1)
                 if bad.any():
                     if mode == "error":
                         raise ValueError(

@@ -36,7 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sntc_tpu.obs import module_of, span
+from sntc_tpu.parallel.collectives import _put_sharded
 from sntc_tpu.parallel.mesh import map_at, payload_nbytes, record_collective
+
+_MODULE = module_of(__name__)
 
 
 class Forest(NamedTuple):
@@ -149,13 +153,14 @@ def make_bagging_weights(rng, bootstrap: bool, rate: float, T: int, n: int,
     from Spark's exact sampling) shared by both forests."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    if bootstrap:
-        w = rng.poisson(rate, size=(T, n)).astype(np.float32)
-    elif rate < 1.0:
-        w = (rng.random((T, n)) < rate).astype(np.float32)
-    else:
-        w = np.ones((T, n), np.float32)
-    return jax.device_put(
+    with span("rf.bagging", trees=T, rows=n, module=_MODULE):
+        if bootstrap:
+            w = rng.poisson(rate, size=(T, n)).astype(np.float32)
+        elif rate < 1.0:
+            w = (rng.random((T, n)) < rate).astype(np.float32)
+        else:
+            w = np.ones((T, n), np.float32)
+    return _put_sharded(
         w, NamedSharding(mesh, P(None, mesh.axis_names[0]))
     )
 
@@ -808,9 +813,10 @@ def grow_forest(
         keep_hists=keep_hists, mesh=mesh,
         interpret=interpret,
     )
-    feature, threshold, leaf_stats, gain_arr, count_arr = (
-        np.asarray(a) for a in out
-    )
+    with span("d2h.fetch", what="forest", module=_MODULE):
+        feature, threshold, leaf_stats, gain_arr, count_arr = (
+            np.asarray(a) for a in out
+        )
     return Forest(feature, threshold, leaf_stats, max_depth,
                   gain_arr, count_arr)
 

@@ -35,9 +35,12 @@ from sntc_tpu.models.tree.grower import (
     make_bagging_weights,
     resolve_feature_subset_k,
 )
+from sntc_tpu.obs import module_of, span
 from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
 from sntc_tpu.parallel.collectives import shard_batch, shard_weights
 from sntc_tpu.parallel.context import get_default_mesh
+
+_MODULE = module_of(__name__)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -78,14 +81,18 @@ class RandomForestClassifier(_RfParams, ClassifierEstimator):
 
     def _fit(self, frame: Frame) -> "RandomForestClassificationModel":
         mesh = self._mesh or get_default_mesh()
-        X, y, w = self._extract(frame)
-        n, F = X.shape
-        k = int(y.max()) + 1 if n else 2
-        k = max(k, 2)
+        with span("rf.extract", module=_MODULE):
+            X, y, w = self._extract(frame)
+            n, F = X.shape
+            k = int(y.max()) + 1 if n else 2
+            k = max(k, 2)
         T = self.getNumTrees()
         n_bins = self.getMaxBins()
 
-        edges = quantile_bin_edges(X, max_bins=n_bins, seed=self.getSeed())
+        with span("rf.bin_edges", module=_MODULE):
+            edges = quantile_bin_edges(
+                X, max_bins=n_bins, seed=self.getSeed()
+            )
         xs, ys, _ = shard_batch(mesh, X, y.astype(np.int32))
         ws = shard_weights(mesh, w, xs.shape[0])
         axis = mesh.axis_names[0]

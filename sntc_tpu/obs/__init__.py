@@ -11,10 +11,10 @@ This package is the single plane they all land on:
   ``tenant=<id>``) with lock-free-on-read snapshots, Prometheus-text
   and JSONL exposition, and injectable clocks for deterministic tests.
 * :mod:`sntc_tpu.obs.trace` — a span tracer (``obs.span("stage",
-  **attrs)``) recording wall+monotonic intervals on a ring buffer and
-  exporting Chrome-trace/Perfetto JSON; ``jax.profiler`` /
-  compiled-program cost-analysis hooks behind flags so device time can
-  be correlated with the host spans.
+  **attrs)``) with two sinks: a ring buffer of wall+monotonic intervals
+  exported as Chrome-trace/Perfetto JSON, and, while a ``jax.profiler``
+  session runs (``obs.device_trace``), the profiler's own trace, where
+  the spans sit on the device's clock beside its operations.
 * :mod:`sntc_tpu.obs.bridge` — the consolidation glue: an event-stream
   observer folding every structured resilience event (retry, breaker,
   shed, quarantine, drift, health transitions, fault injections) into
@@ -47,6 +47,7 @@ from sntc_tpu.obs.trace import (
     device_trace,
     disable_tracing,
     enable_tracing,
+    module_of,
     span,
     tracer,
     tracing_enabled,
@@ -63,6 +64,7 @@ __all__ = [
     "observe",
     "SpanTracer",
     "span",
+    "module_of",
     "tracer",
     "enable_tracing",
     "disable_tracing",

@@ -194,6 +194,18 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "(each costs at most one XLA compile; legacy view: "
         "BatchPredictor.compile_events).",
     ),
+    "sntc_xla_compiles_total": dict(
+        type=COUNTER, labels=("outcome",),
+        help="Executables XLA built (outcome=compiled) or loaded from the "
+        "persistent compilation cache (outcome=cache_loaded): one per "
+        "miss of a jitted function's own cache, fit path included "
+        "(utils/compile_cache.py listener on jax.monitoring).",
+    ),
+    "sntc_xla_compile_seconds_total": dict(
+        type=COUNTER, labels=(),
+        help="Seconds jax reported for those builds and loads "
+        "(backend_compile_duration).",
+    ),
     "sntc_predict_bucket_hits_total": dict(
         type=COUNTER, labels=(),
         help="Dispatches that reused an already-seen row shape.",

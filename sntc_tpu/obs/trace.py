@@ -1,12 +1,19 @@
-"""Span tracer — host-side stage timing on a ring buffer, exported as
-Chrome-trace/Perfetto JSON.
+"""Span tracer — one call site, two sinks.
 
-``obs.span("stage.name", **attrs)`` wraps a hot-path stage; each closed
-span records (name, monotonic start, duration, wall start, thread id,
-attrs) onto a bounded ring.  Tracing is OFF by default and the disabled
-path is one attribute read + one dict build — the engine's hot paths
-carry the calls permanently without measurable cost (bench config 5
-pins the ≤ 5% overhead budget, docs/OBSERVABILITY.md has the numbers).
+``obs.span("stage.name", **attrs)`` wraps a stage at a layer boundary.
+A span goes to either, both or neither of:
+
+* the **ring**: each closed span records (name, monotonic start,
+  duration, wall start, thread id, attrs, id, parent id) onto a bounded
+  ring, armed by :func:`enable_tracing` (``--trace-out``);
+* the **profiler's trace**: while a ``jax.profiler`` session is running
+  (``TraceAnnotation.is_enabled()`` — the session IS the switch) the
+  span also opens a ``TraceAnnotation`` named ``sntc:<name>`` with the
+  same attributes, so it lands in the ``.xplane.pb`` beside the
+  device's operations, on the device trace's clock.
+
+With neither on, the call returns one shared no-op — the hot paths
+carry the calls permanently (docs/OBSERVABILITY.md has the costs).
 
 :meth:`SpanTracer.export_chrome_trace` writes the ring as Chrome
 ``traceEvents`` JSON, loadable in ``chrome://tracing`` and
@@ -16,9 +23,10 @@ thread's track.  Ring overflow drops the OLDEST spans and counts them
 
 Device-side correlation hooks (both opt-in — they cost real time):
 
-* :func:`device_trace` — a ``jax.profiler`` trace context (XLA op-level
-  timeline for TensorBoard/Perfetto) around any region; the serve CLIs
-  expose it as ``--device-trace DIR``.
+* :func:`device_trace` — a ``jax.profiler`` session (XLA op-level
+  timeline, the program's ``sntc:`` spans on the same clock, no Python
+  tracer) around any region; the CLIs expose it as
+  ``--device-trace DIR``.
 * ``SNTC_OBS_COST_ANALYSIS=1`` — the fusion planner additionally runs
   XLA's compiled-program ``cost_analysis()`` per compiled signature and
   keeps the FLOPs/bytes estimates on the segment
@@ -28,8 +36,10 @@ Device-side correlation hooks (both opt-in — they cost real time):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -55,29 +65,45 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """One live span: records itself on exit (exceptions included —
-    a failing stage's time is exactly the time worth seeing)."""
+    a failing stage's time is exactly the time worth seeing).
+    ``tracer`` is the ring it records onto (or None), ``annotation`` the
+    profiler's ``TraceAnnotation`` it holds open meanwhile (or None)."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_wall0")
+    __slots__ = ("_tracer", "_annotation", "name", "attrs", "_t0",
+                 "_wall0", "_id", "_parent")
 
-    def __init__(self, tracer: "SpanTracer", name: str, attrs):
+    def __init__(self, tracer: "Optional[SpanTracer]", name: str, attrs,
+                 annotation=None):
         self._tracer = tracer
+        self._annotation = annotation
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
-        self._wall0 = self._tracer._wall()
-        self._t0 = self._tracer._clock()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        t = self._tracer
+        if t is not None:
+            self._id, self._parent = t._open()
+            self._wall0 = t._wall()
+            self._t0 = t._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._tracer._record(
-            self.name,
-            self._t0,
-            self._tracer._clock() - self._t0,
-            self._wall0,
-            threading.get_ident(),
-            self.attrs,
-        )
+        t = self._tracer
+        if t is not None:
+            t._record(
+                self.name,
+                self._t0,
+                t._clock() - self._t0,
+                self._wall0,
+                threading.get_ident(),
+                self.attrs,
+                self._id,
+                self._parent,
+            )
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -103,11 +129,25 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.capacity)
         self.dropped = 0
+        self._ids = itertools.count(1)
+        self._live = threading.local()  # .stack: this thread's open ids
 
     def span(self, name: str, **attrs: Any) -> _Span:
         return _Span(self, name, attrs or None)
 
-    def _record(self, name, t0, dur, wall0, tid, attrs) -> None:
+    def _open(self):
+        """``(id, parent)`` of a span opening now on this thread: the
+        parent is the enclosing live span of the same thread."""
+        stack = getattr(self._live, "stack", None)
+        if stack is None:
+            stack = self._live.stack = []
+        sid = next(self._ids)
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        return sid, parent
+
+    def _record(self, name, t0, dur, wall0, tid, attrs, sid, parent) -> None:
+        self._live.stack.pop()
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
@@ -115,7 +155,9 @@ class SpanTracer:
                     inc("sntc_spans_dropped_total")
                 except Exception:
                     pass
-            self._ring.append((name, t0, dur, wall0, tid, attrs))
+            self._ring.append(
+                (name, t0, dur, wall0, tid, attrs, sid, parent)
+            )
 
     def spans(self) -> List[Dict[str, Any]]:
         with self._lock:
@@ -123,9 +165,10 @@ class SpanTracer:
         return [
             {
                 "name": name, "t0": t0, "dur_s": dur, "wall": wall0,
-                "tid": tid, "attrs": attrs or {},
+                "tid": tid, "attrs": attrs or {}, "id": sid,
+                "parent": parent,
             }
-            for name, t0, dur, wall0, tid, attrs in ring
+            for name, t0, dur, wall0, tid, attrs, sid, parent in ring
         ]
 
     def stats(self) -> Dict[str, Any]:
@@ -158,7 +201,7 @@ class SpanTracer:
                 "name": "thread_name", "ph": "M", "pid": pid,
                 "tid": tid, "args": {"name": tname},
             })
-        for name, t0, dur, wall0, tid, attrs in ring:
+        for name, t0, dur, wall0, tid, attrs, sid, parent in ring:
             ev: Dict[str, Any] = {
                 "name": name, "cat": "host", "ph": "X",
                 "ts": round(t0 * 1e6, 3),
@@ -167,6 +210,8 @@ class SpanTracer:
             }
             args = dict(attrs) if attrs else {}
             args["wall_ts"] = wall0
+            args["id"] = sid
+            args["parent"] = parent
             ev["args"] = args
             events.append(ev)
         doc = {
@@ -193,13 +238,37 @@ class SpanTracer:
 _tracer: Optional[SpanTracer] = None
 
 
+def _session_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    running, else None.  ``jax`` is taken from ``sys.modules``: a process
+    that never imported it has no session, and this package imports
+    only the standard library."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
+
+
 def span(name: str, **attrs: Any):
     """``with obs.span("stream.read", batch=3): ...`` — records onto
-    the process tracer when enabled, a shared no-op otherwise."""
+    the process tracer's ring when armed, into the profiler's trace (as
+    ``sntc:<name>``) while a session runs; a shared no-op otherwise."""
     t = _tracer
-    if t is None:
-        return _NULL_SPAN
-    return t.span(name, **attrs)
+    annotation = _session_annotation()
+    if annotation is None:
+        if t is None:
+            return _NULL_SPAN
+        return t.span(name, **attrs)
+    return _Span(t, name, attrs or None, annotation("sntc:" + name, **attrs))
+
+
+def module_of(where) -> str:
+    """The layer a span's ``module=`` attribute names: the second part
+    of a module path (``sntc_tpu.feature.chisq_selector`` ->
+    ``feature``), of a dotted name or of the class or function given."""
+    path = where if isinstance(where, str) else where.__module__
+    return path.split(".")[1] if "." in path else path
 
 
 def tracer() -> Optional[SpanTracer]:
@@ -232,10 +301,13 @@ def disable_tracing() -> Optional[SpanTracer]:
 
 
 class device_trace:
-    """``with device_trace(log_dir):`` — a ``jax.profiler`` capture
-    (XLA op-level Perfetto/TensorBoard timeline) around the block, so
-    device time lines up with the host spans recorded inside it.
-    Expensive; the serve CLIs gate it behind ``--device-trace DIR``."""
+    """``with device_trace(log_dir):`` — a ``jax.profiler`` session
+    around the block: the device's operations and, on the same clock,
+    every ``obs.span`` opened inside it (``sntc:<name>`` host events).
+    The Python tracer is off (it would record every Python call) and
+    the host tracer at level 2, so the ``.xplane.pb`` under
+    ``log_dir/plugins/profile/`` is the kind of trace the benchmark
+    reduces.  The CLIs gate it behind ``--device-trace DIR``."""
 
     def __init__(self, log_dir: str):
         self.log_dir = log_dir
@@ -243,7 +315,10 @@ class device_trace:
     def __enter__(self):
         import jax
 
-        jax.profiler.start_trace(self.log_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
         return self
 
     def __exit__(self, *exc):

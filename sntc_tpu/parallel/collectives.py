@@ -45,6 +45,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sntc_tpu.obs import module_of, span
 from sntc_tpu.parallel.mesh import (
     DATA_AXIS,
     map_reduce_at,
@@ -60,6 +61,9 @@ from sntc_tpu.resilience import (
     with_retries,
 )
 from sntc_tpu.resilience.policy import int_from_env
+
+
+_MODULE = module_of(__name__)
 
 
 def _dispatch_breaker():
@@ -193,11 +197,13 @@ def _put_sharded(arr, sharding):
     here lands in the active transfer ledgers — the r22 fix for
     collective dispatches undercounting the ``sntc_transfer_*``
     series."""
-    if _spans_processes(sharding.mesh):
-        out = _global_shard_put(arr, sharding)
-    else:
-        out = jax.device_put(arr, sharding)
-    _ledger_movement(getattr(arr, "nbytes", 0))
+    nbytes = getattr(arr, "nbytes", 0)
+    with span("h2d.put", bytes=int(nbytes), module=_MODULE):
+        if _spans_processes(sharding.mesh):
+            out = _global_shard_put(arr, sharding)
+        else:
+            out = jax.device_put(arr, sharding)
+    _ledger_movement(nbytes)
     return out
 
 
@@ -231,10 +237,12 @@ def _cached_shard_put(arr, n_pad: int, sharding):
             )
             arr_p = jnp.concatenate([arr, pad_block], axis=0)
         else:
-            pad_block = np.broadcast_to(
-                arr[:1], (n_pad - n,) + arr.shape[1:]
-            )
-            arr_p = np.concatenate([arr, pad_block], axis=0)
+            # a host copy of the whole array for the sake of its last rows
+            with span("h2d.pad", bytes=int(arr.nbytes), module=_MODULE):
+                pad_block = np.broadcast_to(
+                    arr[:1], (n_pad - n,) + arr.shape[1:]
+                )
+                arr_p = np.concatenate([arr, pad_block], axis=0)
     else:
         arr_p = arr
     dev = _put_sharded(arr_p, sharding)

@@ -3,13 +3,11 @@ from sntc_tpu.utils.logging import MetricsLogger
 from sntc_tpu.utils.profiling import (
     TransferLedger,
     ledger_scope,
-    profile_trace,
     transfer_ledger,
 )
 
 __all__ = [
     "MetricsLogger",
-    "profile_trace",
     "TransferLedger",
     "transfer_ledger",
     "ledger_scope",

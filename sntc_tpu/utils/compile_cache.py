@@ -16,11 +16,21 @@ location: the path is part of what makes an entry findable again, so it
 never comes from ``$HOME``, a temp name, a pid or the time.
 
 Opt-out with ``SNTC_NO_COMPILE_CACHE=1``.
+
+:func:`enable_persistent_cache` also installs the process's compile
+listener (cache or no cache): every executable XLA builds or loads from
+this cache — every miss of a jitted function's own in-memory cache —
+counts into ``sntc_xla_compiles_total{outcome}`` /
+``sntc_xla_compile_seconds_total`` and leaves an ``xla.compile`` marker
+span, so a compile inside a measured window shows on the trace's clock.
 """
 
 from __future__ import annotations
 
 import os
+import threading
+
+from sntc_tpu.obs import inc, module_of, span
 
 #: the in-checkout default: <repo>/.jax_cache, beside the package
 _DEFAULT_DIR = os.path.join(
@@ -136,11 +146,62 @@ def fsck_compile_cache(
     return report
 
 
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MODULE = module_of(__name__)
+
+
+class _CompileListener:
+    """``jax.monitoring`` listener pair.  jax reports the backend-compile
+    duration for an executable it built and for one it loaded from the
+    persistent cache alike; a load fires the cache-hit event first, on
+    the compiling thread, which is how the two are told apart."""
+
+    def __init__(self):
+        self._loaded = threading.local()
+
+    def on_event(self, event: str, **kwargs) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self._loaded.flag = True
+
+    def on_duration(self, event: str, duration_secs: float, **kwargs) -> None:
+        if event != _BACKEND_COMPILE_EVENT:
+            return
+        loaded = getattr(self._loaded, "flag", False)
+        self._loaded.flag = False
+        outcome = "cache_loaded" if loaded else "compiled"
+        inc("sntc_xla_compiles_total", outcome=outcome)
+        inc("sntc_xla_compile_seconds_total", duration_secs)
+        # a marker, opened and closed at once where the compile ENDED
+        with span("xla.compile", seconds=duration_secs, outcome=outcome,
+                  program=kwargs.get("fun_name", ""), module=_MODULE):
+            pass
+
+
+_listener: _CompileListener | None = None
+
+
+def _install_compile_listener() -> None:
+    global _listener
+    if _listener is not None:
+        return
+    import jax.monitoring
+
+    _listener = _CompileListener()
+    jax.monitoring.register_event_listener(_listener.on_event)
+    jax.monitoring.register_event_duration_secs_listener(
+        _listener.on_duration
+    )
+
+
 def enable_persistent_cache() -> str | None:
     """Turn on JAX's on-disk compilation cache at
     :func:`resolve_cache_dir`; returns the dir (or None when disabled).
     Safe to call more than once; must run before the first compilation
-    to help.  Never writes ``JAX_COMPILATION_CACHE_DIR``."""
+    to help.  Never writes ``JAX_COMPILATION_CACHE_DIR``.  Installs the
+    compile listener first, so a process without a cache still counts
+    its compiles."""
+    _install_compile_listener()
     resolved = resolve_cache_dir()
     if resolved is None:
         return None

@@ -3,10 +3,11 @@
 Behavioral spec: SURVEY.md §5.1: Spark's per-stage timelines come from the
 listener bus; the TPU-native equivalents are (a) ``jax.profiler`` traces
 viewable in TensorBoard/Perfetto (XLA op-level — far deeper than Spark's
-stage view; see also ``sntc_tpu.obs.trace.device_trace``), (b) the host
-span tracer (``sntc_tpu.obs.span``) for the engine's stage timeline, and
-(c) the transfer ledger below, whose counters also mirror into the
-``sntc_tpu.obs`` metrics registry (``sntc_transfer_*`` series).
+stage view: ``sntc_tpu.obs.device_trace``), (b) the span tracer
+(``sntc_tpu.obs.span``) for the stage timeline, on its own ring and
+inside (a), and (c) the transfer ledger below, whose counters also
+mirror into the ``sntc_tpu.obs`` metrics registry (``sntc_transfer_*``
+series).
 """
 
 from __future__ import annotations
@@ -14,19 +15,6 @@ from __future__ import annotations
 import contextlib
 import threading
 from typing import Dict, Optional
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """``with profile_trace("/tmp/trace"):`` — captures an XLA profiler
-    trace for TensorBoard/Perfetto."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class TransferLedger:

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 import sntc_tpu.resilience as R
+from sntc_tpu import obs
 from sntc_tpu.core.base import Pipeline, Transformer
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.feature import MinMaxScaler, VectorAssembler
@@ -253,6 +254,268 @@ def test_chrome_trace_export_loadable(tmp_path):
         assert "wall_ts" in e["args"]
     assert events[1]["args"]["batch"] == 1  # ring order: inner first
     assert any(e["ph"] == "M" for e in doc["traceEvents"])  # thread names
+
+
+# ---------------------------------------------------------------------------
+# one span API, two sinks: the ring and the profiler's trace (one clock)
+# ---------------------------------------------------------------------------
+
+
+def _profiler_events(log_dir):
+    """``{name: [(start_ns, end_ns, stats, line)]}`` of every host event in
+    the newest ``.xplane.pb`` under ``log_dir``, read with jax alone."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append((
+                    ev.start_ns, ev.start_ns + ev.duration_ns,
+                    {str(k): v for k, v in ev.stats}, line.name,
+                ))
+    return out
+
+
+def test_span_lands_in_profiler_trace_nested_with_stats(tmp_path):
+    assert obs_tracer() is None  # the session alone is the switch
+    with obs.device_trace(str(tmp_path)):
+        with obs_span("outer.stage", rows=12, module="core"):
+            with obs_span("inner.stage", stage="Indexer", index=0):
+                pass
+            with obs_span("marker", seconds=0.5, outcome="compiled"):
+                pass
+    events = _profiler_events(str(tmp_path))
+    (outer,) = events["sntc:outer.stage"]
+    (inner,) = events["sntc:inner.stage"]
+    (marker,) = events["sntc:marker"]
+    assert outer[2] == {"rows": 12, "module": "core"}
+    assert inner[2] == {"stage": "Indexer", "index": 0}
+    assert marker[2] == {"seconds": 0.5, "outcome": "compiled"}
+    # one clock, one thread's line: children inside the parent's interval
+    assert outer[3] == inner[3] == marker[3]
+    assert outer[0] <= inner[0] <= inner[1] <= marker[0] <= marker[1]
+    assert marker[1] <= outer[1]
+    assert "outer.stage" not in events  # only under the sntc: prefix
+
+
+def test_device_trace_writes_no_python_tracer_events(tmp_path):
+    def traced_python_call():
+        return sum(range(10))
+
+    with obs.device_trace(str(tmp_path)):
+        with obs_span("only.this"):
+            traced_python_call()
+    events = _profiler_events(str(tmp_path))
+    assert "sntc:only.this" in events
+    # the Python tracer names its events "$<file>:<line> <function>"
+    assert not [n for n in events if n.startswith("$")]
+
+
+def test_span_is_the_shared_null_with_no_session_and_no_ring(tmp_path):
+    from sntc_tpu.obs import trace as obs_trace
+
+    assert obs_span("nothing.on", k=1) is obs_trace._NULL_SPAN
+    with obs.device_trace(str(tmp_path)):
+        assert obs_span("session.on") is not obs_trace._NULL_SPAN
+    # the session closed: the switch is off again
+    assert obs_span("nothing.on") is obs_trace._NULL_SPAN
+    with obs_span("nothing.on") as live:
+        assert live is None
+
+
+def test_span_goes_to_both_sinks_at_once(tmp_path):
+    t = enable_tracing(capacity=16)
+    with obs.device_trace(str(tmp_path)):
+        with obs_span("both.sinks", batch=3):
+            pass
+    with obs_span("ring.only"):
+        pass
+    assert [s["name"] for s in t.spans()] == ["both.sinks", "ring.only"]
+    assert t.spans()[0]["attrs"] == {"batch": 3}
+    events = _profiler_events(str(tmp_path))
+    assert events["sntc:both.sinks"][0][2] == {"batch": 3}
+    assert "sntc:ring.only" not in events
+
+
+def test_ring_records_carry_id_and_parent(tmp_path):
+    t = SpanTracer(capacity=16)
+    with t.span("root"):
+        with t.span("child.a"):
+            with t.span("leaf"):
+                pass
+        with t.span("child.b"):
+            pass
+    other = {}
+
+    def elsewhere():
+        with t.span("other.thread"):
+            pass
+        other["done"] = True
+
+    with t.span("root.2"):
+        th = threading.Thread(target=elsewhere)
+        th.start()
+        th.join(timeout=30)
+    assert other == {"done": True}
+    by_name = {s["name"]: s for s in t.spans()}
+    ids = [s["id"] for s in t.spans()]
+    assert len(set(ids)) == len(ids) == 6
+    assert by_name["root"]["parent"] is None
+    assert by_name["child.a"]["parent"] == by_name["root"]["id"]
+    assert by_name["leaf"]["parent"] == by_name["child.a"]["id"]
+    assert by_name["child.b"]["parent"] == by_name["root"]["id"]
+    assert by_name["root.2"]["parent"] is None
+    # the parent is the enclosing span of the SAME thread
+    assert by_name["other.thread"]["parent"] is None
+    path = t.export_chrome_trace(str(tmp_path / "trace.json"))
+    with open(path) as f:
+        args = {e["name"]: e["args"] for e in json.load(f)["traceEvents"]
+                if e["ph"] == "X"}
+    assert args["leaf"]["parent"] == by_name["child.a"]["id"]
+    assert args["leaf"]["id"] == by_name["leaf"]["id"]
+    assert args["root"]["parent"] is None
+
+
+@pytest.mark.parametrize("where,module", [
+    ("sntc_tpu.feature.chisq_selector", "feature"),
+    ("sntc_tpu.models.tree.grower", "models"),
+    (VectorAssembler, "feature"),
+    (LogisticRegression, "models"),
+    (Pipeline, "core"),
+    ("toplevel", "toplevel"),
+])
+def test_module_of_is_the_layer_of_the_code(where, module):
+    assert obs.module_of(where) == module
+
+
+def _toy_frame(n=64):
+    rng = np.random.default_rng(1)
+    cols = {
+        f"c{i}": np.abs(rng.normal(3, 2, n)).astype(np.float32)
+        for i in range(3)
+    }
+    cols["label"] = (cols["c0"] > 3.0).astype(np.float64)
+    return Frame(cols)
+
+
+def test_pipeline_fit_emits_stage_spans_in_stage_order(mesh8):
+    pipe = Pipeline(stages=[
+        VectorAssembler(inputCols=["c0", "c1", "c2"], outputCol="features"),
+        MinMaxScaler(inputCol="features", outputCol="scaled"),
+        LogisticRegression(mesh=mesh8, featuresCol="scaled", maxIter=3),
+    ])
+    frame = _toy_frame()
+    t = enable_tracing(capacity=256)
+    model = pipe.fit(frame)
+    spans = t.spans()
+    (root,) = [s for s in spans if s["name"] == "pipeline.fit"]
+    assert root["parent"] is None
+    assert root["attrs"]["stages"] == 3 and root["attrs"]["module"] == "core"
+    stage_spans = sorted(
+        (s for s in spans if s["parent"] == root["id"]), key=lambda s: s["t0"]
+    )
+    assert [
+        (s["name"], s["attrs"]["stage"], s["attrs"]["index"],
+         s["attrs"]["module"])
+        for s in stage_spans
+    ] == [
+        ("stage.transform", "VectorAssembler", 0, "feature"),
+        ("stage.fit", "MinMaxScaler", 1, "feature"),
+        ("stage.transform", "MinMaxScalerModel", 1, "feature"),
+        # the last estimator's model is not run over the training frame
+        ("stage.fit", "LogisticRegression", 2, "models"),
+    ]
+    # every other span of the fit hangs below one of the stage spans
+    below = {s["id"] for s in stage_spans}
+    for s in spans:
+        if s["id"] in below or s is root:
+            continue
+        assert s["parent"] is not None
+        below.add(s["id"])
+    # a second fit is a second root with the next run number
+    t.clear()
+    pipe.fit(frame)
+    (again,) = [s for s in t.spans() if s["name"] == "pipeline.fit"]
+    assert again["attrs"]["run"] > root["attrs"]["run"]
+
+    t.clear()
+    model.transform(frame)
+    spans = t.spans()
+    (root,) = [s for s in spans if s["name"] == "pipeline.transform"]
+    assert [
+        (s["attrs"]["stage"], s["attrs"]["index"])
+        for s in sorted(
+            (s for s in spans if s["parent"] == root["id"]),
+            key=lambda s: s["t0"],
+        )
+    ] == [("VectorAssembler", 0), ("MinMaxScalerModel", 1),
+          ("LogisticRegressionModel", 2)]
+
+
+def test_uploads_cross_the_one_routing_point(mesh8):
+    from sntc_tpu.models.tree.grower import make_bagging_weights
+    from sntc_tpu.parallel.collectives import shard_weights
+
+    t = enable_tracing(capacity=64)
+    before = _get("sntc_transfer_upload_bytes_total")
+    w = make_bagging_weights(np.random.default_rng(0), True, 1.0, 4, 64, mesh8)
+    assert w.shape == (4, 64)
+    shard_weights(mesh8, np.ones(60, np.float32), 64)
+    names = [(s["name"], s["attrs"].get("bytes")) for s in t.spans()]
+    assert names == [
+        ("rf.bagging", None), ("h2d.put", 4 * 64 * 4), ("h2d.put", 64 * 4),
+    ]
+    assert all(s["attrs"]["module"] in ("models", "parallel")
+               for s in t.spans())
+    # the bagging weights used to bypass the ledger (a bare device_put)
+    assert (_get("sntc_transfer_upload_bytes_total") - before
+            == 4 * 64 * 4 + 64 * 4)
+
+
+def test_xla_compiles_counted_on_a_fresh_jit_not_on_a_repeat():
+    import jax
+    import jax.numpy as jnp
+
+    from sntc_tpu.utils.compile_cache import enable_persistent_cache
+
+    # no cache directory under tier-1 (SNTC_NO_COMPILE_CACHE): the listener
+    # is installed before that early return, and installed once
+    assert enable_persistent_cache() is None
+    assert enable_persistent_cache() is None
+
+    def body(x):
+        return x * 2.0 + 1.0
+
+    x = jnp.arange(8.0)
+    x.block_until_ready()
+    t = enable_tracing(capacity=64)
+    n0 = _get("sntc_xla_compiles_total", outcome="compiled")
+    s0 = _get("sntc_xla_compile_seconds_total")
+    fresh = jax.jit(body)
+    fresh(x).block_until_ready()
+    n1 = _get("sntc_xla_compiles_total", outcome="compiled")
+    assert n1 == n0 + 1
+    assert _get("sntc_xla_compile_seconds_total") > s0
+    (marker,) = [s for s in t.spans() if s["name"] == "xla.compile"]
+    assert marker["attrs"]["outcome"] == "compiled"
+    assert marker["attrs"]["seconds"] > 0
+    assert marker["attrs"]["module"] == "utils"
+    assert "body" in marker["attrs"]["program"]
+    fresh(x).block_until_ready()  # the same jitted object: its own cache
+    assert _get("sntc_xla_compiles_total", outcome="compiled") == n1
+    jax.jit(body)(x).block_until_ready()  # jax knows the function itself
+    assert _get("sntc_xla_compiles_total", outcome="compiled") == n1
+    # a new closure over the old function is a new program to jax: what a
+    # stage that builds its jit per fit pays on every fit
+    jax.jit(lambda v: body(v))(x).block_until_ready()
+    assert _get("sntc_xla_compiles_total", outcome="compiled") == n1 + 1
+    assert _get("sntc_xla_compiles_total", outcome="cache_loaded") == 0
 
 
 # ---------------------------------------------------------------------------
