@@ -1,11 +1,12 @@
 """Quantile binning — the ``findSplits`` analog (SURVEY.md §3.2).
 
-Spark's tree path bins continuous features once into uint8 bin ids
+Spark's tree path bins continuous features once into small integer bin ids
 (``TreePoint.convertToTreePoint`` after ``findSplits`` quantile sampling [U])
 so every later pass is integer histogramming.  We keep that design because it
-is exactly what the TPU wants: the 2.8M×78 dataset becomes a device-resident
-uint8 tensor (~220 MB) and every histogram is a ``segment_sum`` feeding the
-MXU-friendly reductions (SURVEY.md §7.1 step 4).
+is exactly what the TPU wants: the feature matrix becomes a device-resident
+``int32 [N, F]`` tensor of bin ids (4 bytes a value: 1.27 GB for 4.06M×78)
+and every histogram is a one-hot matmul or a ``segment_sum`` over it
+(SURVEY.md §7.1 step 4).
 
 Edge computation is sample-based like Spark's ``findSplits`` (which draws
 ``max(maxBins², 10000)`` rows); measured on the bench workload, macro-F1 is
@@ -14,9 +15,10 @@ bin count.  Host (numpy) inputs compute edges on host; device-resident
 columns (``jax.Array`` — e.g. handed down by a fitted scaler, or the 2.8M
 full-scale matrix already in HBM) compute them ON DEVICE with a jitted
 ``jnp.quantile`` — no device→host round trip for the feature matrix.
-``bin_features`` is jitted and runs on device.  Static output shape
-``[F, max_bins - 1]``; duplicate edges from low-cardinality features are
-harmless (empty bins).
+``bin_features`` is jitted and runs on device: a compare-and-count over the
+edges, one elementwise pass with no gather (a per-value binary search lowers
+to serial gathers on the TPU).  Static output shape ``[F, max_bins - 1]``;
+duplicate edges from low-cardinality features are harmless (empty bins).
 """
 
 from __future__ import annotations
@@ -88,12 +90,19 @@ def quantile_bin_edges(
     return np.ascontiguousarray(edges)
 
 
-@partial(jax.jit, static_argnames=())
+@jax.jit
 def bin_features(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     """Map ``X [N, F]`` to bin ids ``[N, F]`` (int32 in [0, B-1]) given
-    ``edges [F, B-1]``: ``bin = #edges <= x`` (right-closed, Spark-style)."""
+    ``edges [F, B-1]``: ``bin = #edges <= x`` (right-closed, Spark-style).
 
-    def one_feature(col: jnp.ndarray, col_edges: jnp.ndarray) -> jnp.ndarray:
-        return jnp.searchsorted(col_edges, col, side="right").astype(jnp.int32)
-
-    return jax.vmap(one_feature, in_axes=(1, 0), out_axes=1)(X, edges)
+    Equal, value for value, to ``searchsorted(edges[f], x, side="right")``
+    on sorted edges — ties go right, NaN sorts last (bin ``B-1``) — but
+    counted with one compare per edge: the loop unrolls into a single
+    elementwise fusion that reads ``X`` once and holds no ``[N, F, B]``
+    temporary.
+    """
+    is_nan = jnp.isnan(X)
+    bins = jnp.zeros(X.shape, jnp.int32)
+    for j in range(edges.shape[1]):
+        bins = bins + ((X >= edges[:, j]) | is_nan).astype(jnp.int32)
+    return bins
