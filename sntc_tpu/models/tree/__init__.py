@@ -1,3 +1,14 @@
+"""Tree estimators over one level-wise binned grower (``grower.py``).
+
+Every family here (forest, decision tree, boosted trees and their
+regressors) bins its features once, grows dense-heap trees level by level
+inside one XLA program (``grower._grow_fused``) and routes rows between
+levels as vector work: a compare-and-select over the level's packed
+decision table and the rows of the transposed bin matrix
+(``grower._route_rows``), never a per-row gather.  Serving walks the fitted
+heaps on raw floats (``grower.forest_leaf_stats``, ``kernels/forest.py``).
+"""
+
 from sntc_tpu.models.tree.random_forest import (
     RandomForestClassifier,
     RandomForestClassificationModel,

@@ -22,7 +22,11 @@ TPU redesign (SURVEY.md §7.2 item 1 — static shapes over dynamic trees):
     same kernel grows RF and GBT trees.
 
 Row routing uses bin ids (``bin <= split_bin`` goes left ⟺ ``x < edges[f,
-split_bin]``); serving traverses on raw floats with the stored thresholds.
+split_bin]``) and is vector work, never a per-row gather (:func:`_route_rows`:
+the level's split decisions packed into one small table, a compare-and-
+select over its nodes, then over the feature rows of the transposed bin
+matrix, each summed away); serving traverses on raw floats with the stored
+thresholds.
 """
 
 from __future__ import annotations
@@ -274,7 +278,6 @@ def node_group_size(T: int, F: int, n_bins: int, S: int) -> int:
 
 
 def _level_core(
-    binned,  # [N, F] int32, row-sharded
     binned_t,  # [F, N] int32, row-sharded on axis 1 (pallas layout)
     row_stats,  # [N, S] f32 shared, or [T, N, S] per-tree (the vectorized
     #            one-vs-rest path: every "tree" is a different binary
@@ -303,10 +306,11 @@ def _level_core(
     """One level's histogram + split evaluation + (optional) row routing,
     with the node axis evaluated in memory-bounded groups of ``group``
     nodes (Spark's maxMemoryInMB node-group analog; resolved ONCE in
-    :func:`grow_forest` so it participates in the jit cache key).  Traced
+    :func:`grow_forest` so it participates in the jit cache key).  Rows
+    are routed over the WHOLE level's decision tables after the groups
+    are stacked (:func:`_route_rows`), whatever the grouping.  Traced
     inside :func:`_grow_fused`'s unrolled level loop."""
-    n, F = binned.shape
-    S = row_stats.shape[-1]
+    F = binned_t.shape[0]
     T = w_trees.shape[0]
 
     # feature subsetting drawn ONCE for the level (tiny [T, nodes, F]),
@@ -319,7 +323,7 @@ def _level_core(
 
     if n_nodes <= group:
         out = _eval_node_group(
-            binned, binned_t, row_stats, row_label, row_weight,
+            binned_t, row_stats, row_label, row_weight,
             w_trees, node_idx, fmask, min_instances, parent_hist,
             lo=jnp.int32(0), g=n_nodes, n_bins=n_bins,
             impurity=impurity, hist_impl=hist_impl, mesh=mesh,
@@ -336,7 +340,7 @@ def _level_core(
 
             def one(lo_t):
                 return _eval_node_group(
-                    binned, binned_t, row_stats, row_label, row_weight,
+                    binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, None, min_instances, parent_hist,
                     lo=lo_t, g=group, n_bins=n_bins, impurity=impurity,
                     hist_impl=hist_impl, mesh=mesh, interpret=interpret,
@@ -350,7 +354,7 @@ def _level_core(
 
             def one(a):
                 return _eval_node_group(
-                    binned, binned_t, row_stats, row_label, row_weight,
+                    binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, a[1], min_instances, parent_hist,
                     lo=a[0], g=group, n_bins=n_bins, impurity=impurity,
                     hist_impl=hist_impl, mesh=mesh, interpret=interpret,
@@ -376,18 +380,9 @@ def _level_core(
 
     # ---- route rows to children (skipped at the last level) ----------------
     if route:
-        idx = jnp.where(node_idx >= 0, node_idx, 0)  # [T, N]
-        splits = jnp.take_along_axis(do_split, idx, axis=1)  # [T, N]
-        feats = jnp.take_along_axis(best_feat, idx, axis=1)  # [T, N]
-        bins_thr = jnp.take_along_axis(best_bin, idx, axis=1)  # [T, N]
-        row_bins = jax.vmap(
-            lambda f_t: jnp.take_along_axis(binned, f_t[:, None], axis=1)[:, 0]
-        )(feats)  # [T, N]
-        go_right = (row_bins > bins_thr).astype(jnp.int32)
-        child = 2 * idx + go_right
-        new_node_idx = jnp.where(
-            (node_idx >= 0) & splits, child, -1
-        ).astype(jnp.int32)
+        new_node_idx = _route_rows(
+            binned_t, node_idx, best_feat, best_bin, do_split
+        )
     else:
         new_node_idx = node_idx
 
@@ -408,8 +403,47 @@ def _level_core(
     return res
 
 
+def _route_rows(binned_t, node_idx, best_feat, best_bin, do_split):
+    """Child node id of every row: ``2 * node + (bin of the node's split
+    feature > the node's split bin)``, or -1 for a dead row (``node_idx ==
+    -1``) and for a row whose node does not split.
+
+    ``binned_t`` [F, N], ``node_idx`` [T, N]; ``best_feat`` / ``best_bin``
+    / ``do_split`` are the level's [T, n_nodes] decision tables
+    (``best_feat`` of a node that does not split may be negative).
+
+    No per-row gather: on the TPU ``take_along_axis`` over [T, N] lowers to
+    a serial ``kCustom`` fusion (12-28 ns an element).  The three tables
+    fold into one int32 per node (``feat << 16 | bin``, -1 = no split);
+    a row picks its node's entry, then its bin id among the ``F`` rows of
+    ``binned_t``, each as a masked sum over the small axis — a compare, a
+    select and a reduce that fuse, with nothing of [T, n_nodes, N] or
+    [T, F, N] ever in memory."""
+    F = binned_t.shape[0]
+    n_nodes = best_feat.shape[1]
+    if F > 1 << 15:
+        raise ValueError(f"row routing packs the feature id in 15 bits, F={F}")
+    # bin ids fit the low 16 bits: the estimators cap maxBins at 256
+    packed = jnp.where(
+        do_split, (best_feat.clip(0) << 16) | best_bin.clip(0), -1
+    )  # [T, n_nodes] int32
+    node_ids = jnp.arange(n_nodes, dtype=jnp.int32)[None, :, None]
+    # +1/-1: a dead row matches no node and sums to 0, i.e. to "no split"
+    sel = jnp.sum(
+        jnp.where(node_idx[:, None, :] == node_ids, packed[:, :, None] + 1, 0),
+        axis=1,
+    ) - 1  # [T, N]
+    feat_ids = jnp.arange(F, dtype=jnp.int32)[None, :, None]
+    row_bins = jnp.sum(
+        jnp.where((sel >> 16)[:, None, :] == feat_ids, binned_t[None], 0),
+        axis=1,
+    )  # [T, N]
+    child = 2 * node_idx + (row_bins > (sel & 0xFFFF)).astype(jnp.int32)
+    return jnp.where(sel >= 0, child, -1)
+
+
 def _eval_node_group(
-    binned, binned_t, row_stats, row_label, row_weight,
+    binned_t, row_stats, row_label, row_weight,
     w_trees, node_idx, fmask, min_instances, parent_hist,
     *,
     lo,  # traced int32 scalar: first node id of the group
@@ -435,7 +469,7 @@ def _eval_node_group(
     group passes on the segment path.  Children of non-split parents
     derive garbage (parent − 0) but are masked by ``exists_lvl`` in
     :func:`_grow_fused` before any heap write, and no row routes there."""
-    n, F = binned.shape
+    F = binned_t.shape[0]
     S = row_stats.shape[-1]
     T = w_trees.shape[0]
 
@@ -445,7 +479,7 @@ def _eval_node_group(
             (node_idx - lo) >> 1, -1,
         )
         h_even = _group_hist(
-            binned, binned_t, row_stats, row_label, row_weight, w_trees,
+            binned_t, row_stats, row_label, row_weight, w_trees,
             ids_even, g_eff=g // 2, n_bins=n_bins, hist_impl=hist_impl,
             mesh=mesh, interpret=interpret,
         )
@@ -471,7 +505,7 @@ def _eval_node_group(
             (node_idx >= lo) & (node_idx < lo + g), node_idx - lo, -1
         )
         hist = _group_hist(
-            binned, binned_t, row_stats, row_label, row_weight, w_trees,
+            binned_t, row_stats, row_label, row_weight, w_trees,
             ids, g_eff=g, n_bins=n_bins, hist_impl=hist_impl, mesh=mesh,
             interpret=interpret,
         )
@@ -483,7 +517,7 @@ def _eval_node_group(
 
 
 def _group_hist(
-    binned, binned_t, row_stats, row_label, row_weight, w_trees,
+    binned_t, row_stats, row_label, row_weight, w_trees,
     node_idx,  # [T, N] int32 GROUP-LOCAL ids in [0, g_eff) (-1 = dead)
     *,
     g_eff: int,
@@ -500,7 +534,7 @@ def _group_hist(
     vector rows, ~6× less scatter traffic; requires
     ``row_stats == one_hot(row_label) * row_weight[:, None]``), and the
     generic vector ``segment_sum``."""
-    n, F = binned.shape
+    F = binned_t.shape[0]
     S = row_stats.shape[-1]
     T = w_trees.shape[0]
     per_tree_stats = row_stats.ndim == 3
@@ -805,7 +839,7 @@ def grow_forest(
         if row_weight is not None:
             row_weight = jnp.where(in_range, row_weight, 0.0)
     out = _grow_fused(
-        binned, binned_t, row_stats, row_label, row_weight, w_trees,
+        binned_t, row_stats, row_label, row_weight, w_trees,
         jnp.asarray(edges), keys,
         jnp.float32(min_instances_per_node), jnp.float32(min_info_gain),
         max_depth=max_depth, n_bins=n_bins, impurity=impurity,
@@ -829,7 +863,7 @@ def grow_forest(
     ),
 )
 def _grow_fused(
-    binned, binned_t, row_stats, row_label, row_weight, w_trees,
+    binned_t, row_stats, row_label, row_weight, w_trees,
     edges_dev, keys,
     min_instances, min_info_gain,
     *, max_depth, n_bins, impurity, subset_k, group, hist_impls,
@@ -840,7 +874,10 @@ def _grow_fused(
     (``2^d`` — no padding waste) and heap updates are static slices.  No
     host round trip per level — the forest leaves the device exactly once
     (SURVEY.md §1 restack: the per-level driver synchronization of Spark's
-    ``while nodeStack`` loop disappears entirely)."""
+    ``while nodeStack`` loop disappears entirely).  Between levels the rows'
+    node ids ``[T, N]`` are rewritten by :func:`_route_rows` (compare-and-
+    select over the level's decision tables and the rows of ``binned_t``;
+    the ``[N, F]`` bin matrix is no operand of this program)."""
     T, n = w_trees.shape
     S = row_stats.shape[-1]
     H = (1 << (max_depth + 1)) - 1
@@ -858,7 +895,7 @@ def _grow_fused(
         n_nodes = 1 << depth
         off = n_nodes - 1
         out = _level_core(
-            binned, binned_t, row_stats, row_label, row_weight,
+            binned_t, row_stats, row_label, row_weight,
             w_trees, node_idx, keys[depth],
             min_instances, min_info_gain, prev_hist,
             n_nodes=n_nodes, n_bins=n_bins, impurity=impurity,
