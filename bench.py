@@ -4253,25 +4253,25 @@ def bench_mfu(n_rows, mesh):
         node_idx = jnp.asarray(
             rng.integers(0, n_nodes, size=n_loc, dtype=np.int32)
         )
-        stats = jnp.asarray(rng.random((n_loc, S), np.float32))
+        stats_t = jnp.asarray(rng.random((S, n_loc), np.float32))
+        weight = jnp.ones((n_loc,), jnp.float32)
         call = jax.jit(
-            lambda bt, ni, st: level_histogram_pallas(
-                bt, ni, st, n_nodes=n_nodes, n_bins=B
+            lambda bt, ni, st, w: level_histogram_pallas(
+                bt, ni, st, w, n_nodes=n_nodes, n_bins=B
             )
         )
-        call(binned_t, node_idx, stats).block_until_ready()  # compile
+        call(binned_t, node_idx, stats_t, weight).block_until_ready()
         reps = 10
         t0 = time.perf_counter()
         for _ in range(reps):
-            r = call(binned_t, node_idx, stats)
+            r = call(binned_t, node_idx, stats_t, weight)
         r.block_until_ready()
         dt = (time.perf_counter() - t0) / reps
-        # executed dense FLOPs: one-hot [tile, nb_pad]ᵀ @ stats
-        # [tile, s_pad] per feature block — padded widths are what the
-        # MXU really runs
-        nb_pad = -(-max(n_nodes * B + 1, 128) // 128) * 128
+        # executed dense FLOPs: bin one-hot [F * B, tile] against the
+        # node-folded stats [3 terms * nodes * s_pad, tile], contracted
+        # over the rows — padded widths are what the MXU really runs
         s_pad = -(-S // 8) * 8
-        hist_flops = 2.0 * n_loc * nb_pad * s_pad * F
+        hist_flops = 2.0 * n_loc * (F * B) * (3 * n_nodes * s_pad)
         out["hist_kernel_shapes"] = (
             f"N={n_loc} F={F} nodes={n_nodes} bins={B}"
         )

@@ -291,6 +291,9 @@ def _level_core(
     min_info_gain,  # f32 scalar
     parent_hist,  # [T, n_nodes/2, F, B, S] previous level's histograms
     #             (sibling-subtraction path) or None (direct)
+    stats_t,  # [S_pad, N] / [T, S_pad, N] f32: ``row_stats`` with the rows
+    #         along lanes (:func:`_lane_dense_stats`), the pallas kernel's
+    #         operand; None when no level takes the kernel
     *,
     n_nodes: int,
     n_bins: int,
@@ -324,7 +327,7 @@ def _level_core(
     if n_nodes <= group:
         out = _eval_node_group(
             binned_t, row_stats, row_label, row_weight,
-            w_trees, node_idx, fmask, min_instances, parent_hist,
+            w_trees, node_idx, fmask, min_instances, parent_hist, stats_t,
             lo=jnp.int32(0), g=n_nodes, n_bins=n_bins,
             impurity=impurity, hist_impl=hist_impl, mesh=mesh,
             interpret=interpret, keep_hist=keep_hist,
@@ -342,9 +345,9 @@ def _level_core(
                 return _eval_node_group(
                     binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, None, min_instances, parent_hist,
-                    lo=lo_t, g=group, n_bins=n_bins, impurity=impurity,
-                    hist_impl=hist_impl, mesh=mesh, interpret=interpret,
-                    keep_hist=keep_hist,
+                    stats_t, lo=lo_t, g=group, n_bins=n_bins,
+                    impurity=impurity, hist_impl=hist_impl, mesh=mesh,
+                    interpret=interpret, keep_hist=keep_hist,
                 )
         else:
             fmask_g = fmask.reshape(T, n_groups, group, F).transpose(
@@ -356,9 +359,9 @@ def _level_core(
                 return _eval_node_group(
                     binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, a[1], min_instances, parent_hist,
-                    lo=a[0], g=group, n_bins=n_bins, impurity=impurity,
-                    hist_impl=hist_impl, mesh=mesh, interpret=interpret,
-                    keep_hist=keep_hist,
+                    stats_t, lo=a[0], g=group, n_bins=n_bins,
+                    impurity=impurity, hist_impl=hist_impl, mesh=mesh,
+                    interpret=interpret, keep_hist=keep_hist,
                 )
 
         stacked = jax.lax.map(one, args)  # each: [n_groups, T, group, ...]
@@ -444,7 +447,7 @@ def _route_rows(binned_t, node_idx, best_feat, best_bin, do_split):
 
 def _eval_node_group(
     binned_t, row_stats, row_label, row_weight,
-    w_trees, node_idx, fmask, min_instances, parent_hist,
+    w_trees, node_idx, fmask, min_instances, parent_hist, stats_t,
     *,
     lo,  # traced int32 scalar: first node id of the group
     g: int,
@@ -480,8 +483,8 @@ def _eval_node_group(
         )
         h_even = _group_hist(
             binned_t, row_stats, row_label, row_weight, w_trees,
-            ids_even, g_eff=g // 2, n_bins=n_bins, hist_impl=hist_impl,
-            mesh=mesh, interpret=interpret,
+            ids_even, stats_t, g_eff=g // 2, n_bins=n_bins,
+            hist_impl=hist_impl, mesh=mesh, interpret=interpret,
         )
         par = jax.lax.dynamic_slice(
             parent_hist, (0, lo // 2, 0, 0, 0),
@@ -506,8 +509,8 @@ def _eval_node_group(
         )
         hist = _group_hist(
             binned_t, row_stats, row_label, row_weight, w_trees,
-            ids, g_eff=g, n_bins=n_bins, hist_impl=hist_impl, mesh=mesh,
-            interpret=interpret,
+            ids, stats_t, g_eff=g, n_bins=n_bins, hist_impl=hist_impl,
+            mesh=mesh, interpret=interpret,
         )
 
     out = _eval_from_hist(hist, fmask, min_instances, impurity=impurity)
@@ -519,6 +522,7 @@ def _eval_node_group(
 def _group_hist(
     binned_t, row_stats, row_label, row_weight, w_trees,
     node_idx,  # [T, N] int32 GROUP-LOCAL ids in [0, g_eff) (-1 = dead)
+    stats_t,  # [S_pad, N] / [T, S_pad, N] f32 (pallas levels) or None
     *,
     g_eff: int,
     n_bins: int,
@@ -528,10 +532,10 @@ def _group_hist(
 ):
     """Histogram ``[T, g_eff, F, B, S]`` over pre-mapped local node ids.
 
-    Three impls: the pallas MXU one-hot matmul (TPU), the label-fused
-    scalar ``segment_sum`` (classification with shared one-hot stats —
-    scatters N scalars into ``(node·B + bin)·S + label`` instead of N×S
-    vector rows, ~6× less scatter traffic; requires
+    Three impls: the pallas MXU bin-one-hot matmul over ``stats_t`` (TPU),
+    the label-fused scalar ``segment_sum`` (classification with shared
+    one-hot stats — scatters N scalars into ``(node·B + bin)·S + label``
+    instead of N×S vector rows, ~6× less scatter traffic; requires
     ``row_stats == one_hot(row_label) * row_weight[:, None]``), and the
     generic vector ``segment_sum``."""
     F = binned_t.shape[0]
@@ -542,43 +546,44 @@ def _group_hist(
 
     # ---- histogram: [T, nodes, F, B, S] ------------------------------------
     if hist_impl == "pallas":
-        # MXU one-hot matmul kernel per shard, explicit psum over the mesh
-        # (sntc_tpu/ops/pallas_histogram.py)
+        # MXU factored one-hot matmul kernel per shard, explicit psum over
+        # the mesh (sntc_tpu/ops/pallas_histogram.py).  Every operand has
+        # the rows along lanes: the statistics arrive transposed once a
+        # fit, the tree's weight as it lies, and the kernel multiplies
+        # the two on its own tile; dead rows (id -1) match no node
         from jax.sharding import PartitionSpec as P
 
         from sntc_tpu.ops.pallas_histogram import level_histogram_pallas
 
         axis = mesh.axis_names[0]
-        rs_spec = (
-            P(None, axis, None) if per_tree_stats else P(axis, None)
+        st_spec = (
+            P(None, None, axis) if per_tree_stats else P(None, axis)
         )
 
-        def shard_fn(bt, rs, wt, ni):
-            def hist_one(w_t, node_t, rs_t):
-                active = (node_t >= 0).astype(rs_t.dtype)
-                data = rs_t * (w_t * active)[:, None]
+        def shard_fn(bt, st, wt, ni):
+            def hist_one(w_t, node_t, st_t):
                 return level_histogram_pallas(
-                    bt, node_t, data,
+                    bt, node_t, st_t, w_t,
                     n_nodes=n_nodes, n_bins=n_bins, interpret=interpret,
-                )  # [F, nodes*B, S]
+                )[..., :S]  # [F, nodes*B, S]
 
             if per_tree_stats:
-                hs = jax.lax.map(lambda a: hist_one(*a), (wt, ni, rs))
+                hs = jax.lax.map(lambda a: hist_one(*a), (wt, ni, st))
             else:
-                # shared stats stay closure-captured (no [T, n, S]
+                # shared stats stay closure-captured (no [T, S, n]
                 # broadcast materialized per shard)
                 hs = jax.lax.map(
-                    lambda a: hist_one(a[0], a[1], rs), (wt, ni)
+                    lambda a: hist_one(a[0], a[1], st), (wt, ni)
                 )  # [T, F, nodes*B, S]
             return jax.lax.psum(hs, axis)
 
         hists = map_at(
             mesh, shard_fn,
-            in_specs=(P(None, axis), rs_spec, P(None, axis), P(None, axis)),
+            in_specs=(P(None, axis), st_spec, P(None, axis), P(None, axis)),
             out_specs=P(),
             check_vma=False,  # pallas_call outputs carry no vma metadata
             jit=False,  # rebuilt per level; an outer jit would recompile
-        )(binned_t, row_stats, w_trees, node_idx)
+        )(binned_t, stats_t, w_trees, node_idx)
         record_collective(
             "tree.histogram", axis, mesh.shape[axis], payload_nbytes(hists)
         )
@@ -688,6 +693,16 @@ def _eval_from_hist(hist, fmask, min_instances, *, impurity):
         "left_stats": bl,
         "right_stats": br,
     }
+
+
+def _lane_dense_stats(row_stats):
+    """``row_stats`` ``[..., N, S]`` as the pallas kernel takes it:
+    ``[..., S_pad, N]``, rows along lanes and the statistics padded with
+    zero rows to the float32 sublane tile of 8.  (``[N, 15]`` lies tiled
+    to 128 lanes in HBM, eight times its bytes; this lies dense.)"""
+    S = row_stats.shape[-1]
+    pad = [(0, 0)] * (row_stats.ndim - 1) + [(0, -S % 8)]
+    return jnp.swapaxes(jnp.pad(row_stats, pad), -1, -2)
 
 
 @jax.jit
@@ -890,6 +905,12 @@ def _grow_fused(
     node_idx = jnp.zeros((T, n), jnp.int32)
     exists_lvl = jnp.ones((T, 1), bool)  # root exists
 
+    # the kernel's statistics operand, made once a fit and shared by every
+    # level (and, for shared statistics, by every tree)
+    stats_t = (
+        _lane_dense_stats(row_stats) if "pallas" in hist_impls else None
+    )
+
     prev_hist = None
     for depth in range(max_depth):
         n_nodes = 1 << depth
@@ -897,7 +918,7 @@ def _grow_fused(
         out = _level_core(
             binned_t, row_stats, row_label, row_weight,
             w_trees, node_idx, keys[depth],
-            min_instances, min_info_gain, prev_hist,
+            min_instances, min_info_gain, prev_hist, stats_t,
             n_nodes=n_nodes, n_bins=n_bins, impurity=impurity,
             subset_k=subset_k, group=group,
             hist_impl=hist_impls[depth], mesh=mesh,

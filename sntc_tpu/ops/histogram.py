@@ -53,12 +53,17 @@ def binned_contingency_onehot(
     N×F elements, which serialize on TPU)."""
     from sntc_tpu.ops.pallas_histogram import level_histogram_pallas
 
-    yoh = jax.nn.one_hot(y, n_classes, dtype=jnp.float32) * w[:, None]
+    # class indicators with the rows along lanes, a whole sublane tile of
+    # them (the classes past ``n_classes`` match no label); the kernel
+    # multiplies the row weight in on its own tile
+    yoh_t = jax.nn.one_hot(
+        y, -(-n_classes // 8) * 8, dtype=jnp.float32, axis=0
+    )
     node0 = jnp.zeros(y.shape[0], jnp.int32)
     return level_histogram_pallas(
-        binned.T, node0, yoh,
+        binned.T, node0, yoh_t, w,
         n_nodes=1, n_bins=n_bins, interpret=interpret,
-    )  # [F, B, C]
+    )[..., :n_classes]  # [F, B, C]
 
 
 def chi_square(observed: np.ndarray) -> tuple:

@@ -1,24 +1,51 @@
-"""Pallas TPU kernel: per-level tree histogram as MXU one-hot matmuls.
+"""Pallas TPU kernel: per-level tree histogram as MXU matmuls.
 
 The tree grower's hot op (SURVEY.md §3.2/§7.2 item 1) is the
-(node, feature, bin, stat) sufficient-statistics accumulation.  The XLA
-fallback (sntc_tpu/ops/histogram.py + grower) lowers it to scatter-adds,
-which serialize on TPU.  This kernel recasts it as dense matmuls:
+(node, feature, bin, stat) sufficient-statistics accumulation
 
-    for each (feature f, row-block r):
-        ids     = node_idx * B + bin[f]                  # [TILE_N]
-        onehot  = (iota_cols == ids)                     # [TILE_N, NBpad]
-        acc[f] += stats_blockᵀ @ onehot                  # [S, NBpad] on MXU
+    hist[f, node, bin, s] = sum_n [node_n == node] * [bin_n,f == bin]
+                                  * w_n * stats[n, s]
 
-so the accumulation rides the systolic array instead of scatter units.
-The row-block axis is the innermost grid dimension; the output block for
-feature ``f`` is revisited across row-blocks and accumulated in place
-(initialized at r == 0) — the standard Pallas reduction pattern.
+The XLA fallback (sntc_tpu/ops/histogram.py + grower) lowers it to
+scatter-adds, which serialize on TPU.  This kernel writes it as one
+matmul per row tile whose two operands carry one indicator each:
 
-Layouts: ``binned`` arrives transposed ``[F, N]`` so each (f, r) block is
-lane-contiguous; the output is ``[F, S_pad, NB_pad]`` with the large
-node×bin axis last (128-lane aligned).  Stats arrive pre-weighted
-(bagging × user weight × active mask), so padded/dead rows contribute 0.
+    x     = w * stats_t                              # [S_pad, TILE]
+    A_t   = [(node == k) ? term(x) : 0               # [3 * NODES * S_pad,
+             for term in (hi, mid, lo) for k]        #  TILE] bfloat16
+    onehot= [(iota_bins == bins[f]) for f]           # [F_blk * B_pad, TILE]
+    acc  += onehot . A_t^T                           # contract the rows
+
+The node is folded into the statistics once per row tile (it does not
+depend on the feature; dead rows, id -1, match no node and add nothing);
+only the bin is one-hot per feature, as ``binned_t`` lies, ``n_bins``
+wide and built in bfloat16, where 0 and 1 are exact.  ``A_t`` is the
+stationary operand, shared by every feature of the step, and hundreds of
+one-hot rows stream through it.
+
+Precision.  The statistics are float32 and the product is exact to
+float32: ``x`` is split into its three bfloat16 terms, ``hi = bf16(x)``,
+``mid = bf16(x - hi)``, ``lo = bf16(x - hi - mid)`` (8 + 8 + 8 mantissa
+bits, so ``hi + mid + lo == x`` bit for bit for every float32 whose low
+term does not underflow), the terms are stacked as columns of ``A_t`` and
+each is multiplied ONCE, in one default-precision pass with float32
+accumulation; the wrapper adds the three partial histograms in float32.
+That is what the HIGHEST matmul precision computes for this product,
+minus the three of its six passes that multiply the one-hot's middle and
+low terms, which are all zeros: every product here is a 0/1 factor times
+a bfloat16 term, hence exact, and the sums are float32.  Integer-valued
+weighted statistics with cell sums under 2^24 come out array-equal to
+the ``segment_sum`` twin.
+
+Layouts: every operand has the rows along lanes: ``binned_t`` ``[F, N]``,
+the statistics transposed ``[S, N]`` (made once a fit by the grower), the
+node ids and the tree's weight ``[N]``.  The row-tile axis is the
+innermost grid dimension; the output block ``[F_blk * B_pad, columns]`` is
+revisited across row tiles and accumulated in place (initialized at
+r == 0), the standard Pallas reduction pattern.  Levels wider than
+``_MAX_COLUMNS`` stacked columns take several node chunks (a grid axis)
+of the same product, each chunk's columns rounded up to whole 128-lane
+tiles with zero columns that the wrapper drops.
 
 Selection (``_resolve_tree_hist``): on a TPU backend ``grower`` and
 ``ChiSqSelector`` take this kernel by default whenever a mesh is given and
@@ -33,21 +60,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-_F_BLOCK = 8  # features per grid step (TPU sublane granularity)
-_ONEHOT_BUDGET = 4 * 1024 * 1024  # VMEM budget for the in-kernel one-hot
+_F_BLOCK = 8  # least features per grid step (TPU sublane granularity)
+_ONEHOT_BUDGET = 4 * 1024 * 1024  # the guard's measure of a level's width
 _MIN_TILE = 128
+_MAX_TILE = 2048
+_MAX_COLUMNS = 1536  # stacked columns (3 terms x nodes x S_pad) a step
+_ACC_BUDGET = 4 * 1024 * 1024  # the accumulator block [F_blk * B_pad, cols]
+# sized for the v5e's 128 MiB of VMEM a core (the chip this kernel is
+# measured on): half of it as the scoped limit, and a row tile's values
+# under that.  A chip with less has to lower both or the call will not
+# compile there.
+_TILE_BUDGET = 36 * 1024 * 1024  # one-hot + A_t values of one row tile
+_VMEM_LIMIT = 64 * 1024 * 1024
+#: contract the row (lane) axis of both operands: the ``q . k^T`` form
+_CONTRACT_ROWS = (((1,), (1,)), ((), ()))
 
 
 def hist_fits_pallas(n_nodes: int, n_bins: int) -> bool:
-    """True if a level histogram of this width fits the kernel's VMEM
-    budget at the minimum row tile (beyond it, the one-hot block alone
-    would exhaust VMEM — callers fall back to the segment_sum impl)."""
+    """True if a level histogram of this width takes the kernel (beyond
+    it callers fall back to the segment_sum impl).  The line is where the
+    fused node x bin one-hot of the kernel's first form overflowed VMEM at
+    the minimum row tile; the grower's node groups and the sibling gate
+    are decided from it, so it stays there: the factored product needs
+    less VMEM at every width it admits (``_plan``)."""
     nb_pad = _round_up(max(n_nodes * n_bins + 1, 128), 128)
     return _MIN_TILE * nb_pad * 4 <= _ONEHOT_BUDGET
 
@@ -89,38 +131,92 @@ def resolve_hist_impl(n_nodes_max: int, n_bins: int, mesh=None) -> str:
     )
 
 
+def _split3(x):
+    """The three bfloat16 terms of a float32 array, each still float32:
+    ``hi + mid + lo == x`` exactly (the two differences are exact in
+    float32), and each term converts to bfloat16 without rounding."""
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = x - hi
+    mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+    return hi, mid, rest - mid
+
+
 def _hist_kernel(
-    binned_ref, node_ref, stats_ref, acc_ref, *, n_bins, nb_pad, f_block
+    binned_ref, node_ref, weight_ref, stats_ref, acc_ref,
+    *, b_pad, node_chunk, cols, f_block,
 ):
-    r = pl.program_id(1)
-    nodes = node_ref[0, :]  # [TILE_N] int32 (-1 = inactive)
-    stats_t = stats_ref[:].T  # [S_pad, TILE_N]
-    alive = nodes >= 0
-    base = nodes * n_bins
-    for j in range(f_block):  # unrolled: f_block matmuls per grid step
-        bins = binned_ref[j, :]  # [TILE_N] int32 (feature f+j's bins)
-        ids = jnp.where(alive, base + bins, nb_pad - 1)
-        # dead rows point at the last padded column, which is sliced off;
-        # their stats are also zero (pre-masked), so this is belt & braces
-        onehot = (
-            jax.lax.broadcasted_iota(jnp.int32, (bins.shape[0], nb_pad), 1)
-            == ids[:, None]
-        ).astype(jnp.float32)
-        # fp32 contract: at the default precision the MXU takes the
-        # stats bf16-rounded (measured 3.9e-3 max rel error vs the
-        # segment_sum twin on a v5e; 2.4e-7 under HIGHEST — PERF.md)
-        contrib = jnp.dot(
-            stats_t, onehot, preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )  # [S_pad, NB_pad]
+    c = pl.program_id(1)
+    r = pl.program_id(2)
+    nodes = node_ref[...] - c * node_chunk  # [1, TILE] chunk-local ids
+    x = weight_ref[...] * stats_ref[...]  # [S_pad, TILE] f32
+    in_node = [nodes == k for k in range(node_chunk)]
+    # the fold is a select, so it commutes with the split: split the
+    # S_pad rows once, select them into every node's rows
+    terms = [jnp.where(m, t, 0.0) for t in _split3(x) for m in in_node]
+    spare = cols - len(terms) * x.shape[0]  # zero columns up to the block
+    if spare:
+        terms.append(jnp.zeros((spare, x.shape[1]), jnp.float32))
+    a_t = jnp.concatenate(terms, axis=0).astype(jnp.bfloat16)  # exact
+    bin_ids = jax.lax.broadcasted_iota(
+        jnp.int32, (b_pad, x.shape[1]), 0
+    )
+    onehot = jnp.concatenate(
+        [
+            (bin_ids == binned_ref[j:j + 1, :]).astype(jnp.bfloat16)
+            for j in range(f_block)
+        ],
+        axis=0,
+    )  # [f_block * B_pad, TILE]
+    # one default-precision pass: both operands are bfloat16 already and
+    # every product is 0/1 times a bfloat16 term, summed in float32
+    contrib = jax.lax.dot_general(
+        onehot, a_t, _CONTRACT_ROWS, preferred_element_type=jnp.float32
+    )  # [f_block * B_pad, cols]
 
-        @pl.when(r == 0)
-        def _init(j=j, contrib=contrib):
-            acc_ref[j] = contrib
+    @pl.when(r == 0)
+    def _init():
+        acc_ref[...] = contrib
 
-        @pl.when(r != 0)
-        def _acc(j=j, contrib=contrib):
-            acc_ref[j] += contrib
+    @pl.when(r != 0)
+    def _acc():
+        acc_ref[...] += contrib
+
+
+def _plan(f: int, n: int, s_pad: int, n_nodes: int, b_pad: int):
+    """Blocks from the static shapes alone: nodes a step (so the stacked
+    columns stay under ``_MAX_COLUMNS``) and the column block that holds
+    them (rounded up to whole 128-lane tiles with zero columns where the
+    level takes several chunks: a block that is not the whole array has
+    to be lane-aligned), features a step (all of them where the
+    accumulator block fits ``_ACC_BUDGET``, so ``A_t`` is built once a
+    row tile; else a multiple of 8), and the row tile: the largest power
+    of two from ``_MIN_TILE`` to ``_MAX_TILE`` whose one-hot and ``A_t``
+    (10 bytes an element with their float32 intermediates) fit
+    ``_TILE_BUDGET``, or the largest smaller one that divides ``n`` if
+    any does (a ragged tail costs a padded copy of every operand a
+    call)."""
+    node_chunk = max(1, min(n_nodes, _MAX_COLUMNS // (3 * s_pad)))
+    cols = 3 * node_chunk * s_pad
+    if node_chunk < n_nodes:
+        cols = _round_up(cols, 128)
+    fit = _ACC_BUDGET // (b_pad * cols * 4)
+    if f <= fit:
+        f_block = f  # one block, as ``binned_t`` lies: no feature padding
+    else:  # blocks of a sublane multiple, one that divides F if any does
+        f_block = max(_F_BLOCK, fit // _F_BLOCK * _F_BLOCK)
+        while f_block > _F_BLOCK and _round_up(f, _F_BLOCK) % f_block:
+            f_block -= _F_BLOCK
+    fits = _MAX_TILE
+    while fits > _MIN_TILE and (
+        10 * fits * (f_block * b_pad + cols) > _TILE_BUDGET
+    ):
+        fits //= 2
+    tile_n = fits
+    while tile_n > _MIN_TILE and n % tile_n:
+        tile_n //= 2
+    if n % tile_n:  # no candidate divides n: pad once, at the largest
+        tile_n = fits
+    return node_chunk, cols, f_block, tile_n
 
 
 @functools.partial(
@@ -129,69 +225,81 @@ def _hist_kernel(
 )
 def level_histogram_pallas(
     binned_t: jnp.ndarray,  # [F, N] int32 (transposed bins)
-    node_idx: jnp.ndarray,  # [N] int32
-    weighted_stats: jnp.ndarray,  # [N, S] f32, pre-weighted/masked
+    node_idx: jnp.ndarray,  # [N] int32 (-1 = dead row)
+    stats_t: jnp.ndarray,  # [S, N] f32 row statistics, rows along lanes
+    weight: jnp.ndarray,  # [N] f32 the tree's row weights
     *,
     n_nodes: int,
     n_bins: int,
     tile_n: int = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """One tree's level histogram ``[n_nodes * n_bins, S]`` (LOCAL rows —
-    caller psums across shards).
+    """One tree's level histogram ``[F, n_nodes * n_bins, S]`` of
+    ``weight * stats_t`` (LOCAL rows — caller psums across shards).
 
-    Grid is ``(F/8, N/tile)``: feature blocks of 8 satisfy the TPU sublane
-    tiling rule (a block's second-to-last dim must be a multiple of 8), and
-    the row tile adapts so the in-VMEM one-hot ``[tile, NB_pad]`` stays
-    ~4 MB regardless of the node×bin width (GBT's 128-bin levels would
-    otherwise blow VMEM).
+    Grid is ``(F / F_blk, node chunks, N / tile)``, all three from the
+    static shapes (``_plan``).  ``S`` a multiple of 8 (the grower's
+    ``_lane_dense_stats``) and ``N`` a multiple of the tile are taken as
+    they lie; anything else is zero-padded here first.
     """
     f, n = binned_t.shape
-    s = weighted_stats.shape[1]
-    nb = n_nodes * n_bins
-    nb_pad = _round_up(max(nb + 1, 128), 128)  # +1: dead-row dump column
+    s = stats_t.shape[0]
     s_pad = _round_up(s, 8)
+    b_pad = _round_up(n_bins, 16)  # the bfloat16 sublane tile
+    node_chunk, cols, f_block, tile_plan = _plan(
+        f, n, s_pad, n_nodes, b_pad
+    )
     if tile_n is None:
-        budget = _ONEHOT_BUDGET // (nb_pad * 4)
-        tile_n = max(_MIN_TILE, min(2048, (budget // 128) * 128))
+        tile_n = tile_plan
+    n_chunks = -(-n_nodes // node_chunk)
     n_pad = _round_up(n, tile_n)
-    f_pad = _round_up(f, _F_BLOCK)
+    f_pad = _round_up(f, f_block)
 
     if n_pad != n:
         binned_t = jnp.pad(binned_t, ((0, 0), (0, n_pad - n)))
         node_idx = jnp.pad(
             node_idx, (0, n_pad - n), constant_values=-1
         )
-        weighted_stats = jnp.pad(
-            weighted_stats, ((0, n_pad - n), (0, 0))
-        )
+        weight = jnp.pad(weight, (0, n_pad - n))
     if f_pad != f:
         binned_t = jnp.pad(binned_t, ((0, f_pad - f), (0, 0)))
-    if s_pad != s:
-        weighted_stats = jnp.pad(weighted_stats, ((0, 0), (0, s_pad - s)))
-
-    node_2d = node_idx[None, :]  # [1, N]
-    grid = (f_pad // _F_BLOCK, n_pad // tile_n)
+    if (s_pad, n_pad) != (s, n):
+        stats_t = jnp.pad(stats_t, ((0, s_pad - s), (0, n_pad - n)))
 
     out = pl.pallas_call(
         functools.partial(
-            _hist_kernel, n_bins=n_bins, nb_pad=nb_pad, f_block=_F_BLOCK
+            _hist_kernel, b_pad=b_pad, node_chunk=node_chunk, cols=cols,
+            f_block=f_block,
         ),
-        grid=grid,
+        grid=(f_pad // f_block, n_chunks, n_pad // tile_n),
         in_specs=[
-            pl.BlockSpec((_F_BLOCK, tile_n), lambda i, r: (i, r)),  # binned_t
-            pl.BlockSpec((1, tile_n), lambda i, r: (0, r)),  # node_idx
-            pl.BlockSpec((tile_n, s_pad), lambda i, r: (r, 0)),  # stats
+            pl.BlockSpec((f_block, tile_n), lambda i, c, r: (i, r)),
+            pl.BlockSpec((1, tile_n), lambda i, c, r: (0, r)),  # node_idx
+            pl.BlockSpec((1, tile_n), lambda i, c, r: (0, r)),  # weight
+            pl.BlockSpec((s_pad, tile_n), lambda i, c, r: (0, r)),
         ],
         out_specs=pl.BlockSpec(
-            (_F_BLOCK, s_pad, nb_pad), lambda i, r: (i, 0, 0)
+            (f_block * b_pad, cols), lambda i, c, r: (i, c)
         ),
-        out_shape=jax.ShapeDtypeStruct((f_pad, s_pad, nb_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (f_pad * b_pad, n_chunks * cols), jnp.float32
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(binned_t, node_2d, weighted_stats)
+    )(binned_t, node_idx[None, :], weight[None, :], stats_t)
 
-    # [F_pad, S_pad, NB_pad] -> [F, NB, S] (the grower's layout)
-    return out[:f, :s, :nb].transpose(0, 2, 1)
+    # columns are (chunk, term, node, stat) and a chunk's spare zeros: add
+    # the three terms' partial histograms in float32, then [F, node, bin,
+    # S] (the grower's layout)
+    out = out.reshape(f_pad, b_pad, n_chunks, cols)
+    out = out[..., :3 * node_chunk * s_pad]
+    out = out.reshape(f_pad, b_pad, n_chunks, 3, node_chunk, s_pad)
+    out = out.sum(axis=3).transpose(0, 2, 3, 1, 4)
+    out = out.reshape(f_pad, n_chunks * node_chunk, b_pad, s_pad)
+    return out[:f, :n_nodes, :n_bins, :s].reshape(f, n_nodes * n_bins, s)
 
 
 # registered behind the shared kernel capability registry (r21):
@@ -202,20 +310,20 @@ from sntc_tpu.kernels.registry import KernelSpec, register_kernel  # noqa: E402
 
 def _smoke_case(rows: int):
     """One depth-4 level of the bench config 3 forest: 16 nodes x 32
-    bins over the 40 chi-square-selected features, 15 class stats."""
+    bins over the 40 chi-square-selected features, 15 class stats, with
+    dead rows and real-valued weights."""
     import numpy as np
 
     n_nodes, n_bins, f, s = 16, 32, 40, 15
     rng = np.random.default_rng(0)
     node_idx = rng.integers(-1, n_nodes, size=rows).astype(np.int32)
-    stats = rng.random((rows, s)).astype(np.float32)
-    stats[node_idx < 0] = 0.0  # pre-masked, as the grower guarantees
 
-    def twin(binned_t, node_idx, stats):
+    def twin(binned_t, node_idx, stats_t, weight):
+        data = (stats_t * jnp.where(node_idx >= 0, weight, 0.0)).T
         ids = jnp.maximum(node_idx, 0)[None, :] * n_bins + binned_t
         return jax.vmap(
             lambda i: jax.ops.segment_sum(
-                stats, i, num_segments=n_nodes * n_bins
+                data, i, num_segments=n_nodes * n_bins
             )
         )(ids)
 
@@ -227,7 +335,8 @@ def _smoke_case(rows: int):
         (
             rng.integers(0, n_bins, size=(f, rows)).astype(np.int32),
             node_idx,
-            stats,
+            rng.random((s, rows)).astype(np.float32),
+            rng.random(rows).astype(np.float32),
         ),
         1e-5,
     )
@@ -239,7 +348,7 @@ register_kernel(
         module="sntc_tpu/ops/pallas_histogram.py",
         guard_name="hist_fits_pallas",
         guard=hist_fits_pallas,
-        tolerance="<=1e-5 rel f32 (pre-weighted stats accumulation)",
+        tolerance="<=1e-5 rel f32 (three exact bf16 terms, f32 sums)",
         fallback="XLA segment_sum level histogram (ops/histogram.py)",
         env="SNTC_TREE_HIST",
         resolver=_resolve_tree_hist,
