@@ -4227,24 +4227,16 @@ def bench_mfu(n_rows, mesh):
     )
 
     # ---- (b) histogram kernel at config-3 level shapes ----
-    from sntc_tpu.ops.pallas_histogram import (
-        hist_fits_pallas,
-        level_histogram_pallas,
-    )
-
-    from sntc_tpu.models.tree.grower import node_group_size
+    from sntc_tpu.models.tree.grower import _level_plan
+    from sntc_tpu.ops.pallas_histogram import level_histogram_pallas
 
     F, B, S = CHISQ_TOP, 32, 15  # config-3 classification stats width
     # the width a config-3 level pass really runs: the deepest level,
-    # capped by the grower's memory-bounded node group, shrunk until
-    # the kernel's VMEM gate admits it — the same resolution
-    # grow_forest applies on TPU
-    n_nodes = min(
-        2 ** (RF_DEPTH - 1), node_group_size(RF_TREES, F, B, S)
-    )
-    while n_nodes > 1 and not hist_fits_pallas(n_nodes, B):
-        n_nodes //= 2
-    if hist_fits_pallas(n_nodes, B) and platform != "cpu":
+    # capped by the node group of the plan grow_forest makes for it
+    # (the memory budget, cut to the kernel's VMEM guard)
+    plan = _level_plan(RF_TREES, F, B, S, RF_DEPTH, mesh)
+    n_nodes = min(2 ** (RF_DEPTH - 1), plan.group)
+    if plan.hist_impl == "pallas" and platform != "cpu":
         rng = np.random.default_rng(0)
         n_loc = min(N, 200_000)
         binned_t = jnp.asarray(

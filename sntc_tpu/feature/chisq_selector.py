@@ -39,7 +39,7 @@ _MODULE = module_of(__name__)
 
 
 @lru_cache(maxsize=None)
-def _contingency_agg(mesh, n_bins, n_classes, impl, interpret):
+def _contingency_agg(mesh, n_bins, n_classes, impl):
     """One compiled contingency program per configuration across fits
     (edges arrive as a replicated ARGUMENT, not a baked-in constant —
     rebuilding the aggregate per fit recompiled on every call)."""
@@ -48,8 +48,7 @@ def _contingency_agg(mesh, n_bins, n_classes, impl, interpret):
         binned = bin_features(xs, edges)
         if impl == "pallas":
             return binned_contingency_onehot(
-                binned, ys, w, n_bins=n_bins, n_classes=n_classes,
-                interpret=interpret,
+                binned, ys, w, n_bins=n_bins, n_classes=n_classes
             )
         return binned_contingency(
             binned, ys, w, n_bins=n_bins, n_classes=n_classes
@@ -106,18 +105,15 @@ def chi2_scores(X: np.ndarray, y: np.ndarray, mesh, n_bins: int):
     """``(stats [F], p_values [F])`` of the binned χ² test — the one chi2
     scoring pipeline shared by ChiSqSelector and
     UnivariateFeatureSelector's categorical/categorical mode."""
-    import jax
-
-    from sntc_tpu.ops.pallas_histogram import resolve_hist_impl
+    from sntc_tpu.ops.pallas_histogram import tree_hist_impl
 
     y = np.asarray(y).astype(np.int32)
     n_classes = int(y.max()) + 1 if len(y) else 1
     with span("chi2.bin_edges", module=_MODULE):
         edges = quantile_bin_edges(X, max_bins=n_bins)
     xs, ys, w = shard_batch(mesh, X, y)
-    on_tpu = jax.default_backend() == "tpu"
-    impl = resolve_hist_impl(1, n_bins, mesh)
-    observed = _contingency_agg(mesh, n_bins, n_classes, impl, not on_tpu)(
+    impl = tree_hist_impl(1, n_bins, mesh)
+    observed = _contingency_agg(mesh, n_bins, n_classes, impl)(
         xs, ys, w, jnp.asarray(edges)
     )
     with span("d2h.fetch", what="contingency", module=_MODULE):

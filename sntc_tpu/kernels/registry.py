@@ -16,8 +16,9 @@ Selection and survival are shared, not per-kernel ad hoc:
   tier — ``SNTC_SERVE_KERNELS`` = ``auto`` (pallas on TPU, off
   elsewhere) / ``pallas`` / ``interpret`` (the CPU tier-1 mode: every
   kernel runs through the Pallas interpreter) / ``off``.  The fit-side
-  ``SNTC_TREE_HIST`` switch routes through :func:`resolve_impl` with
-  its historical semantics intact (satellite: behavior-preserving).
+  ``tree_hist`` is registered here for its guard, twin and smoke case
+  only: its selection is ``ops.pallas_histogram.tree_hist_impl``
+  (backend, mesh, guard; ``SNTC_TREE_HIST``), not this switch.
 
 * :func:`kernel_dispatch` is the poison/fallback ladder for host-level
   kernel calls: a fresh (kernel, signature) crosses the
@@ -72,9 +73,6 @@ class KernelSpec:
     fallback: str
     #: env switch that selects this kernel (shared or kernel-specific)
     env: str = _SERVE_ENV
-    #: optional kernel-specific resolver (the tree_hist historical
-    #: semantics); None = the shared serve-tier resolution
-    resolver: Optional[Callable[..., str]] = None
     #: ``smoke_case(rows) -> (kernel_fn, twin_fn, args, rtol)``: the
     #: kernel (``kernel_fn(*args, interpret=...)``) and its twin
     #: (``twin_fn(*args)``) over the same seeded inputs at the widths
@@ -158,23 +156,13 @@ def serve_kernels_forced() -> bool:
 
 
 def resolve_impl(name: str, **guard_kwargs) -> str:
-    """Implementation selection for ``name`` through its registered
-    resolver (the fit-side ``tree_hist`` keeps its historical
-    ``SNTC_TREE_HIST`` semantics) or the shared serve-tier switch.
-    Returns the impl token the caller dispatches on; every resolution
-    is counted into the ``sntc_kernel_*`` family."""
+    """Implementation selection for the serve-tier kernel ``name``
+    through the shared switch and its guard.  Returns the impl token
+    the caller dispatches on; a fallback is counted into the
+    ``sntc_kernel_*`` family here, a dispatch by the caller."""
     from sntc_tpu.obs.metrics import inc
 
     spec = kernel_spec(name)
-    if spec.resolver is not None:
-        impl = spec.resolver(**guard_kwargs)
-        inc(
-            "sntc_kernel_dispatch_total"
-            if impl == "pallas" else "sntc_kernel_fallback_total",
-            kernel=name,
-            **({"impl": impl} if impl == "pallas" else {"reason": impl}),
-        )
-        return impl
     mode = resolve_serve_kernels()
     if mode == "off":
         inc("sntc_kernel_fallback_total", kernel=name, reason="off")

@@ -32,7 +32,6 @@ thresholds.
 from __future__ import annotations
 
 import math
-import os
 from functools import partial
 from typing import NamedTuple, Tuple
 
@@ -260,21 +259,71 @@ def _stat_count(stats: jnp.ndarray, impurity: str) -> jnp.ndarray:
     return stats.sum(axis=-1)
 
 
-def node_group_size(T: int, F: int, n_bins: int, S: int) -> int:
-    """Nodes per histogram pass, bounded so the level working set
-    (histogram + cumsum + left/right slices + gain tensor, ~5× the raw
-    histogram) stays under ``SNTC_TREE_NODE_GROUP_MB`` (default 2 GB;
-    Spark's ``maxMemoryInMB=256`` bounds its node groups the same way
-    [U] — we default 8× that, HBM being roomier than a 2010s JVM heap;
-    measured on the depth-10 bench config, 2 GB more than halves deep-
-    level wall-clock vs 512 MB and going past it buys nothing).
-    Deep levels evaluate in several passes over the binned data instead
-    of materializing a multi-GB ``[T, 2^d, F, B, S]`` tensor — the
-    memory/compute tradeoff Spark makes."""
-    budget = float(os.environ.get("SNTC_TREE_NODE_GROUP_MB", 2048))
-    per_node = 5.0 * T * F * n_bins * S * 4
-    raw = max(1, int(budget * 1024 * 1024 / per_node))
-    return 1 << (raw.bit_length() - 1)  # pow2: levels split evenly
+#: bytes one node group's working set may take (histogram + cumsum +
+#: left/right slices + gain tensor, about 5x the raw histogram).  Spark's
+#: ``maxMemoryInMB=256`` bounds its node groups the same way [U]; 8x
+#: that, HBM being roomier than a 2010s JVM heap: on the depth-10 bench
+#: config 2 GiB more than halved deep-level wall-clock against 512 MiB
+#: and going past it bought nothing.
+_NODE_GROUP_BYTES = 2 << 30
+#: a level's full histogram is kept on the device for the next level's
+#: sibling subtraction only while it is no larger than this
+_SIBLING_BYTES = 1 << 30
+
+
+class LevelPlan(NamedTuple):
+    """How one fit builds its histograms; hashable, the one static
+    argument of :func:`_grow_fused` that says so."""
+
+    hist_impl: str  # "pallas" | "segment", the same on every level
+    group: int  # nodes a histogram pass (a power of two)
+    keep_hists: Tuple[bool, ...]  # per level: kept for the next level
+
+
+def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
+                mesh) -> LevelPlan:
+    """The fit's histogram decisions, made once from its shapes and what
+    :func:`~sntc_tpu.ops.pallas_histogram.tree_hist_impl` observes.
+
+    Node group: deep levels evaluate in several passes over the binned
+    data instead of materializing a multi-GB ``[T, 2^d, F, B, S]``
+    tensor, the memory/compute tradeoff Spark makes; the largest power
+    of two (levels split evenly) whose working set fits
+    ``_NODE_GROUP_BYTES``.  Under the kernel the group is cut further to
+    the kernel's guard, so that every level takes it: more group passes
+    cost less there than one level on the serial scatter-adds (on the
+    v5e the kernel builds an 8-node level of the benchmark's forest in
+    22 ms, and 128 nodes, the guard's edge, in 345 ms: PERF.md section 6,
+    PR 30).
+
+    Sibling subtraction (LightGBM-style, beyond Spark's
+    ``DTStatsAggregator``): level ``d``'s full histogram is kept, and
+    level ``d + 1`` histograms only left children and derives each right
+    sibling as parent - left, where that pays: under the kernel, whose
+    cost follows the node-axis width (a ``segment_sum`` scatter costs
+    O(N) whatever the width, so there the kept histogram's traffic is
+    pure overhead: 2.1x slower on CPU at the depth-10 bench shapes), with
+    groups of at least a left/right pair, and while the kept histogram
+    fits ``_SIBLING_BYTES``."""
+    # imported where used: Pallas costs a second to import, and a process
+    # that grows no tree (the MLP's fit, the serve path) should not pay it
+    from sntc_tpu.ops.pallas_histogram import hist_fits_pallas, tree_hist_impl
+
+    hist_bytes = T * F * n_bins * S * 4  # one node's histogram
+    raw = max(1, _NODE_GROUP_BYTES // (5 * hist_bytes))
+    group = 1 << (raw.bit_length() - 1)
+    # asked at one node: a fit whose narrowest level the kernel refuses
+    # takes it nowhere, and every wider level is cut to the guard below
+    hist_impl = tree_hist_impl(1, n_bins, mesh)
+    if hist_impl == "pallas":
+        while not hist_fits_pallas(group, n_bins):
+            group //= 2
+    siblings = hist_impl == "pallas" and group >= 2
+    keep_hists = tuple(
+        siblings and d < max_depth - 1 and (hist_bytes << d) <= _SIBLING_BYTES
+        for d in range(max_depth)
+    )
+    return LevelPlan(hist_impl, group, keep_hists)
 
 
 def _level_core(
@@ -293,23 +342,21 @@ def _level_core(
     #             (sibling-subtraction path) or None (direct)
     stats_t,  # [S_pad, N] / [T, S_pad, N] f32: ``row_stats`` with the rows
     #         along lanes (:func:`_lane_dense_stats`), the pallas kernel's
-    #         operand; None when no level takes the kernel
+    #         operand; None when the fit takes ``segment_sum``
     *,
     n_nodes: int,
     n_bins: int,
     impurity: str,
     subset_k: int,
     group: int,
-    hist_impl: str = "segment",
     mesh=None,
-    interpret: bool = False,
     route: bool = True,
     keep_hist: bool = False,
 ):
     """One level's histogram + split evaluation + (optional) row routing,
     with the node axis evaluated in memory-bounded groups of ``group``
-    nodes (Spark's maxMemoryInMB node-group analog; resolved ONCE in
-    :func:`grow_forest` so it participates in the jit cache key).  Rows
+    nodes (Spark's maxMemoryInMB node-group analog; :func:`_level_plan`
+    decides it once a fit, so it participates in the jit cache key).  Rows
     are routed over the WHOLE level's decision tables after the groups
     are stacked (:func:`_route_rows`), whatever the grouping.  Traced
     inside :func:`_grow_fused`'s unrolled level loop."""
@@ -329,8 +376,7 @@ def _level_core(
             binned_t, row_stats, row_label, row_weight,
             w_trees, node_idx, fmask, min_instances, parent_hist, stats_t,
             lo=jnp.int32(0), g=n_nodes, n_bins=n_bins,
-            impurity=impurity, hist_impl=hist_impl, mesh=mesh,
-            interpret=interpret, keep_hist=keep_hist,
+            impurity=impurity, mesh=mesh, keep_hist=keep_hist,
         )
     else:
         # groups share shapes (pow2 group divides the pow2 level), so the
@@ -346,8 +392,7 @@ def _level_core(
                     binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, None, min_instances, parent_hist,
                     stats_t, lo=lo_t, g=group, n_bins=n_bins,
-                    impurity=impurity, hist_impl=hist_impl, mesh=mesh,
-                    interpret=interpret, keep_hist=keep_hist,
+                    impurity=impurity, mesh=mesh, keep_hist=keep_hist,
                 )
         else:
             fmask_g = fmask.reshape(T, n_groups, group, F).transpose(
@@ -360,8 +405,7 @@ def _level_core(
                     binned_t, row_stats, row_label, row_weight,
                     w_trees, node_idx, a[1], min_instances, parent_hist,
                     stats_t, lo=a[0], g=group, n_bins=n_bins,
-                    impurity=impurity, hist_impl=hist_impl, mesh=mesh,
-                    interpret=interpret, keep_hist=keep_hist,
+                    impurity=impurity, mesh=mesh, keep_hist=keep_hist,
                 )
 
         stacked = jax.lax.map(one, args)  # each: [n_groups, T, group, ...]
@@ -453,9 +497,7 @@ def _eval_node_group(
     g: int,
     n_bins: int,
     impurity: str,
-    hist_impl: str,
     mesh,
-    interpret: bool,
     keep_hist: bool,
 ):
     """Histogram + best-split evaluation for the ``g`` nodes starting at
@@ -483,8 +525,7 @@ def _eval_node_group(
         )
         h_even = _group_hist(
             binned_t, row_stats, row_label, row_weight, w_trees,
-            ids_even, stats_t, g_eff=g // 2, n_bins=n_bins,
-            hist_impl=hist_impl, mesh=mesh, interpret=interpret,
+            ids_even, stats_t, g_eff=g // 2, n_bins=n_bins, mesh=mesh,
         )
         par = jax.lax.dynamic_slice(
             parent_hist, (0, lo // 2, 0, 0, 0),
@@ -509,8 +550,7 @@ def _eval_node_group(
         )
         hist = _group_hist(
             binned_t, row_stats, row_label, row_weight, w_trees,
-            ids, stats_t, g_eff=g, n_bins=n_bins, hist_impl=hist_impl,
-            mesh=mesh, interpret=interpret,
+            ids, stats_t, g_eff=g, n_bins=n_bins, mesh=mesh,
         )
 
     out = _eval_from_hist(hist, fmask, min_instances, impurity=impurity)
@@ -522,21 +562,21 @@ def _eval_node_group(
 def _group_hist(
     binned_t, row_stats, row_label, row_weight, w_trees,
     node_idx,  # [T, N] int32 GROUP-LOCAL ids in [0, g_eff) (-1 = dead)
-    stats_t,  # [S_pad, N] / [T, S_pad, N] f32 (pallas levels) or None
+    stats_t,  # [S_pad, N] / [T, S_pad, N] f32 (the kernel's) or None
     *,
     g_eff: int,
     n_bins: int,
-    hist_impl: str,
     mesh,
-    interpret: bool,
 ):
     """Histogram ``[T, g_eff, F, B, S]`` over pre-mapped local node ids.
 
-    Three impls: the pallas MXU bin-one-hot matmul over ``stats_t`` (TPU),
-    the label-fused scalar ``segment_sum`` (classification with shared
+    Three impls, chosen by what the caller passed: the pallas MXU
+    bin-one-hot matmul over ``stats_t`` where the fit takes the kernel
+    (``stats_t`` is made for it alone: :func:`_grow_fused`), else the
+    label-fused scalar ``segment_sum`` (classification with shared
     one-hot stats — scatters N scalars into ``(node·B + bin)·S + label``
     instead of N×S vector rows, ~6× less scatter traffic; requires
-    ``row_stats == one_hot(row_label) * row_weight[:, None]``), and the
+    ``row_stats == one_hot(row_label) * row_weight[:, None]``), else the
     generic vector ``segment_sum``."""
     F = binned_t.shape[0]
     S = row_stats.shape[-1]
@@ -545,7 +585,7 @@ def _group_hist(
     n_nodes = g_eff  # group-local histogram width
 
     # ---- histogram: [T, nodes, F, B, S] ------------------------------------
-    if hist_impl == "pallas":
+    if stats_t is not None:
         # MXU factored one-hot matmul kernel per shard, explicit psum over
         # the mesh (sntc_tpu/ops/pallas_histogram.py).  Every operand has
         # the rows along lanes: the statistics arrive transposed once a
@@ -563,8 +603,7 @@ def _group_hist(
         def shard_fn(bt, st, wt, ni):
             def hist_one(w_t, node_t, st_t):
                 return level_histogram_pallas(
-                    bt, node_t, st_t, w_t,
-                    n_nodes=n_nodes, n_bins=n_bins, interpret=interpret,
+                    bt, node_t, st_t, w_t, n_nodes=n_nodes, n_bins=n_bins
                 )[..., :S]  # [F, nodes*B, S]
 
             if per_tree_stats:
@@ -726,71 +765,22 @@ def grow_forest(
     impurity: str,
     seed: int,
     mesh=None,
-    hist_impl: str = None,
     row_label=None,  # [N] int32 (device, row-sharded): class ids
     row_weight=None,  # [N] f32 (device, row-sharded): per-row weights
 ) -> Forest:
     """Grow T trees level-synchronously; returns host-side dense heaps.
 
-    ``hist_impl``: "pallas" (MXU one-hot matmul kernel; requires ``mesh``)
-    or "segment" (XLA scatter-add).  Default: pallas on TPU, segment
-    elsewhere (scatter-adds serialize on TPU, the one-hot contraction
-    rides the MXU; pallas-vs-segment on the local v5e is not measured).
-    Resolved PER LEVEL: deep levels whose node×bin width would overflow
-    the kernel's VMEM budget fall back to segment_sum while shallow levels
-    keep the MXU path.  Overridable via the ``SNTC_TREE_HIST`` env var.
+    How the histograms are built (the Pallas kernel or ``segment_sum``,
+    the node group, sibling subtraction) is :func:`_level_plan`'s to say,
+    once a fit, from the shapes, ``mesh`` (the kernel requires one) and
+    the backend.
 
     ``row_label``/``row_weight``: classification callers whose
     ``row_stats`` satisfy ``one_hot(row_label) * row_weight[:, None]``
     pass both to unlock the label-fused scalar scatter (~6× less scatter
-    traffic than the [N, S] vector scatter on CPU/segment levels).
-
-    Sibling-histogram subtraction (LightGBM-style, beyond Spark's
-    DTStatsAggregator) engages per level when the NEXT level runs the
-    pallas one-hot kernel (where histogram cost ∝ node-axis width — the
-    matmul halves; a segment_sum scatter costs O(N) regardless, so on
-    CPU the kept-histogram traffic would be pure overhead) AND the
-    previous level's full histogram fits ``SNTC_TREE_SIBLING_MB``
-    (default 1024 MB): only left children are histogrammed from rows,
-    right siblings are derived as parent − left.
-    ``SNTC_TREE_SIBLING=0`` disables everywhere; ``=1`` forces it on
-    segment levels too (tests).
+    traffic than the [N, S] vector scatter) where the fit takes
+    ``segment_sum``.
     """
-    from sntc_tpu.ops.pallas_histogram import (
-        hist_fits_pallas,
-        resolve_hist_impl,
-    )
-
-    on_tpu = jax.default_backend() == "tpu"
-    # per-level histogram width is bounded by the node-group size
-    # (Spark maxMemoryInMB analog), so deep levels can keep the pallas
-    # kernel: its VMEM test sees the group width, not 2^d
-    group = node_group_size(
-        w_trees.shape[0], binned.shape[1], n_bins, row_stats.shape[-1]
-    )
-    if (
-        on_tpu
-        and mesh is not None
-        and hist_impl is None
-        and "SNTC_TREE_HIST" not in os.environ
-        and "SNTC_TREE_NODE_GROUP_MB" not in os.environ
-    ):
-        # on TPU a group whose node×bin width overflows the kernel's
-        # VMEM budget would silently fall back to segment_sum — and
-        # scatter-adds SERIALIZE there, which is assumed to cost more
-        # than extra group passes (not measured on the local chip).
-        # Shrink the group until every level rides the MXU.
-        while group > 1 and not hist_fits_pallas(group, n_bins):
-            group //= 2
-    hist_impls = tuple(
-        hist_impl
-        if hist_impl is not None
-        else resolve_hist_impl(min(1 << d, group), n_bins, mesh)
-        for d in range(max(max_depth, 1))
-    )
-    if mesh is None:
-        hist_impls = tuple("segment" for _ in hist_impls)
-    interpret = not on_tpu
     # every histogram impl scans the transposed layout: contiguous
     # per-feature bins (pallas lane layout; stride-F column gathers
     # dominated CPU level cost otherwise)
@@ -809,41 +799,8 @@ def grow_forest(
         return Forest(feature, threshold, leaf_stats, max_depth,
                       np.zeros((T, H), np.float32), np.zeros((T, H), np.float32))
 
-    # sibling subtraction: level d+1 can subtract iff level d's FULL
-    # histogram is worth keeping device-resident (size gate) and the
-    # group width admits (even, ≥2) left/right pairs.  Profitable ONLY
-    # on the pallas path, where histogram cost ∝ node-axis width (the
-    # one-hot matmul halves); a segment_sum scatter costs O(N) regardless
-    # of width, so on CPU the kept-histogram traffic is pure overhead
-    # (measured 2.1× slower at the depth-10 bench shapes).
-    # SNTC_TREE_SIBLING=1 forces it everywhere (tests), =0 disables.
-    sib_env = os.environ.get("SNTC_TREE_SIBLING", "")
-    if sib_env not in ("", "0", "1"):
-        import warnings
-
-        warnings.warn(
-            f"SNTC_TREE_SIBLING={sib_env!r} is not one of '', '0', '1'; "
-            "using the default (pallas-gated on)",
-            stacklevel=2,
-        )
-        sib_env = ""
-    sib_on = group >= 2 and sib_env in ("", "1")
-    sib_mb = float(os.environ.get("SNTC_TREE_SIBLING_MB", 1024))
-    per_node_hist_mb = (
-        T * binned.shape[1] * n_bins * S * 4 / (1024 * 1024)
-    )
-    keep_hists = tuple(
-        sib_on
-        and d < max_depth - 1
-        # the level that WOULD subtract (d+1) must be on the matmul path
-        and (hist_impls[d + 1] == "pallas" or sib_env == "1")
-        and (1 << d) * per_node_hist_mb <= sib_mb
-        for d in range(max_depth)
-    )
-
+    plan = _level_plan(T, binned.shape[1], n_bins, S, max_depth, mesh)
     keys = jax.random.split(jax.random.PRNGKey(seed), max_depth)
-    if os.environ.get("SNTC_TREE_LABEL_FUSED", "1") == "0":
-        row_label = row_weight = None  # field kill-switch: generic path
     if row_label is not None:
         # out-of-range labels (e.g. a -1 sentinel) must contribute ZERO,
         # exactly like one_hot's out-of-range zero vector — a raw scatter
@@ -858,9 +815,7 @@ def grow_forest(
         jnp.asarray(edges), keys,
         jnp.float32(min_instances_per_node), jnp.float32(min_info_gain),
         max_depth=max_depth, n_bins=n_bins, impurity=impurity,
-        subset_k=subset_k, group=group, hist_impls=hist_impls,
-        keep_hists=keep_hists, mesh=mesh,
-        interpret=interpret,
+        subset_k=subset_k, plan=plan, mesh=mesh,
     )
     with span("d2h.fetch", what="forest", module=_MODULE):
         feature, threshold, leaf_stats, gain_arr, count_arr = (
@@ -873,16 +828,14 @@ def grow_forest(
 @partial(
     jax.jit,
     static_argnames=(
-        "max_depth", "n_bins", "impurity", "subset_k", "group",
-        "hist_impls", "keep_hists", "mesh", "interpret",
+        "max_depth", "n_bins", "impurity", "subset_k", "plan", "mesh",
     ),
 )
 def _grow_fused(
     binned_t, row_stats, row_label, row_weight, w_trees,
     edges_dev, keys,
     min_instances, min_info_gain,
-    *, max_depth, n_bins, impurity, subset_k, group, hist_impls,
-    keep_hists, mesh, interpret,
+    *, max_depth, n_bins, impurity, subset_k, plan, mesh,
 ):
     """The WHOLE level-wise growth as one XLA program: the depth loop is
     unrolled at trace time, so every level keeps its exact node count
@@ -908,7 +861,7 @@ def _grow_fused(
     # the kernel's statistics operand, made once a fit and shared by every
     # level (and, for shared statistics, by every tree)
     stats_t = (
-        _lane_dense_stats(row_stats) if "pallas" in hist_impls else None
+        _lane_dense_stats(row_stats) if plan.hist_impl == "pallas" else None
     )
 
     prev_hist = None
@@ -920,11 +873,9 @@ def _grow_fused(
             w_trees, node_idx, keys[depth],
             min_instances, min_info_gain, prev_hist, stats_t,
             n_nodes=n_nodes, n_bins=n_bins, impurity=impurity,
-            subset_k=subset_k, group=group,
-            hist_impl=hist_impls[depth], mesh=mesh,
-            interpret=interpret,
+            subset_k=subset_k, group=plan.group, mesh=mesh,
             route=depth < max_depth - 1,
-            keep_hist=keep_hists[depth],
+            keep_hist=plan.keep_hists[depth],
         )
         prev_hist = out.get("hist")
         split_mask = out["do_split"] & exists_lvl

@@ -46,7 +46,6 @@ def binned_contingency_onehot(
     *,
     n_bins: int,
     n_classes: int,
-    interpret: bool = False,
 ) -> jnp.ndarray:
     """MXU path for :func:`binned_contingency` — the pallas level-histogram
     kernel with a single "node" (the segment_sum form scatter-adds
@@ -62,7 +61,7 @@ def binned_contingency_onehot(
     node0 = jnp.zeros(y.shape[0], jnp.int32)
     return level_histogram_pallas(
         binned.T, node0, yoh_t, w,
-        n_nodes=1, n_bins=n_bins, interpret=interpret,
+        n_nodes=1, n_bins=n_bins,
     )[..., :n_classes]  # [F, B, C]
 
 

@@ -47,20 +47,24 @@ r == 0), the standard Pallas reduction pattern.  Levels wider than
 of the same product, each chunk's columns rounded up to whole 128-lane
 tiles with zero columns that the wrapper drops.
 
-Selection (``_resolve_tree_hist``): on a TPU backend ``grower`` and
-``ChiSqSelector`` take this kernel by default whenever a mesh is given and
-the level fits the VMEM budget; elsewhere the XLA segment-sum.
-``SNTC_TREE_HIST`` overrides; interpret mode backs the CPU tests.
+Selection is :func:`tree_hist_impl`, the one place that knows the rule and
+the one reader of ``SNTC_TREE_HIST``; the grower, ``ChiSqSelector`` and
+``stat.ChiSquareTest`` ask it once a fit.  Off a TPU the kernel runs
+through the Pallas interpreter (:func:`level_histogram_pallas` decides
+that itself), which backs the CPU tests.
 """
 
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from sntc_tpu.obs.metrics import inc
 
 
 def _round_up(x: int, m: int) -> int:
@@ -87,48 +91,51 @@ def hist_fits_pallas(n_nodes: int, n_bins: int) -> bool:
     """True if a level histogram of this width takes the kernel (beyond
     it callers fall back to the segment_sum impl).  The line is where the
     fused node x bin one-hot of the kernel's first form overflowed VMEM at
-    the minimum row tile; the grower's node groups and the sibling gate
-    are decided from it, so it stays there: the factored product needs
-    less VMEM at every width it admits (``_plan``)."""
+    the minimum row tile.  The kernel has not built that one-hot since
+    PR 30 and needs less VMEM at every width the line admits (``_plan``),
+    but the grower's node groups are cut to this line, so moving it onto
+    ``_plan`` would change which program a forest of 256+ nodes x 32 bins
+    compiles, and no benchmark cell grows one to judge that by: the
+    verdicts stay until one does (``ROADMAP.md`` C6)."""
     nb_pad = _round_up(max(n_nodes * n_bins + 1, 128), 128)
     return _MIN_TILE * nb_pad * 4 <= _ONEHOT_BUDGET
 
 
-def _resolve_tree_hist(n_nodes_max: int, n_bins: int, mesh=None) -> str:
-    """The historical ``SNTC_TREE_HIST`` selection semantics, verbatim
-    (r21 moved the dispatch behind the kernel registry; this resolver
-    keeps the fit-side behavior byte-identical)."""
-    import os
+def tree_hist_impl(n_nodes: int, n_bins: int, mesh) -> str:
+    """``"pallas"`` or ``"segment"``: which implementation builds a fit's
+    histograms of up to ``n_nodes`` nodes x ``n_bins`` bins a pass.
 
-    import jax
+    The kernel where the backend is a TPU, a mesh is given (the kernel
+    runs per shard under :func:`~sntc_tpu.parallel.mesh.map_at`) and the
+    guard admits the width; the XLA ``segment_sum`` elsewhere.  On the
+    v5e the kernel is the faster by far: XLA lowers the scatter-adds to
+    serial loops there, and the five kernel calls of the benchmark's
+    forest fit (4,063,232 rows x 40 features, 1-8 histogrammed nodes)
+    take 1.149 s of a 12.873 s fit, the chi-square contingency 0.0146 s
+    (ledger, PR 30, ``breakdown.device_ops``).
 
-    on_tpu = jax.default_backend() == "tpu"
-    impl = os.environ.get(
-        "SNTC_TREE_HIST", "pallas" if on_tpu else "segment"
+    ``SNTC_TREE_HIST`` = ``pallas`` | ``segment`` stands in for the
+    backend's verdict (mesh and guard still apply).  It is the one switch
+    the fit's histogram keeps, for its two users: ``pallas`` off a TPU is
+    how ``chip_smoke.py --rehearse-cpu`` and the twin-comparison tests
+    run the kernel (through the interpreter), and ``segment`` on a TPU is
+    the operator's way back to the XLA twin.
+
+    Every call counts into ``sntc_kernel_dispatch_total{kernel=
+    "tree_hist",impl="pallas"}`` or ``sntc_kernel_fallback_total{kernel=
+    "tree_hist",reason="segment"}``."""
+    want = os.environ.get("SNTC_TREE_HIST") or (
+        "pallas" if jax.default_backend() == "tpu" else "segment"
     )
-    if impl == "pallas" and (
-        mesh is None or not hist_fits_pallas(n_nodes_max, n_bins)
+    if (
+        want == "pallas"
+        and mesh is not None
+        and hist_fits_pallas(n_nodes, n_bins)
     ):
-        return "segment"
-    return impl
-
-
-def resolve_hist_impl(n_nodes_max: int, n_bins: int, mesh=None) -> str:
-    """Histogram impl selection shared by the tree grower and
-    ChiSqSelector: the one-hot MXU kernel on TPU (scatter-adds serialize
-    there; its speed on the local v5e is not measured), segment_sum
-    elsewhere, when no mesh is available, or when the widest level
-    overflows the kernel's VMEM budget.  ``SNTC_TREE_HIST`` overrides.
-
-    Since r21 the call routes through the shared kernel registry
-    (``sntc_tpu.kernels.registry``) so the fit-side kernel shares the
-    serve tier's fit-guard/fallback/cost accounting; the selection
-    itself is unchanged (``_resolve_tree_hist``)."""
-    from sntc_tpu.kernels.registry import resolve_impl
-
-    return resolve_impl(
-        "tree_hist", n_nodes_max=n_nodes_max, n_bins=n_bins, mesh=mesh
-    )
+        inc("sntc_kernel_dispatch_total", kernel="tree_hist", impl="pallas")
+        return "pallas"
+    inc("sntc_kernel_fallback_total", kernel="tree_hist", reason="segment")
+    return "segment"
 
 
 def _split3(x):
@@ -232,7 +239,7 @@ def level_histogram_pallas(
     n_nodes: int,
     n_bins: int,
     tile_n: int = None,
-    interpret: bool = False,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     """One tree's level histogram ``[F, n_nodes * n_bins, S]`` of
     ``weight * stats_t`` (LOCAL rows — caller psums across shards).
@@ -240,8 +247,12 @@ def level_histogram_pallas(
     Grid is ``(F / F_blk, node chunks, N / tile)``, all three from the
     static shapes (``_plan``).  ``S`` a multiple of 8 (the grower's
     ``_lane_dense_stats``) and ``N`` a multiple of the tile are taken as
-    they lie; anything else is zero-padded here first.
+    they lie; anything else is zero-padded here first.  ``interpret``
+    left ``None`` means the Pallas interpreter unless the backend is a
+    TPU, so no caller has to probe; tests pass it to pin either.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     f, n = binned_t.shape
     s = stats_t.shape[0]
     s_pad = _round_up(s, 8)
@@ -302,10 +313,9 @@ def level_histogram_pallas(
     return out[:f, :n_nodes, :n_bins, :s].reshape(f, n_nodes * n_bins, s)
 
 
-# registered behind the shared kernel capability registry (r21):
-# selection stays the historical SNTC_TREE_HIST resolver above, but the
-# fit-side kernel now shares the serve tier's registry ⇔ docs ⇔ tests
-# drift check and the sntc_kernel_* accounting
+# registered in the kernel capability registry for its guard, twin and
+# smoke case (the registry <-> docs <-> tests drift check, chip_smoke.py's
+# twins); selection is tree_hist_impl above, not the serve tier's switch
 from sntc_tpu.kernels.registry import KernelSpec, register_kernel  # noqa: E402
 
 def _smoke_case(rows: int):
@@ -351,7 +361,6 @@ register_kernel(
         tolerance="<=1e-5 rel f32 (three exact bf16 terms, f32 sums)",
         fallback="XLA segment_sum level histogram (ops/histogram.py)",
         env="SNTC_TREE_HIST",
-        resolver=_resolve_tree_hist,
         smoke_case=_smoke_case,
     )
 )

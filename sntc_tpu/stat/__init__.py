@@ -53,7 +53,7 @@ from sntc_tpu.ops.histogram import (
     binned_contingency_onehot,
     chi_square,
 )
-from sntc_tpu.ops.pallas_histogram import resolve_hist_impl
+from sntc_tpu.ops.pallas_histogram import tree_hist_impl
 from sntc_tpu.parallel.collectives import (
     make_tree_aggregate,
     shard_batch,
@@ -208,18 +208,15 @@ class ChiSquareTest:
         binned = np.stack(cols, axis=1).astype(np.int32)
         n_bins = max(cards)
         xs, ys, w = shard_batch(mesh, binned, y_idx.astype(np.int32))
-        on_tpu = jax.default_backend() == "tpu"
-        impl = resolve_hist_impl(1, n_bins, mesh)
-        agg = _contingency_count_agg(
-            mesh, n_bins, len(classes), impl, not on_tpu
-        )
+        impl = tree_hist_impl(1, n_bins, mesh)
+        agg = _contingency_count_agg(mesh, n_bins, len(classes), impl)
         observed = np.asarray(agg(xs, ys, w))
         stats, pvals, dofs = chi_square(observed)
         return _test_frame(stats, pvals, dofs, flatten)
 
 
 @lru_cache(maxsize=None)
-def _contingency_count_agg(mesh, n_bins, n_classes, impl, interpret):
+def _contingency_count_agg(mesh, n_bins, n_classes, impl):
     """Same impl dispatch as ``chisq_selector._contingency_agg``: the
     one-hot MXU kernel on TPU (scatter-adds serialize there),
     ``segment_sum`` elsewhere."""
@@ -227,8 +224,7 @@ def _contingency_count_agg(mesh, n_bins, n_classes, impl, interpret):
     def contingency(binned, ys, w):
         if impl == "pallas":
             return binned_contingency_onehot(
-                binned, ys, w, n_bins=n_bins, n_classes=n_classes,
-                interpret=interpret,
+                binned, ys, w, n_bins=n_bins, n_classes=n_classes
             )
         return binned_contingency(
             binned, ys, w, n_bins=n_bins, n_classes=n_classes

@@ -15,8 +15,7 @@ the whole matrix in interpret mode on CPU:
   bitwise on the XLA path with zero quarantines/strikes, host-level
   AND inside a fused trace (where the segment must recompile on pure
   XLA, not fall to the eager host path);
-* ``tree_hist`` selection semantics preserved through the registry
-  reroute (satellite: behavior-preserving);
+* ``tree_hist`` selection: the table of ``tree_hist_impl``;
 * the registry ⇔ docs ⇔ tests drift check
   (``scripts/check_kernel_registry.py``) wired tier-1.
 """
@@ -402,30 +401,48 @@ def test_registry_selection_and_guards(monkeypatch):
     assert not pad_fits_pallas(1 << 20, 1 << 10)
 
 
-def test_tree_hist_selection_preserved_through_registry(monkeypatch):
-    """Satellite regression pin: routing SNTC_TREE_HIST through the
-    registry must not change a single selection decision."""
-    from sntc_tpu.ops.pallas_histogram import (
-        _resolve_tree_hist,
-        resolve_hist_impl,
-    )
+@pytest.mark.parametrize("backend,env,on_the_kernel", [
+    ("cpu", None, False),  # the default off a TPU: the XLA segment_sum
+    ("tpu", None, True),  # the default on the chip
+    ("cpu", "pallas", True),  # asked for by name: the rehearsal, the twins
+    ("tpu", "segment", False),  # the operator's way back to the twin
+])
+def test_tree_hist_impl_table(monkeypatch, backend, env, on_the_kernel):
+    """The one selection function of the fit's histogram: backend x
+    ``SNTC_TREE_HIST`` x mesh x width -> implementation, and the counter
+    each verdict bumps (``tree_hist_roofline`` reads the first)."""
+    from sntc_tpu.obs.metrics import registry
+    from sntc_tpu.ops.pallas_histogram import tree_hist_impl
 
-    cases = [(8, 32, None), (8, 32, object()), (1 << 14, 128, object())]
-    for env in (None, "pallas", "segment"):
-        if env is None:
-            monkeypatch.delenv("SNTC_TREE_HIST", raising=False)
-        else:
-            monkeypatch.setenv("SNTC_TREE_HIST", env)
-        for n_nodes, n_bins, mesh in cases:
-            assert resolve_hist_impl(n_nodes, n_bins, mesh) == (
-                _resolve_tree_hist(n_nodes, n_bins, mesh)
-            )
-    # on CPU the default stays segment; guard overflow forces segment
-    monkeypatch.delenv("SNTC_TREE_HIST", raising=False)
-    assert resolve_hist_impl(8, 32, object()) == "segment"
-    monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
-    assert resolve_hist_impl(1 << 14, 128, object()) == "segment"
-    assert resolve_hist_impl(8, 32, object()) == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if env is None:
+        monkeypatch.delenv("SNTC_TREE_HIST", raising=False)
+    else:
+        monkeypatch.setenv("SNTC_TREE_HIST", env)
+
+    def counts():
+        return (
+            registry().get("sntc_kernel_dispatch_total",
+                           kernel="tree_hist", impl="pallas") or 0,
+            registry().get("sntc_kernel_fallback_total",
+                           kernel="tree_hist", reason="segment") or 0,
+        )
+
+    # (nodes, bins, mesh): the cell's deepest level, the guard's edge on
+    # both sides, a freak width, and no mesh to map the kernel over
+    for n_nodes, n_bins, mesh, admitted in [
+        (8, 32, object(), True),
+        (128, 32, object(), True),
+        (256, 32, object(), False),
+        (1 << 14, 128, object(), False),
+        (8, 32, None, False),
+    ]:
+        want = "pallas" if on_the_kernel and admitted else "segment"
+        dispatched, fell_back = counts()
+        assert tree_hist_impl(n_nodes, n_bins, mesh) == want
+        assert counts() == (
+            dispatched + (want == "pallas"), fell_back + (want == "segment")
+        )
 
 
 def test_probed_peaks_sources(monkeypatch):

@@ -298,12 +298,12 @@ def test_rf_identical_forest_under_pallas_hist(mesh8, monkeypatch):
     )
 
 
-def test_mixed_pallas_segment_levels_with_sibling(mesh8, monkeypatch):
-    """Depth deep enough that the widest levels overflow the pallas VMEM
-    gate and fall back to segment_sum while shallow levels keep the MXU
-    kernel — the exact mixed regime a real chip hits — with sibling
-    subtraction auto-gated per level (engages only where the NEXT level
-    is pallas).  The grown forest must equal the all-segment one.
+def test_forest_deeper_than_the_guard_shrinks_its_group(mesh8, monkeypatch):
+    """Depth deep enough that the widest level (256 nodes x 32 bins)
+    overflows the kernel's guard: under the kernel the node group is cut
+    to the guard (128) and the level runs in two kernel passes, never on
+    ``segment_sum``, with sibling subtraction on every level below the
+    root.  The grown forest must equal the all-segment one.
 
     Exact equality is safe, not flaky: Poisson bagging weights are
     integer-valued, so every histogram cell is an exact small-int f32
@@ -312,31 +312,39 @@ def test_mixed_pallas_segment_levels_with_sibling(mesh8, monkeypatch):
     and the gain argmaxes cannot diverge."""
     from sntc_tpu.core.frame import Frame
     from sntc_tpu.models import RandomForestClassifier
-    from sntc_tpu.ops.pallas_histogram import hist_fits_pallas
+    from sntc_tpu.models.tree.grower import _level_plan
 
-    # depth 9 → level 8 has 256 nodes; 256·32 bins overflows the kernel
-    # budget, so levels 0–7 are pallas and level 8 is segment
     assert hist_fits_pallas(128, 32) and not hist_fits_pallas(256, 32)
 
     rng = np.random.default_rng(21)
     n = 800
     X = rng.normal(size=(n, 8)).astype(np.float32)
-    y = ((X[:, 0] > 0) * 2 + (X[:, 3] > 0.2)).astype(np.float64)
+    # noisy labels: the trees keep splitting down to the 256-node level
+    noise = rng.normal(size=(2, n))
+    y = ((X[:, 0] + noise[0] > 0) * 2
+         + (X[:, 3] + noise[1] > 0.2)).astype(np.float64)
     f = Frame({"features": X, "label": y})
     kw = dict(mesh=mesh8, numTrees=2, maxDepth=9, seed=0,
               featureSubsetStrategy="all")
 
     monkeypatch.setenv("SNTC_TREE_HIST", "segment")
+    assert _level_plan(2, 8, 32, 4, 9, mesh8) == (
+        "segment", 1 << 15, (False,) * 9
+    )
     m_seg = RandomForestClassifier(**kw).fit(f)
     monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
-    m_mix = RandomForestClassifier(**kw).fit(f)
-
-    np.testing.assert_array_equal(
-        m_mix.forest.feature, m_seg.forest.feature
+    assert _level_plan(2, 8, 32, 4, 9, mesh8) == (
+        "pallas", 128, (True,) * 8 + (False,)
     )
-    np.testing.assert_allclose(
-        m_mix.forest.leaf_stats, m_seg.forest.leaf_stats,
-        rtol=1e-5, atol=1e-5,
+    m_pal = RandomForestClassifier(**kw).fit(f)
+
+    # the 256-node level is really there, and splits
+    assert (m_seg.forest.feature[:, 255:511] >= 0).any()
+    np.testing.assert_array_equal(
+        m_pal.forest.feature, m_seg.forest.feature
+    )
+    np.testing.assert_array_equal(
+        m_pal.forest.leaf_stats, m_seg.forest.leaf_stats
     )
 
 
@@ -391,7 +399,7 @@ def test_kernel_compiles_for_the_chip(v5e_chip, f, s, n_nodes, n_bins):
 
     compiled = jax.jit(
         lambda bt, ni, st, w: level_histogram_pallas(
-            bt, ni, st, w, n_nodes=n_nodes, n_bins=n_bins
+            bt, ni, st, w, n_nodes=n_nodes, n_bins=n_bins, interpret=False
         )
     ).lower(
         sds((f, n), jnp.int32), sds((n,), jnp.int32),

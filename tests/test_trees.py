@@ -554,17 +554,60 @@ def test_gbt_regressor_validated_early_stop(mesh8):
     assert m.numTrees < 60
 
 
+@pytest.mark.parametrize("backend,shapes,mesh,want", [
+    # the benchmark cell on the chip (T 20, F 40, 32 bins, S 15, depth
+    # 5): 2 GiB gives 256 nodes a group, the guard halves it; sibling
+    # histograms kept for levels 0-3, so the kernel histograms 1, 1, 2,
+    # 4, 8 nodes: the five calls of the ledger's breakdown
+    ("tpu", (20, 40, 32, 15, 5), "mesh",
+     ("pallas", 128, (True, True, True, True, False))),
+    # the same fit off a TPU: segment_sum, the budget's group, no siblings
+    ("cpu", (20, 40, 32, 15, 5), "mesh", ("segment", 256, (False,) * 5)),
+    # the kernel runs per shard of a mesh: none given, none taken
+    ("tpu", (20, 40, 32, 15, 5), None, ("segment", 256, (False,) * 5)),
+    # the guard's edge: 130 nodes x 63 bins fit the kernel, 128 x 64 do not
+    ("tpu", (1, 8, 63, 3, 9), "mesh", ("pallas", 128, (True,) * 8 + (False,))),
+    ("tpu", (1, 8, 64, 3, 9), "mesh", ("pallas", 64, (True,) * 8 + (False,))),
+    # wide histograms (120 MB a node): the budget cuts the group to 2, and
+    # a level's histogram is kept only while it fits 1 GiB (8 nodes)
+    ("tpu", (100, 78, 256, 15, 6), "mesh",
+     ("pallas", 2, (True, True, True, True, False, False))),
+])
+def test_level_plan(monkeypatch, backend, shapes, mesh, want):
+    """The fit's one decision point: implementation, node group and
+    sibling gate from the shapes, the mesh and the backend."""
+    import jax
+
+    from sntc_tpu.models.tree.grower import LevelPlan, _level_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.delenv("SNTC_TREE_HIST", raising=False)
+    plan = _level_plan(*shapes, mesh and object())
+    assert plan == LevelPlan(*want)
+    hash(plan)  # a static jit argument
+
+
+def _forest_arrays(model):
+    fo = model.forest
+    return fo.feature.copy(), fo.threshold.copy(), fo.leaf_stats.copy()
+
+
+def _assert_same_forest(a, b):
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+
+
 @pytest.mark.parametrize("subset", ["all", "sqrt"])
 def test_node_group_batching_identical_forest(mesh8, monkeypatch, subset):
     """The memory-bounded node-group path (Spark maxMemoryInMB analog)
     must produce EXACTLY the forest the single-pass path grows — the
-    grouping is a pure execution-schedule choice.  ``group`` is resolved
-    in grow_forest and passed as a STATIC jit arg, so the env override
-    retraces rather than silently reusing the cached single-pass program
-    (both branches — shared fmask=None and the per-group fmask slices of
-    'sqrt' — are exercised)."""
+    grouping is a pure execution-schedule choice.  The group is part of
+    ``_level_plan``'s result, a STATIC jit arg of ``_grow_fused``, so a
+    smaller budget retraces rather than silently reusing the cached
+    single-pass program (both branches — shared fmask=None and the
+    per-group fmask slices of 'sqrt' — are exercised)."""
     from sntc_tpu.models import RandomForestClassifier
-    from sntc_tpu.models.tree.grower import node_group_size
+    from sntc_tpu.models.tree import grower
 
     rng = np.random.default_rng(3)
     n = 4000
@@ -573,22 +616,20 @@ def test_node_group_batching_identical_forest(mesh8, monkeypatch, subset):
     f = Frame({"features": X, "label": y})
 
     def grow():
-        m = RandomForestClassifier(
+        return _forest_arrays(RandomForestClassifier(
             mesh=mesh8, numTrees=4, maxDepth=6, seed=0,
             featureSubsetStrategy=subset,
-        ).fit(f)
-        fo = m.forest
-        return fo.feature.copy(), fo.threshold.copy(), fo.leaf_stats.copy()
+        ).fit(f))
 
-    monkeypatch.delenv("SNTC_TREE_NODE_GROUP_MB", raising=False)
+    def group():
+        return grower._level_plan(4, 12, 32, 4, 6, mesh8).group
+
     base = grow()
-    assert node_group_size(4, 12, 32, 4) >= 32  # default: one group
+    assert group() >= 32  # default: one group
 
-    monkeypatch.setenv("SNTC_TREE_NODE_GROUP_MB", "0.2")
-    assert node_group_size(4, 12, 32, 4) < 32  # forces multiple groups
-    grouped = grow()
-    for a, b in zip(base, grouped):
-        np.testing.assert_array_equal(a, b)
+    monkeypatch.setattr(grower, "_NODE_GROUP_BYTES", 200 * 1024)
+    assert group() < 32  # forces multiple groups
+    _assert_same_forest(base, grow())
 
 
 @pytest.mark.parametrize("subset", ["all", "sqrt"])
@@ -598,9 +639,12 @@ def test_sibling_subtraction_identical_forest(mesh8, monkeypatch, subset):
     Poisson bagging weights every histogram cell is an exact small-int
     f32 sum, so the subtraction is exact and the forests are
     bit-identical — including under memory-bounded node grouping (the
-    subtraction path slices the SAME parent histograms per group)."""
+    subtraction path slices the SAME parent histograms per group).
+    Run as the chip runs it, under the Pallas kernel (here through the
+    interpreter): kernel + sibling (the default rule) against the kernel
+    with the gate closed against ``segment_sum``."""
     from sntc_tpu.models import RandomForestClassifier
-    from sntc_tpu.models.tree.grower import node_group_size
+    from sntc_tpu.models.tree import grower
 
     rng = np.random.default_rng(5)
     n = 4000
@@ -609,37 +653,42 @@ def test_sibling_subtraction_identical_forest(mesh8, monkeypatch, subset):
     f = Frame({"features": X, "label": y})
 
     def grow():
-        m = RandomForestClassifier(
+        return _forest_arrays(RandomForestClassifier(
             mesh=mesh8, numTrees=4, maxDepth=6, seed=0,
             featureSubsetStrategy=subset,
-        ).fit(f)
-        fo = m.forest
-        return fo.feature.copy(), fo.threshold.copy(), fo.leaf_stats.copy()
+        ).fit(f))
 
-    monkeypatch.setenv("SNTC_TREE_SIBLING", "0")
-    direct = grow()
-    monkeypatch.setenv("SNTC_TREE_SIBLING", "1")  # force (CPU default: off)
+    def plan():
+        return grower._level_plan(4, 12, 32, 4, 6, mesh8)
+
+    monkeypatch.setenv("SNTC_TREE_HIST", "segment")
+    segment = grow()
+    monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
+    with monkeypatch.context() as gate:
+        gate.setattr(grower, "_SIBLING_BYTES", 0)
+        assert plan() == ("pallas", 128, (False,) * 6)
+        direct = grow()
+    assert plan() == ("pallas", 128, (True,) * 5 + (False,))
     sibling = grow()
-    for a, b in zip(direct, sibling):
-        np.testing.assert_array_equal(a, b)
+    _assert_same_forest(direct, sibling)
+    _assert_same_forest(segment, sibling)
 
     # grouping invariance on the subtraction path itself: the budget must
     # land group in [2, 32) — group=1 would disable sibling subtraction
     # entirely and make this leg vacuous (direct == direct)
-    monkeypatch.setenv("SNTC_TREE_NODE_GROUP_MB", "0.5")
-    assert 2 <= node_group_size(4, 12, 32, 4) < 32
-    sibling_grouped = grow()
-    for a, b in zip(sibling, sibling_grouped):
-        np.testing.assert_array_equal(a, b)
+    monkeypatch.setattr(grower, "_NODE_GROUP_BYTES", 512 * 1024)
+    assert 2 <= plan().group < 32 and any(plan().keep_hists)
+    _assert_same_forest(sibling, grow())
 
 
 def test_sibling_subtraction_regression_signed_stats(mesh8, monkeypatch):
     """Variance stats ([w, wy, wy²]) are signed in wy — the sibling path
     must NOT clamp derived siblings at zero (a clamp would zero negative
     residual sums and corrupt every TPU GBT/regressor fit).  Integer-
-    valued targets keep all sums exact, so direct and sibling forests
-    are bit-identical."""
+    valued targets keep all sums exact, so the kernel's direct and
+    sibling forests and the ``segment_sum`` one are bit-identical."""
     from sntc_tpu.models import RandomForestRegressor
+    from sntc_tpu.models.tree import grower
 
     rng = np.random.default_rng(13)
     n = 3000
@@ -649,50 +698,67 @@ def test_sibling_subtraction_regression_signed_stats(mesh8, monkeypatch):
     f = Frame({"features": X, "label": y})
 
     def grow():
-        m = RandomForestRegressor(
+        return _forest_arrays(RandomForestRegressor(
             mesh=mesh8, numTrees=3, maxDepth=5, seed=0,
             featureSubsetStrategy="all",
-        ).fit(f)
-        fo = m.forest
-        return fo.feature.copy(), fo.threshold.copy(), fo.leaf_stats.copy()
+        ).fit(f))
 
-    monkeypatch.setenv("SNTC_TREE_SIBLING", "0")
-    direct = grow()
-    monkeypatch.setenv("SNTC_TREE_SIBLING", "1")
+    monkeypatch.setenv("SNTC_TREE_HIST", "segment")
+    segment = grow()
+    monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
+    with monkeypatch.context() as gate:
+        gate.setattr(grower, "_SIBLING_BYTES", 0)
+        direct = grow()
+    assert any(grower._level_plan(3, 8, 32, 3, 5, mesh8).keep_hists)
     sibling = grow()
-    for a, b in zip(direct, sibling):
-        np.testing.assert_array_equal(a, b)
+    _assert_same_forest(direct, sibling)
+    _assert_same_forest(segment, sibling)
     # the planted negative-mean leaves really exist (guards vacuity)
     leaf_wy = direct[2][..., 1][direct[0] == -1]
     assert (leaf_wy < 0).any(), "no negative wy leaf — test lost its teeth"
 
 
-def test_label_fused_scatter_identical_forest(mesh8, monkeypatch):
-    """The label-fused scalar scatter (default for classification) must
-    produce EXACTLY the forest of the generic vector segment_sum path —
-    both accumulate the same integer-valued weights in row order, so the
-    comparison is bit-exact.  SNTC_TREE_LABEL_FUSED=0 is the field
-    kill-switch that forces the generic path."""
-    from sntc_tpu.models import RandomForestClassifier
+def test_label_fused_scatter_identical_forest(mesh8):
+    """The label-fused scalar scatter (what a classification fit on
+    ``segment_sum`` runs: the caller passes ``row_label`` / ``row_weight``)
+    must produce EXACTLY the forest of the generic vector segment_sum
+    path (the same call without them) — both accumulate the same
+    integer-valued weights in row order, so the comparison is
+    bit-exact."""
+    import jax.numpy as jnp
+
+    from sntc_tpu.models.tree.grower import (
+        grow_forest,
+        make_bagging_weights,
+    )
+    from sntc_tpu.models.tree.random_forest import _one_hot_stats
+    from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
+    from sntc_tpu.parallel.collectives import shard_batch
 
     rng = np.random.default_rng(9)
     n = 3000
     X = rng.normal(size=(n, 9)).astype(np.float32)
-    y = ((X[:, 0] > -0.5) * 2 + (X[:, 2] > 0.4)).astype(np.float64)
-    f = Frame({"features": X, "label": y})
+    y = ((X[:, 0] > -0.5) * 2 + (X[:, 2] > 0.4)).astype(np.int32)
+    edges = quantile_bin_edges(X, max_bins=32, seed=0)
+    xs, ys, ws = shard_batch(mesh8, X, y)
+    binned = bin_features(xs, jnp.asarray(edges))
+    row_stats = _one_hot_stats(ys, ws, 4)
+    w_trees = make_bagging_weights(
+        np.random.default_rng(0), True, 1.0, 3, xs.shape[0], mesh8
+    )
 
-    def grow():
-        m = RandomForestClassifier(
-            mesh=mesh8, numTrees=3, maxDepth=5, seed=0
-        ).fit(f)
-        fo = m.forest
-        return fo.feature.copy(), fo.threshold.copy(), fo.leaf_stats.copy()
+    def grow(**label_kwargs):
+        fo = grow_forest(
+            binned, row_stats, w_trees, edges, n_bins=32, max_depth=5,
+            min_instances_per_node=1.0, min_info_gain=0.0, subset_k=3,
+            impurity="gini", seed=0, mesh=mesh8, **label_kwargs,
+        )
+        return fo.feature, fo.threshold, fo.leaf_stats
 
-    fused = grow()
-    monkeypatch.setenv("SNTC_TREE_LABEL_FUSED", "0")
+    fused = grow(row_label=ys, row_weight=ws)
     generic = grow()
-    for a, b in zip(fused, generic):
-        np.testing.assert_array_equal(a, b)
+    assert (fused[0] >= 0).sum() > 10  # real trees, not stumps
+    _assert_same_forest(fused, generic)
 
 
 def test_gbt_regressor_absolute_loss_wide_range_targets(mesh8):
