@@ -24,8 +24,8 @@ import numpy as np
 from sntc_tpu.core.base import Estimator, Model
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
-from sntc_tpu.feature.selection import select_features_by_mode
-from sntc_tpu.obs import module_of, span
+from sntc_tpu.feature.selection import select_features_by_mode, take_columns
+from sntc_tpu.obs import inc, module_of, span
 from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
 from sntc_tpu.ops.histogram import (
     binned_contingency,
@@ -130,7 +130,14 @@ class ChiSqSelector(_SelectorParams, Estimator):
     def _fit(self, frame: Frame) -> "ChiSqSelectorModel":
         mesh = self._mesh or get_default_mesh()
         with span("chi2.extract", module=_MODULE):
-            X = frame[self.getFeaturesCol()].astype(np.float32)
+            col = frame[self.getFeaturesCol()]
+            # the frame's own matrix when it is float32 already (the
+            # assembler's is): the upload then keys the device cache on
+            # an array that outlives this stage, as a re-fit wants
+            X = col.astype(np.float32, copy=False)
+            if X is not col:
+                inc("sntc_feature_copy_bytes_total", X.nbytes,
+                    site="chi2.extract")
             y = frame[self.getLabelCol()]
         stats, p_values = chi2_scores(X, y, mesh, self.getMaxBins())
 
@@ -166,6 +173,7 @@ class ChiSqSelectorModel(_SelectorParams, Model):
         return m
 
     def transform(self, frame: Frame) -> Frame:
-        X = frame[self.getFeaturesCol()]
-        out = np.ascontiguousarray(X[:, self.selected_features])
+        out = take_columns(
+            frame[self.getFeaturesCol()], self.selected_features
+        )
         return frame.with_column(self.getOutputCol(), out)

@@ -27,6 +27,7 @@ import numpy as np
 from sntc_tpu.core.base import Estimator, Model, Transformer
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
+from sntc_tpu.feature.selection import take_columns
 
 
 class _OheParams:
@@ -126,9 +127,7 @@ class VectorSlicer(Transformer):
             raise ValueError(
                 f"indices out of range for vector width {X.shape[1]}"
             )
-        return frame.with_column(
-            self.getOutputCol(), np.ascontiguousarray(X[:, idx])
-        )
+        return frame.with_column(self.getOutputCol(), take_columns(X, idx))
 
 
 class ElementwiseProduct(Transformer):

@@ -10,6 +10,9 @@ descending, index ascending on ties) and keep
   * ``fpr``            — every feature with ``p < threshold``,
   * ``fdr``            — Benjamini-Hochberg step-up at ``threshold``,
   * ``fwe``            — Bonferroni: ``p < threshold / F``.
+
+And the package's one column-take, :func:`take_columns`, which the
+selector models and ``VectorSlicer`` apply their selection with.
 """
 
 from __future__ import annotations
@@ -17,6 +20,45 @@ from __future__ import annotations
 from typing import List
 
 import numpy as np
+
+from sntc_tpu.obs import inc, module_of, span
+
+_MODULE = module_of(__name__)
+
+
+def take_columns(X, idx) -> np.ndarray:
+    """``X[:, idx]`` as a fresh host matrix, copied the way ``X`` lies.
+
+    The assembler hands on a feature-major matrix (a ``[F, N]`` base seen
+    as its ``(N, F)`` transpose): its columns are whole contiguous rows of
+    the base, so taking them is ``len(idx)`` memcpys and the result stays
+    feature-major, where a fancy index into row-major misses a cache line
+    on every element.  A row-major matrix takes ``np.take`` along axis 1;
+    anything else (a strided slice, a device-resident column) the plain
+    fancy index.  Same values, dtype and shape in every branch — only the
+    strides of the result follow the input's.  The branch taken is the
+    ``layout`` of the ``select.take`` span and of the bytes counted into
+    ``sntc_feature_copy_bytes_total``.
+    """
+    idx = np.asarray(idx, np.intp)
+    if not (isinstance(X, np.ndarray) and X.ndim == 2):
+        layout = "generic"
+    elif X.flags.c_contiguous:
+        layout = "columns"
+    elif X.flags.f_contiguous:
+        layout = "base_rows"
+    else:
+        layout = "generic"
+    with span("select.take", layout=layout, module=_MODULE):
+        if layout == "base_rows":
+            out = np.take(X.T, idx, axis=0).T
+        elif layout == "columns":
+            out = np.take(X, idx, axis=1)
+        else:
+            out = np.ascontiguousarray(X[:, idx])
+    inc("sntc_feature_copy_bytes_total", out.nbytes,
+        site="select.take", layout=layout)
+    return out
 
 
 def select_features_by_mode(

@@ -34,7 +34,7 @@ import numpy as np
 from sntc_tpu.core.base import Estimator, Model
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
-from sntc_tpu.feature.selection import select_features_by_mode
+from sntc_tpu.feature.selection import select_features_by_mode, take_columns
 from sntc_tpu.parallel.collectives import make_tree_aggregate, shard_batch
 from sntc_tpu.parallel.context import get_default_mesh
 
@@ -260,6 +260,7 @@ class UnivariateFeatureSelectorModel(_UfsParams, Model):
         return m
 
     def transform(self, frame: Frame) -> Frame:
-        X = frame[self.getFeaturesCol()]
-        out = np.ascontiguousarray(X[:, self.selected_features])
+        out = take_columns(
+            frame[self.getFeaturesCol()], self.selected_features
+        )
         return frame.with_column(self.getOutputCol(), out)

@@ -17,7 +17,7 @@ import numpy as np
 from sntc_tpu.core.base import Transformer
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
-from sntc_tpu.obs import module_of, span
+from sntc_tpu.obs import inc, module_of, span
 
 _MODULE = module_of(__name__)
 
@@ -97,6 +97,8 @@ class VectorAssembler(Transformer):
                         else:
                             X[:, off : off + w] = col
                         off += w
+            inc("sntc_feature_copy_bytes_total", X.nbytes,
+                site="assemble.stack")
 
             invalid = None
             if mode != "keep":

@@ -254,6 +254,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         type=COUNTER, labels=("tenant",),
         help="Bytes materialized device→host by fused finalizes.",
     ),
+    # -- host copies of a whole vector column on the fit path (feature/) ----
+    "sntc_feature_copy_bytes_total": dict(
+        type=COUNTER, labels=("site", "layout"),
+        help="Bytes a feature stage wrote to materialise a whole vector "
+        "column on the host: site=assemble.stack (the assembler's "
+        "float32 matrix), chi2.extract (only when the selector has to "
+        "cast its input), select.take (feature.selection.take_columns; "
+        "layout=base_rows | columns | generic is the copy it chose from "
+        "the input's strides).",
+    ),
     # -- collective layer over the mesh substrate (parallel/mesh, r22) ------
     "sntc_collective_dispatches_total": dict(
         type=COUNTER, labels=("op", "axis"),

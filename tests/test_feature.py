@@ -166,6 +166,261 @@ def test_chisq_selector_fpr_mode(mesh8):
 
 
 
+# ---------------- take_columns: the package's one column-take ----------------
+
+def _copy_bytes(**labels):
+    from sntc_tpu.obs import registry
+
+    return registry().get("sntc_feature_copy_bytes_total", **labels) or 0
+
+
+def _matrix_in(layout):
+    """An ``(N, 6)`` float32 matrix laid out as named, and the branch
+    ``take_columns`` has to choose for it from what it can observe."""
+    base = np.arange(6 * 50, dtype=np.float32).reshape(6, 50)  # [F, N]
+    row_major = np.ascontiguousarray(base.T)
+    if layout == "jax_array":
+        import jax.numpy as jnp
+
+        return jnp.asarray(row_major), "generic"
+    return {
+        # the assembler's fast path: a feature-major base, transposed
+        "feature_major": (base.T, "base_rows"),
+        "row_major": (row_major, "columns"),
+        "strided_rows": (row_major[::2], "generic"),
+        "feature_major_strided_rows": (base.T[::2], "generic"),
+        # contiguous both ways: either copy is the same memcpy
+        "n_by_1": (base.T[:, :1], "columns"),
+        "zero_rows": (base.T[:0], "columns"),
+    }[layout]
+
+
+@pytest.mark.parametrize(
+    "selection", [[0, 2, 5], [5, 0, 2], [3, 3, 0], []],
+    ids=["sorted", "unsorted", "repeated", "empty"],
+)
+@pytest.mark.parametrize("layout", [
+    "feature_major", "row_major", "strided_rows",
+    "feature_major_strided_rows", "n_by_1", "zero_rows", "jax_array",
+])
+def test_take_columns_equals_the_fancy_index_in_every_layout(
+    layout, selection
+):
+    from sntc_tpu.feature.selection import take_columns
+    from sntc_tpu.obs import disable_tracing, enable_tracing
+
+    X, branch = _matrix_in(layout)
+    idx = [0] * len(selection) if X.shape[1] == 1 else selection
+    want = np.ascontiguousarray(np.asarray(X)[:, np.asarray(idx, np.intp)])
+    before = _copy_bytes(site="select.take", layout=branch)
+    t = enable_tracing(capacity=16)
+    try:
+        got = take_columns(X, idx)
+        (taken,) = [s for s in t.spans() if s["name"] == "select.take"]
+    finally:
+        disable_tracing()
+    assert isinstance(got, np.ndarray)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+    assert taken["attrs"] == {"layout": branch, "module": "feature"}
+    assert (_copy_bytes(site="select.take", layout=branch) - before
+            == want.nbytes)
+    if branch == "base_rows" and len(idx) > 1:
+        # the result stays feature-major: whole rows of a new base
+        assert got.flags.f_contiguous and not got.flags.c_contiguous
+    assert not np.shares_memory(got, np.asarray(X))
+
+
+def test_selector_models_and_slicer_share_the_one_take():
+    from sntc_tpu.feature import (
+        ChiSqSelectorModel,
+        UnivariateFeatureSelectorModel,
+        VectorSlicer,
+    )
+
+    X, _ = _matrix_in("feature_major")
+    f = Frame({"features": X})
+    before = _copy_bytes(site="select.take", layout="base_rows")
+    outs = [
+        ChiSqSelectorModel(selected_features=[1, 4]).transform(f)[
+            "selectedFeatures"],
+        UnivariateFeatureSelectorModel(selected_features=[1, 4]).transform(f)[
+            "selectedFeatures"],
+        VectorSlicer(indices=[1, 4]).transform(f)["sliced"],
+    ]
+    for out in outs:
+        np.testing.assert_array_equal(out, X[:, [1, 4]])
+        assert out.flags.f_contiguous
+    assert (_copy_bytes(site="select.take", layout="base_rows") - before
+            == 3 * X.shape[0] * 2 * 4)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_chisq_selector_fit_copies_only_to_cast(mesh8, monkeypatch, dtype):
+    """A float32 column reaches ``chi2_scores`` (and so ``shard_batch``) as
+    the frame's own array; a float64 one as a float32 cast of it."""
+    from sntc_tpu.feature import chisq_selector
+
+    rng = np.random.default_rng(5)
+    y = rng.integers(0, 3, size=500)
+    col = (rng.normal(size=(4, 500)) + y).astype(dtype).T  # feature-major
+    seen = []
+    scores = chisq_selector.chi2_scores
+    monkeypatch.setattr(
+        chisq_selector, "chi2_scores",
+        lambda X, *a, **k: seen.append(X) or scores(X, *a, **k),
+    )
+    before = _copy_bytes(site="chi2.extract")
+    ChiSqSelector(mesh=mesh8, numTopFeatures=2, labelCol="label").fit(
+        Frame({"features": col, "label": y.astype(np.float64)})
+    )
+    (X,) = seen
+    assert X.dtype == np.float32
+    np.testing.assert_array_equal(X, col.astype(np.float32))
+    if dtype is np.float32:
+        assert X is col
+        assert _copy_bytes(site="chi2.extract") == before
+    else:
+        assert not np.shares_memory(X, col)
+        assert _copy_bytes(site="chi2.extract") - before == 500 * 4 * 4
+
+
+def test_chisq_selector_refit_on_one_frame_finds_its_matrix_on_the_device(
+    mesh8,
+):
+    """What ``copy=False`` decides for the device cache: the upload is
+    keyed on the frame's own column, so a second fit on the same frame
+    re-uploads the labels and the row weights, not the matrix."""
+    from sntc_tpu.obs import registry
+
+    def uploaded():
+        return registry().get("sntc_transfer_upload_bytes_total") or 0
+
+    rng = np.random.default_rng(6)
+    n = 40_000  # 8 columns of float32: over the cache's 1 MiB floor
+    y = rng.integers(0, 3, size=n)
+    col = (rng.normal(size=(8, n)) + y).astype(np.float32).T
+    f = Frame({"features": col, "label": y.astype(np.float64)})
+    est = ChiSqSelector(mesh=mesh8, numTopFeatures=2, labelCol="label")
+    b0 = uploaded()
+    first = est.fit(f)
+    b1 = uploaded()
+    again = est.fit(f)
+    b2 = uploaded()
+    assert again.selected_features == first.selected_features
+    # the first fit's uploads less the second's are the (padded) matrix
+    assert 0 < b2 - b1 < col.nbytes
+    assert (b1 - b0) - (b2 - b1) >= col.nbytes
+
+
+# ---- the benchmark's four-stage pipeline, from either assembler layout ----
+
+_PIPELINE_ROWS = 4_000
+
+
+def _four_stage_fit(mesh, assembler_cls):
+    """One fit of ``benchmark/estimators/rf.py``'s pipeline on a clean
+    frame: the fitted product, the bytes it added to
+    ``sntc_feature_copy_bytes_total`` and the layouts its takes chose."""
+    from sntc_tpu.core.base import Pipeline
+    from sntc_tpu.data.schema import CICIDS2017_FEATURES
+    from sntc_tpu.data.synth import generate_frame
+    from sntc_tpu.models import RandomForestClassifier
+    from sntc_tpu.obs import disable_tracing, enable_tracing, registry
+
+    def counted():
+        snap = registry().snapshot().get(
+            "sntc_feature_copy_bytes_total", {"series": []}
+        )
+        return {
+            tuple(sorted(s["labels"].items())): s["value"]
+            for s in snap["series"]
+        }
+
+    frame = generate_frame(_PIPELINE_ROWS, seed=31, dirty=False)
+    pipe = Pipeline(stages=[
+        StringIndexer(inputCol="Label", outputCol="label",
+                      handleInvalid="skip"),
+        assembler_cls(inputCols=list(CICIDS2017_FEATURES),
+                      outputCol="rawFeatures", handleInvalid="skip"),
+        ChiSqSelector(mesh=mesh, numTopFeatures=40,
+                      featuresCol="rawFeatures", labelCol="label",
+                      outputCol="features"),
+        RandomForestClassifier(mesh=mesh, numTrees=20, maxDepth=5,
+                               maxBins=32, seed=11, featuresCol="features"),
+    ])
+    before = counted()
+    t = enable_tracing(capacity=256)
+    try:
+        model = pipe.fit(frame)
+        layouts = [s["attrs"]["layout"] for s in t.spans()
+                   if s["name"] == "select.take"]
+    finally:
+        disable_tracing()
+    after = counted()
+    stages = model.getStages()
+    forest = stages[-1].forest
+    return {
+        "selected": np.asarray(stages[2].selected_features),
+        "feature": np.asarray(forest.feature),
+        "threshold": np.asarray(forest.threshold),
+        "leaf_stats": np.asarray(forest.leaf_stats),
+        "added": {k: v - before.get(k, 0) for k, v in after.items()
+                  if v != before.get(k, 0)},
+        "layouts": layouts,
+    }
+
+
+class _RowMajorAssembler(VectorAssembler):
+    """The assembler with its output forced into a C-order matrix."""
+
+    def transform(self, frame):
+        out = super().transform(frame)
+        name = self.getOutputCol()
+        return out.with_column(name, np.ascontiguousarray(out[name]))
+
+
+@pytest.fixture(scope="module")
+def four_stage_fits(mesh8):
+    return {
+        "feature_major": _four_stage_fit(mesh8, VectorAssembler),
+        "row_major": _four_stage_fit(mesh8, _RowMajorAssembler),
+    }
+
+
+def test_forest_is_the_same_from_either_assembler_layout(four_stage_fits):
+    got, want = four_stage_fits["feature_major"], four_stage_fits["row_major"]
+    assert len(got["selected"]) == 40
+    assert (got["feature"] >= 0).sum() > 20  # real trees, not stumps
+    for name in ("selected", "feature", "threshold", "leaf_stats"):
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+@pytest.mark.parametrize("layout, branch", [
+    ("feature_major", "base_rows"), ("row_major", "columns"),
+])
+def test_one_fit_counts_the_copies_it_made(four_stage_fits, layout, branch):
+    """The stack and the take, each once; nothing to obtain float32."""
+    fit = four_stage_fits[layout]
+    assert fit["layouts"] == [branch]
+    assert fit["added"] == {
+        (("site", "assemble.stack"),): _PIPELINE_ROWS * 78 * 4,
+        (("layout", branch), ("site", "select.take")):
+            _PIPELINE_ROWS * 40 * 4,
+    }
+
+
+def test_observability_doc_lists_the_take():
+    import os
+
+    doc = open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "docs", "OBSERVABILITY.md",
+    )).read()
+    assert "`sntc_feature_copy_bytes_total` | counter | layout, site" in doc
+    assert "| `select.take` | `feature/selection.py:take_columns`" in doc
+
+
 def test_chisq_selector_fdr_and_fwe_modes(mesh8):
     """fdr = Benjamini-Hochberg step-up on sorted p-values; fwe =
     Bonferroni p < fwe/F (Spark ChiSqSelector selectorType parity)."""
