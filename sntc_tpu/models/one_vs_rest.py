@@ -16,6 +16,7 @@ import numpy as np
 
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
+from sntc_tpu.obs import module_of, span
 from sntc_tpu.models.base import (
     ClassificationModel,
     ClassifierEstimator,
@@ -95,6 +96,9 @@ def _build_fused_ovr(models):
     return None
 
 
+_MODULE = module_of(__name__)
+
+
 class _OvrParams(ClassifierParams):
     parallelism = Param(
         "API parity only; inner fits already saturate the mesh",
@@ -112,8 +116,9 @@ class OneVsRest(_OvrParams, ClassifierEstimator):
         self._mesh = mesh
 
     def _fit(self, frame: Frame) -> "OneVsRestModel":
-        X, y, w = self._extract(frame)
-        k = int(y.max()) + 1
+        with span("ovr.extract", module=_MODULE):
+            X, y, w = self._extract(frame)
+            k = int(y.max()) + 1
         bin_col = f"ovr_label_{self.uid}"
         overrides = {
             "labelCol": bin_col,

@@ -41,31 +41,52 @@ from sntc_tpu.models.tree.grower import (
     resolve_feature_subset_k,
 )
 from sntc_tpu.models.tree.random_forest import _TreeEnsembleParams
+from sntc_tpu.obs import inc, module_of, span
 from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
 from sntc_tpu.parallel.collectives import shard_batch, shard_weights
 from sntc_tpu.parallel.context import get_default_mesh
+
+_MODULE = module_of(__name__)
+
+
+def _count_round(estimator: str, trees: int) -> None:
+    """One boosting round of ``trees`` trees is done (the regressor and
+    both classifier loops count here)."""
+    inc("sntc_boost_rounds_total", estimator=estimator)
+    inc("sntc_boost_trees_total", trees, estimator=estimator)
+
+
+def _variance_stats(ws, r):
+    """Variance stats ``[w, wr, wr²]`` of residuals ``r``, stacked before
+    the row axis: ``[N]`` residuals give ``[3, N]``, a one-vs-rest round's
+    ``[K, N]`` give ``[K, 3, N]``, the grower's per-tree layout (rows along
+    lanes; a ``[K, N, 3]`` array would lie tiled to 128 lanes on the TPU).
+    The one layout rule of the binary, one-vs-rest and regression loops."""
+    w = jnp.broadcast_to(ws, r.shape)
+    return jnp.stack([w, w * r, w * r * r], axis=-2)
 
 
 @jax.jit
 def _residual_stats(y_signed, ws, margin):
     """Friedman pseudo-residuals for logistic loss -> variance stats."""
     r = 2.0 * y_signed / (1.0 + jnp.exp(2.0 * y_signed * margin))
-    return jnp.stack([ws, ws * r, ws * r * r], axis=1)
+    return _variance_stats(ws, r)
 
 
 @jax.jit
 def _label_stats(y_signed, ws):
-    return jnp.stack([ws, ws * y_signed, ws * y_signed**2], axis=1)
+    """Round 0 fits the signed labels themselves."""
+    return _variance_stats(ws, y_signed)
 
 
-@partial(jax.jit, static_argnames=("max_depth",))
-def _forest_margins(X, feature, threshold, leaf_stats, *, max_depth):
-    """Per-tree mean-residual leaf values [T, N] (the vectorized
-    one-vs-rest path: tree t is class t's tree for this round)."""
-    stats = forest_leaf_stats(
-        X, feature, threshold, leaf_stats, max_depth=max_depth
-    )  # [T, N, 3]
-    return stats[..., 1] / jnp.maximum(stats[..., 0], 1e-12)
+def _leaf_values(X, forest):
+    """``[T, N]``: every row's leaf mean in each tree of a round's
+    ``forest`` (host heaps, a few KB), by the package's one walk."""
+    return forest_leaf_stats(
+        X, jnp.asarray(forest.feature), jnp.asarray(forest.threshold),
+        jnp.asarray(forest.leaf_stats), max_depth=forest.max_depth,
+        value=True,
+    )
 
 
 @partial(jax.jit, static_argnames=("num_classes",))
@@ -75,16 +96,12 @@ def _ovr_signed_labels(ys, *, num_classes):
     return (2.0 * (ys[None, :] == k) - 1.0).astype(jnp.float32)
 
 
-@jax.jit
-def _ovr_label_stats(y_signed, ws):
-    return jax.vmap(lambda ysk: _label_stats(ysk, ws))(y_signed)  # [K,N,3]
-
-
-@jax.jit
-def _ovr_residual_stats(y_signed, ws, margins):
-    return jax.vmap(
-        lambda ysk, mk: _residual_stats(ysk, ws, mk)
-    )(y_signed, margins)  # [K, N, 3]
+@partial(jax.jit, static_argnames=("num_classes",))
+def _broadcast_classes(v, *, num_classes):
+    """``[N]`` -> ``[K, N]``, rows still sharded as ``v`` is.  A module-level
+    program: one built inside the fit would be a new ``jit`` object, and so
+    a compile-cache miss, every fit."""
+    return jnp.broadcast_to(v[None], (num_classes,) + v.shape)
 
 
 def _prepare_boosting(classifier: "GBTClassifier", X, y, w, mesh):
@@ -97,7 +114,8 @@ def _prepare_boosting(classifier: "GBTClassifier", X, y, w, mesh):
     seed = classifier.getSeed()
     rate = classifier.getSubsamplingRate()
 
-    edges = quantile_bin_edges(X, max_bins=n_bins, seed=seed)
+    with span("gbt.bin_edges", module=_MODULE):
+        edges = quantile_bin_edges(X, max_bins=n_bins, seed=seed)
     xs, ys, _ = shard_batch(mesh, X, y.astype(np.int32))
     ws = shard_weights(mesh, w, xs.shape[0])
     binned = bin_features(xs, jnp.asarray(edges))
@@ -117,10 +135,11 @@ def _prepare_boosting(classifier: "GBTClassifier", X, y, w, mesh):
     def round_mask(i: int) -> np.ndarray:
         """Host [n_pad] subsample mask for boosting round ``i`` —
         per-round seeded: resume-deterministic (checkpointing)."""
-        if rate < 1.0:
-            r = np.random.default_rng(seed + 7919 * (i + 1))
-            return (r.random(xs.shape[0]) < rate).astype(np.float32)
-        return np.ones(xs.shape[0], np.float32)
+        with span("gbt.mask", round=i, module=_MODULE):
+            if rate < 1.0:
+                r = np.random.default_rng(seed + 7919 * (i + 1))
+                return (r.random(xs.shape[0]) < rate).astype(np.float32)
+            return np.ones(xs.shape[0], np.float32)
 
     return edges, xs, ys, ws, binned, grow_kwargs, round_mask
 
@@ -206,7 +225,8 @@ class GBTClassifier(_GbtParams, CheckpointParams, ClassifierEstimator):
                 )
             X_val, y_val, w_val = self._extract(frame.filter(vmask))
             frame = frame.filter(~vmask)
-        X, y, w = self._extract(frame)
+        with span("gbt.extract", module=_MODULE):
+            X, y, w = self._extract(frame)
         n, F = X.shape
         y_max = int(y.max(initial=0))
         if y_val is not None:
@@ -292,24 +312,19 @@ class GBTClassifier(_GbtParams, CheckpointParams, ClassifierEstimator):
                     start_round = n_rounds if tracker.done[0] else start_round
         stopped = False
         for m in range(start_round, n_rounds):
-            if m == 0:
-                row_stats = _label_stats(y_signed, ws)
-                tree_weight = 1.0
-            else:
-                row_stats = _residual_stats(y_signed, ws, margin)
-                tree_weight = step
-            forest = grow_forest(
-                binned, row_stats, round_weights(m), edges,
-                seed=self.getSeed() + m, mesh=mesh, **grow_kwargs,
-            )
-            contrib = _forest_margins(
-                xs,
-                jnp.asarray(forest.feature),
-                jnp.asarray(forest.threshold),
-                jnp.asarray(forest.leaf_stats),
-                max_depth=forest.max_depth,
-            )[0]
-            margin = margin + tree_weight * contrib
+            with span("gbt.round", round=m, trees=1, module=_MODULE):
+                if m == 0:
+                    row_stats = _label_stats(y_signed, ws)
+                    tree_weight = 1.0
+                else:
+                    row_stats = _residual_stats(y_signed, ws, margin)
+                    tree_weight = step
+                forest = grow_forest(
+                    binned, row_stats[None], round_weights(m), edges,
+                    seed=self.getSeed() + m, mesh=mesh, **grow_kwargs,
+                )
+                margin = margin + tree_weight * _leaf_values(xs, forest)[0]
+            _count_round("gbt_classifier", 1)
             features.append(forest.feature[0])
             thresholds.append(forest.threshold[0])
             leaves.append(forest.leaf_stats[0])
@@ -317,13 +332,7 @@ class GBTClassifier(_GbtParams, CheckpointParams, ClassifierEstimator):
             counts.append(forest.count[0])
             weights.append(tree_weight)
             if val_col:
-                contrib_val = _forest_margins(
-                    X_val_j,
-                    jnp.asarray(forest.feature),
-                    jnp.asarray(forest.threshold),
-                    jnp.asarray(forest.leaf_stats),
-                    max_depth=forest.max_depth,
-                )[0]
+                contrib_val = _leaf_values(X_val_j, forest)[0]
                 margin_val = margin_val + tree_weight * np.asarray(
                     contrib_val, np.float64
                 )
@@ -546,7 +555,7 @@ def fit_gbt_ovr_vectorized(
 
     The class axis rides the grower's tree axis: every round grows K trees
     over the SAME binned features with per-class residual stats
-    (``row_stats[K, N, 3]``) — K× fewer level passes, host syncs, and
+    (``row_stats[K, 3, N]``, rows along lanes) — K× fewer level passes, host syncs, and
     binning passes than OneVsRest's sequential sub-fits, and the K-wide
     histograms batch better on the MXU (SURVEY.md §7.2 item 4).
 
@@ -596,40 +605,30 @@ def fit_gbt_ovr_vectorized(
     y_signed = _ovr_signed_labels(ys, num_classes=K)  # [K, Np]
     row_sharding = NamedSharding(mesh, P(None, axis))
 
-    # built once: a per-round jit(lambda) would retrace every round
-    broadcast_k = jax.jit(
-        lambda v: jnp.broadcast_to(v[None], (K, n_pad)),
-        out_shardings=row_sharding,
-    )
-
     def round_weights(i):
         # one [n_pad] host->device transfer; the K-way copy happens
         # on-device (no K redundant host buffers on the fit hot loop)
-        return broadcast_k(
-            jax.device_put(round_mask(i), NamedSharding(mesh, P(axis)))
+        return _broadcast_classes(
+            jax.device_put(round_mask(i), NamedSharding(mesh, P(axis))),
+            num_classes=K,
         )
 
     margins = jax.device_put(np.zeros((K, n_pad), np.float32), row_sharding)
     feats, thrs, lvs, gns, cnts, wts = [], [], [], [], [], []
     for m in range(n_rounds):
-        if m == 0:
-            row_stats = _ovr_label_stats(y_signed, ws)
-            tree_weight = 1.0
-        else:
-            row_stats = _ovr_residual_stats(y_signed, ws, margins)
-            tree_weight = step
-        forest = grow_forest(
-            binned, row_stats, round_weights(m), edges,
-            seed=seed + m, mesh=mesh, **grow_kwargs,
-        )
-        contribs = _forest_margins(
-            xs,
-            jnp.asarray(forest.feature),
-            jnp.asarray(forest.threshold),
-            jnp.asarray(forest.leaf_stats),
-            max_depth=forest.max_depth,
-        )  # [K, Np]
-        margins = margins + tree_weight * contribs
+        with span("gbt.round", round=m, trees=K, module=_MODULE):
+            if m == 0:
+                row_stats = _label_stats(y_signed, ws)  # [K, 3, Np]
+                tree_weight = 1.0
+            else:
+                row_stats = _residual_stats(y_signed, ws, margins)
+                tree_weight = step
+            forest = grow_forest(
+                binned, row_stats, round_weights(m), edges,
+                seed=seed + m, mesh=mesh, **grow_kwargs,
+            )
+            margins = margins + tree_weight * _leaf_values(xs, forest)
+        _count_round("gbt_ovr", K)
         feats.append(forest.feature)
         thrs.append(forest.threshold)
         lvs.append(forest.leaf_stats)
@@ -637,13 +636,7 @@ def fit_gbt_ovr_vectorized(
         cnts.append(forest.count)
         wts.append(tree_weight)
         if tracker is not None:
-            contribs_val = _forest_margins(
-                X_val_j,
-                jnp.asarray(forest.feature),
-                jnp.asarray(forest.threshold),
-                jnp.asarray(forest.leaf_stats),
-                max_depth=forest.max_depth,
-            )  # [K, Nv]
+            contribs_val = _leaf_values(X_val_j, forest)  # [K, Nv]
             margins_val = margins_val + tree_weight * np.asarray(
                 contribs_val, np.float64
             )

@@ -38,31 +38,31 @@ from sntc_tpu.models.tree.grower import (
     grow_forest,
     resolve_feature_subset_k,
 )
+from sntc_tpu.models.tree.gbt import (
+    _ValidationTracker,
+    _count_round,
+    _leaf_values,
+    _variance_stats,
+)
 from sntc_tpu.models.tree.random_forest import _TreeEnsembleParams
+from sntc_tpu.obs import module_of, span
 from sntc_tpu.ops.binning import bin_features, quantile_bin_edges
 from sntc_tpu.parallel.collectives import shard_batch, shard_weights
 from sntc_tpu.parallel.context import get_default_mesh
 
+_MODULE = module_of(__name__)
+
 
 @jax.jit
 def _sq_residual_stats(ys, ws, pred):
-    r = ys - pred
-    return jnp.stack([ws, ws * r, ws * r * r], axis=1)
+    """``[1, 3, N]``: the round's one tree's stats in the grower's
+    per-tree layout (rows along lanes, as the classifier's)."""
+    return _variance_stats(ws, ys - pred)[None]
 
 
 @jax.jit
 def _abs_residual_stats(ys, ws, pred):
-    r = jnp.sign(ys - pred)
-    return jnp.stack([ws, ws * r, ws * r * r], axis=1)
-
-
-@partial(jax.jit, static_argnames=("max_depth",))
-def _tree_prediction(X, feature, threshold, leaf_stats, *, max_depth):
-    """Leaf mean of a single-round [1, H] tree -> [N]."""
-    stats = forest_leaf_stats(
-        X, feature, threshold, leaf_stats, max_depth=max_depth
-    )[0]
-    return stats[:, 1] / jnp.maximum(stats[:, 0], 1e-12)
+    return _variance_stats(ws, jnp.sign(ys - pred))[None]
 
 
 @partial(jax.jit, static_argnames=("max_depth",))
@@ -70,10 +70,9 @@ def _gbt_reg_predict(X, feature, threshold, leaf_stats, tree_weights, *,
                      max_depth):
     """F(x) = Σ_m w_m · tree_m(x): one traversal of all M trees + a
     weighted contraction (one dispatch on the serve path)."""
-    stats = forest_leaf_stats(
-        X, feature, threshold, leaf_stats, max_depth=max_depth
-    )  # [M, N, 3]
-    means = stats[..., 1] / jnp.maximum(stats[..., 0], 1e-12)
+    means = forest_leaf_stats(
+        X, feature, threshold, leaf_stats, max_depth=max_depth, value=True
+    )  # [M, N]
     return jnp.einsum("m,mn->n", tree_weights, means)
 
 
@@ -160,7 +159,6 @@ class GBTRegressor(_GbtRegParams, CheckpointParams, Estimator):
             )
 
         from sntc_tpu.mlio import optimizer_checkpoint as _ckpt
-        from sntc_tpu.models.tree.gbt import _ValidationTracker
 
         init = float(np.mean(y)) if n else 0.0
         pred = jnp.full(xs.shape[0], init, jnp.float32)
@@ -227,23 +225,20 @@ class GBTRegressor(_GbtRegParams, CheckpointParams, Estimator):
             # constant init is equivalent (variance splits are
             # shift-invariant, leaf means shift by init).  Sign residuals
             # (absolute loss) apply only from the second tree on.
-            row_stats = (
-                _sq_residual_stats(ys, ws, pred)
-                if m == 0
-                else resid_fn(ys, ws, pred)
-            )
-            forest = grow_forest(
-                binned, row_stats, round_weights(m), edges,
-                seed=seed + m, mesh=mesh, **grow_kwargs,
-            )
-            contrib = _tree_prediction(
-                xs, jnp.asarray(forest.feature),
-                jnp.asarray(forest.threshold),
-                jnp.asarray(forest.leaf_stats),
-                max_depth=forest.max_depth,
-            )
-            tree_w = 1.0 if m == 0 else step
-            pred = pred + tree_w * contrib
+            with span("gbt.round", round=m, trees=1, module=_MODULE):
+                row_stats = (
+                    _sq_residual_stats(ys, ws, pred)
+                    if m == 0
+                    else resid_fn(ys, ws, pred)
+                )
+                forest = grow_forest(
+                    binned, row_stats, round_weights(m), edges,
+                    seed=seed + m, mesh=mesh, **grow_kwargs,
+                )
+                contrib = _leaf_values(xs, forest)[0]
+                tree_w = 1.0 if m == 0 else step
+                pred = pred + tree_w * contrib
+            _count_round("gbt_regressor", 1)
             features.append(forest.feature[0])
             thresholds.append(forest.threshold[0])
             leaves.append(forest.leaf_stats[0])
@@ -252,13 +247,7 @@ class GBTRegressor(_GbtRegParams, CheckpointParams, Estimator):
             weights.append(tree_w)
             if val_col:
                 contrib_val = np.asarray(
-                    _tree_prediction(
-                        X_val_j, jnp.asarray(forest.feature),
-                        jnp.asarray(forest.threshold),
-                        jnp.asarray(forest.leaf_stats),
-                        max_depth=forest.max_depth,
-                    ),
-                    np.float64,
+                    _leaf_values(X_val_j, forest)[0], np.float64
                 )
                 pred_val = pred_val + tree_w * contrib_val
                 err = (

@@ -328,9 +328,10 @@ def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
 
 def _level_core(
     binned_t,  # [F, N] int32, row-sharded on axis 1 (pallas layout)
-    row_stats,  # [N, S] f32 shared, or [T, N, S] per-tree (the vectorized
-    #            one-vs-rest path: every "tree" is a different binary
-    #            problem over the same binned features) — row-sharded
+    row_stats,  # [N, S] f32 shared, or [T, S, N] per-tree, rows along
+    #            lanes (boosting: every "tree" of a one-vs-rest round is a
+    #            different binary problem over the same binned features)
+    #            — row-sharded (:func:`_stats_width` tells the two apart)
     row_label,  # [N] int32 class ids or None (label-fused scatter path)
     row_weight,  # [N] f32 row weights or None (with row_label)
     w_trees,  # [T, N] f32 bagging weights, sharded on N
@@ -515,7 +516,7 @@ def _eval_node_group(
     derive garbage (parent − 0) but are masked by ``exists_lvl`` in
     :func:`_grow_fused` before any heap write, and no row routes there."""
     F = binned_t.shape[0]
-    S = row_stats.shape[-1]
+    S = _stats_width(row_stats)
     T = w_trees.shape[0]
 
     if parent_hist is not None and g >= 2:
@@ -579,7 +580,7 @@ def _group_hist(
     ``row_stats == one_hot(row_label) * row_weight[:, None]``), else the
     generic vector ``segment_sum``."""
     F = binned_t.shape[0]
-    S = row_stats.shape[-1]
+    S = _stats_width(row_stats)
     T = w_trees.shape[0]
     per_tree_stats = row_stats.ndim == 3
     n_nodes = g_eff  # group-local histogram width
@@ -658,6 +659,8 @@ def _group_hist(
         def hist_one(w_t, node_t, rs_t):
             active = (node_t >= 0).astype(rs_t.dtype)
             ids = jnp.where(node_t >= 0, node_t, 0)
+            if per_tree_stats:  # [S, N]: a tree's own, rows along lanes
+                rs_t = rs_t.T
             data = rs_t * (w_t * active)[:, None]
 
             def per_feature(carry, col):
@@ -734,26 +737,38 @@ def _eval_from_hist(hist, fmask, min_instances, *, impurity):
     }
 
 
+def _stats_width(row_stats) -> int:
+    """``S`` of the grower's two statistics layouts: shared ``[N, S]``
+    (one array for every tree: the forests' weighted one-hot labels), or
+    per-tree ``[T, S, N]`` with the rows along lanes (boosting).  A
+    per-tree array never has ``S`` minor: ``[T, N, 3]`` lies tiled to 128
+    lanes in HBM, 42 times its bytes."""
+    return row_stats.shape[-1 if row_stats.ndim == 2 else -2]
+
+
 def _lane_dense_stats(row_stats):
-    """``row_stats`` ``[..., N, S]`` as the pallas kernel takes it:
-    ``[..., S_pad, N]``, rows along lanes and the statistics padded with
-    zero rows to the float32 sublane tile of 8.  (``[N, 15]`` lies tiled
-    to 128 lanes in HBM, eight times its bytes; this lies dense.)"""
-    S = row_stats.shape[-1]
-    pad = [(0, 0)] * (row_stats.ndim - 1) + [(0, -S % 8)]
-    return jnp.swapaxes(jnp.pad(row_stats, pad), -1, -2)
+    """``row_stats`` as the pallas kernel takes it, ``[S_pad, N]`` /
+    ``[T, S_pad, N]``: rows along lanes and the statistics padded with
+    zero rows to the float32 sublane tile of 8.  Shared ``[N, S]``
+    statistics are transposed here, once a fit (``[N, 15]`` lies tiled to
+    128 lanes in HBM, eight times its bytes; this lies dense); per-tree
+    ones arrive lane-dense and only gain the zero rows."""
+    S = _stats_width(row_stats)
+    if row_stats.ndim == 3:
+        return jnp.pad(row_stats, ((0, 0), (0, -S % 8), (0, 0)))
+    return jnp.pad(row_stats, ((0, 0), (0, -S % 8))).T
 
 
 @jax.jit
 def _root_stats(row_stats, w_trees):
     if row_stats.ndim == 3:
-        return jnp.einsum("tn,tns->ts", w_trees, row_stats)
+        return jnp.einsum("tn,tsn->ts", w_trees, row_stats)
     return jnp.einsum("tn,ns->ts", w_trees, row_stats)
 
 
 def grow_forest(
     binned,  # [N, F] int32 (device, row-sharded)
-    row_stats,  # [N, S] shared or [T, N, S] per-tree f32 (device, row-sharded)
+    row_stats,  # [N, S] shared or [T, S, N] per-tree f32 (device, row-sharded)
     w_trees,  # [T, N] f32 (device, sharded on N axis=1)
     edges: np.ndarray,  # [F, B-1] host bin thresholds
     *,
@@ -786,7 +801,7 @@ def grow_forest(
     # dominated CPU level cost otherwise)
     binned_t = jnp.transpose(binned)
     T = w_trees.shape[0]
-    S = row_stats.shape[-1]
+    S = _stats_width(row_stats)
     H = (1 << (max_depth + 1)) - 1
 
     if max_depth == 0:
@@ -847,7 +862,7 @@ def _grow_fused(
     select over the level's decision tables and the rows of ``binned_t``;
     the ``[N, F]`` bin matrix is no operand of this program)."""
     T, n = w_trees.shape
-    S = row_stats.shape[-1]
+    S = _stats_width(row_stats)
     H = (1 << (max_depth + 1)) - 1
 
     feature = jnp.full((T, H), -2, jnp.int32)
@@ -922,25 +937,53 @@ def _grow_fused(
     return feature, threshold, leaf_stats, gain_a, count_a
 
 
-@partial(jax.jit, static_argnames=("max_depth",))
-def forest_leaf_stats(X, feature, threshold, leaf_stats, *, max_depth: int):
-    """Serve: route each row down each tree, return leaf stats [T, N, S].
+@partial(jax.jit, static_argnames=("max_depth", "value"))
+def forest_leaf_stats(X, feature, threshold, leaf_stats, *, max_depth: int,
+                      value: bool = False):
+    """Route each row down each tree on raw floats (``x >= threshold``
+    goes right, NaN left) and return its leaf's stats ``[T, N, S]``, or,
+    with ``value`` (what boosting asks for), the leaf's mean ``[T, N]``:
+    ``sum / max(count, 1e-12)`` of ``[w, wy, wy²]`` stats, divided on the
+    ``[T, H]`` leaf table first, so that no ``[T, N, S]`` array is built.
 
-    Dense traversal: ``max_depth`` gathers, no data-dependent control flow —
-    XLA-friendly (SURVEY.md §1 restack: "no dynamic DAG").
-    """
-    T = feature.shape[0]
-    N = X.shape[0]
-    node = jnp.zeros((T, N), jnp.int32)
-    for _ in range(max_depth):
-        f = jnp.take_along_axis(feature, node, axis=1)  # [T, N]
-        is_internal = f >= 0
-        fc = jnp.where(is_internal, f, 0)
-        xv = jax.vmap(
-            lambda f_t: jnp.take_along_axis(X, f_t[:, None], axis=1)[:, 0]
-        )(fc)  # [T, N]
-        thr = jnp.take_along_axis(threshold, node, axis=1)
-        go_right = (xv >= thr).astype(jnp.int32)
-        child = 2 * node + 1 + go_right
-        node = jnp.where(is_internal, child, node)
-    return jax.vmap(lambda ls_t, n_t: ls_t[n_t])(leaf_stats, node)  # [T, N, S]
+    The one walk of the package: the boosting loops' margin update, the
+    models' ``transform`` and the serve kernel's XLA twin.  No per-row
+    gather, as in :func:`_route_rows` (a per-row table lookup over [T, N]
+    lowers to a serial ``kCustom`` fusion on the TPU, 12-28 ns an
+    element): a row picks its node's feature id and threshold among the
+    ``2^d`` entries of its level, its feature value among the ``F`` rows
+    of ``X`` transposed, and at the end its leaf among the ``H`` slots,
+    each as a masked sum over the small axis with one term that is not
+    zero, so every picked integer and float is the table's own.  Dense
+    and level-synchronous: no data-dependent control flow (SURVEY.md §1
+    restack: "no dynamic DAG")."""
+    T, H = feature.shape
+    F = X.shape[1]
+    X_t = X.T  # [F, N]: rows along lanes, as the grower's ``binned_t``
+    feat_ids = jnp.arange(F, dtype=jnp.int32)[None, :, None]
+    node = jnp.zeros((T, X.shape[0]), jnp.int32)  # heap slot of each row
+    for depth in range(max_depth):
+        off, n_nodes = (1 << depth) - 1, 1 << depth
+        # a row resting in a shallower leaf matches no node of the level
+        at = (node - off)[:, None, :] == jnp.arange(
+            n_nodes, dtype=jnp.int32
+        )[None, :, None]  # [T, n_nodes, N]
+        feat_d, thr_d = (
+            jax.lax.slice_in_dim(a, off, off + n_nodes, axis=1)[..., None]
+            for a in (feature, threshold)
+        )  # [T, n_nodes, 1]
+        # +1/-1: no match sums to -1, a leaf's id (-1, -2) stays negative
+        f = jnp.sum(jnp.where(at, feat_d + 1, 0), axis=1) - 1  # [T, N]
+        thr = jnp.sum(jnp.where(at, thr_d, 0.0), axis=1)
+        xv = jnp.sum(
+            jnp.where(f[:, None, :] == feat_ids, X_t[None], 0.0), axis=1
+        )  # [T, N]
+        child = 2 * node + 1 + (xv >= thr).astype(jnp.int32)
+        node = jnp.where(f >= 0, child, node)
+    at = node[:, None, :] == jnp.arange(H, dtype=jnp.int32)[None, :, None]
+    if value:
+        means = leaf_stats[..., 1] / jnp.maximum(leaf_stats[..., 0], 1e-12)
+        return jnp.sum(jnp.where(at, means[..., None], 0.0), axis=1)
+    return jnp.sum(
+        jnp.where(at[..., None], jnp.expand_dims(leaf_stats, 2), 0.0), axis=1
+    )  # [T, N, S]

@@ -264,6 +264,19 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "layout=base_rows | columns | generic is the copy it chose from "
         "the input's strides).",
     ),
+    # -- boosting rounds on the fit path (models/tree/gbt*.py) ---------------
+    "sntc_boost_rounds_total": dict(
+        type=COUNTER, labels=("estimator",),
+        help="Boosting rounds completed, by loop: estimator=gbt_classifier "
+        "(the binary GBTClassifier), gbt_ovr (the one-vs-rest loop, K "
+        "class trees a round), gbt_regressor.",
+    ),
+    "sntc_boost_trees_total": dict(
+        type=COUNTER, labels=("estimator",),
+        help="Trees grown by boosting rounds (a one-vs-rest round adds "
+        "K, the binary and the regression loops 1), same labels as "
+        "sntc_boost_rounds_total.",
+    ),
     # -- collective layer over the mesh substrate (parallel/mesh, r22) ------
     "sntc_collective_dispatches_total": dict(
         type=COUNTER, labels=("op", "axis"),

@@ -478,6 +478,39 @@ def test_uploads_cross_the_one_routing_point(mesh8):
             == 4 * 64 * 4 + 64 * 4)
 
 
+def test_one_vs_rest_boosting_opens_a_span_and_counts_each_round(mesh8):
+    """``maxIter`` ``gbt.round`` spans (``module=models``, the round's
+    number, K class trees), the grower's fetch nested in each, and
+    ``maxIter`` / ``maxIter * K`` on the two counters."""
+    from sntc_tpu.models import GBTClassifier, OneVsRest
+
+    rng = np.random.default_rng(0)
+    K, rounds = 3, 4
+    y = rng.integers(0, K, size=400)
+    X = (rng.normal(size=(400, 5)) + y[:, None]).astype(np.float32)
+    frame = Frame({"features": X, "label": y.astype(np.float64)})
+    t = enable_tracing(capacity=512)
+    before = [_get(name, estimator="gbt_ovr") for name in
+              ("sntc_boost_rounds_total", "sntc_boost_trees_total")]
+    OneVsRest(classifier=GBTClassifier(
+        mesh=mesh8, maxIter=rounds, maxDepth=2, seed=1
+    )).fit(frame)
+    spans = t.spans()
+    opened = [s for s in spans if s["name"] == "gbt.round"]
+    assert [(s["attrs"]["round"], s["attrs"]["trees"], s["attrs"]["module"])
+            for s in opened] == [(m, K, "models") for m in range(rounds)]
+    fetches = [s for s in spans if s["name"] == "d2h.fetch"]
+    assert [s["parent"] for s in fetches] == [s["id"] for s in opened]
+    for name in ("ovr.extract", "gbt.bin_edges"):
+        (one,) = [s for s in spans if s["name"] == name]
+        assert one["attrs"]["module"] == "models"
+    assert len([s for s in spans if s["name"] == "gbt.mask"]) == rounds
+    assert _get("sntc_boost_rounds_total", estimator="gbt_ovr") \
+        - before[0] == rounds
+    assert _get("sntc_boost_trees_total", estimator="gbt_ovr") \
+        - before[1] == rounds * K
+
+
 def test_xla_compiles_counted_on_a_fresh_jit_not_on_a_repeat():
     import jax
     import jax.numpy as jnp

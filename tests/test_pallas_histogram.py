@@ -386,6 +386,8 @@ def v5e_chip():
     (40, 20, 32, 32),  # S_pad 24: chunk columns rounded up to lanes
     (40, 40, 64, 32),  # S_pad 40
     (40, 3, 63, 128),  # widest one-chunk level at 128 bins
+    (78, 3, 1, 32),  # the boosted cell: per-tree S 3, all 78 features
+    (78, 3, 8, 32),  # its deepest level (8 histogrammed nodes)
 ])
 def test_kernel_compiles_for_the_chip(v5e_chip, f, s, n_nodes, n_bins):
     """The Mosaic lowering takes the planned blocks at the widths the

@@ -180,3 +180,101 @@ def test_fits_equal_the_parents_arrays(mesh8, name):
     if name == "deep_tree":  # the 128-node level really routed rows
         internal = got["feature"][0, (1 << 7) - 1:(1 << 8) - 1] >= 0
         assert internal.sum() > 0
+
+
+# --------------------------------------------------------------------------
+# the walk on raw floats (``grower.forest_leaf_stats``)
+# --------------------------------------------------------------------------
+
+
+def _walk_oracle(X, feature, threshold, leaf_stats, max_depth):
+    """The per-row gathers the walk was before: three ``take_along_axis``
+    a level and the leaf fetch."""
+    T, N = feature.shape[0], X.shape[0]
+    node = jnp.zeros((T, N), jnp.int32)
+    for _ in range(max_depth):
+        f = jnp.take_along_axis(feature, node, axis=1)
+        is_internal = f >= 0
+        fc = jnp.where(is_internal, f, 0)
+        xv = jax.vmap(
+            lambda f_t: jnp.take_along_axis(X, f_t[:, None], axis=1)[:, 0]
+        )(fc)
+        thr = jnp.take_along_axis(threshold, node, axis=1)
+        child = 2 * node + 1 + (xv >= thr).astype(jnp.int32)
+        node = jnp.where(is_internal, child, node)
+    return jax.vmap(lambda ls_t, n_t: ls_t[n_t])(leaf_stats, node)
+
+
+def _random_heap(rng, T, depth, F, S, X):
+    """Dense heaps as the grower writes them: an internal node's children
+    exist, a leaf's subtree is absent (-2); some subtrees die early; every
+    threshold is a value some row holds, so rows sit exactly on it."""
+    H = (1 << (depth + 1)) - 1
+    feature = np.full((T, H), -2, np.int32)
+    threshold = np.zeros((T, H), np.float32)
+    feature[:, 0] = -1
+    for h in range((1 << depth) - 1):  # slots that have children
+        split = (feature[:, h] == -1) & (rng.random(T) < 0.8)
+        f = rng.integers(0, F, size=T)
+        feature[:, h] = np.where(split, f, feature[:, h])
+        threshold[:, h] = np.where(
+            split, X[rng.integers(0, X.shape[0], size=T), f], 0.0
+        )
+        for child in (2 * h + 1, 2 * h + 2):
+            feature[:, child] = np.where(split, -1, feature[:, child])
+    feature[0, 0] = max(feature[0, 0], 0)  # one tree that surely splits
+    leaf_stats = rng.normal(size=(T, H, S)).astype(np.float32)
+    leaf_stats[..., 0] = rng.integers(1, 50, size=(T, H))
+    return feature, threshold, leaf_stats
+
+
+@pytest.mark.parametrize("S", [3, 15])
+@pytest.mark.parametrize("T", [1, 15])
+@pytest.mark.parametrize("depth", [1, 2, 3, 4, 5, 6])
+def test_walk_matches_gather_oracle(depth, T, S):
+    """Integers equal and floats bit-equal, with values exactly on a
+    threshold, NaN features (which go left), dead subtrees and N not a
+    multiple of 128; the boosting callers' leaf value is the table's own
+    ``sum / max(count, 1e-12)``."""
+    n, F = 1003, 11
+    rng = np.random.default_rng(100 * depth + 10 * T + S)
+    X = rng.integers(-3, 4, size=(n, F)).astype(np.float32)  # many ties
+    X[rng.random((n, F)) < 0.3] += np.float32(rng.random())
+    feature, threshold, leaf_stats = _random_heap(rng, T, depth, F, S, X)
+    with jax.debug_nans(False):
+        X[5, :] = np.nan
+        args = tuple(jnp.asarray(a) for a in
+                     (X, feature, threshold, leaf_stats))
+        want = np.asarray(_walk_oracle(*args, depth))
+        got = np.asarray(grower.forest_leaf_stats(*args, max_depth=depth))
+        value = np.asarray(grower.forest_leaf_stats(
+            *args, max_depth=depth, value=True
+        ))
+    assert got.shape == (T, n, S) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        value, want[..., 1] / np.maximum(want[..., 0], np.float32(1e-12))
+    )
+    # the cases mean something: rows sit on thresholds and land on both
+    # sides of the root
+    on_thr = X[:, feature[0, 0]] == threshold[0, 0]
+    assert on_thr.any() and not on_thr.all()
+    assert len(np.unique(got[0, ~np.isnan(X[:, 0]), 1])) > 1  # both sides
+
+
+@pytest.mark.parametrize("value", [False, True])
+def test_walk_lowers_without_gather(value):
+    """No gather, no dynamic per-row indexing and no loop in what the
+    compiler is handed, and (for the boosting callers) no ``[T, N, S]``
+    array."""
+    n = 4099
+    txt = grower.forest_leaf_stats.lower(
+        jax.ShapeDtypeStruct((n, 78), jnp.float32),
+        jax.ShapeDtypeStruct((15, 63), jnp.int32),
+        jax.ShapeDtypeStruct((15, 63), jnp.float32),
+        jax.ShapeDtypeStruct((15, 63, 3), jnp.float32),
+        max_depth=5, value=value,
+    ).as_text()
+    for op in ("gather", "scatter", "while", "dynamic_slice"):
+        assert op not in txt, op
+    assert (f"15x{n}x3x" in txt) is (not value)

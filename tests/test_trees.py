@@ -1,6 +1,8 @@
 """Tree oracle tests (SURVEY.md §4.2): split-for-split vs sklearn on tiny
 data with bins forced equal; behavioral (accuracy/AUC) parity on blobs."""
 
+import os
+
 import numpy as np
 import pytest
 from sklearn.ensemble import GradientBoostingClassifier as SkGBT
@@ -786,3 +788,114 @@ def test_gbt_regressor_absolute_loss_wide_range_targets(mesh8):
     rmse = float(np.sqrt(np.mean((pred - y) ** 2)))
     assert rmse < 0.25 * float(y.std()), rmse
     assert m.treeWeights[0] == 1.0
+
+
+# --------------------------------------------------------------------------
+# boosting state rows-along-lanes (per-tree statistics ``[T, S, N]``)
+# --------------------------------------------------------------------------
+
+_BOOSTED_EXPECTED = os.path.join(
+    os.path.dirname(__file__), "testdata", "boosted_parent_expected.npz"
+)
+
+
+def _boosted_ovr(mesh):
+    f, _, _ = _blobs(n=3000, k=4, d=10, seed=31, scale=1.2)
+    clf = GBTClassifier(mesh=mesh, maxIter=5, maxDepth=4, seed=7)
+    models = OneVsRest(classifier=clf).fit(f).models
+    return {
+        k: np.stack([getattr(m.forest, k) for m in models])
+        for k in ("feature", "threshold", "leaf_stats")
+    }
+
+
+def _boosted_binary(mesh):
+    f, _, _ = _blobs(n=2500, k=2, d=8, seed=32, scale=0.8)
+    m = GBTClassifier(
+        mesh=mesh, maxIter=6, maxDepth=4, stepSize=0.3, seed=7
+    ).fit(f)
+    return {k: getattr(m.forest, k)
+            for k in ("feature", "threshold", "leaf_stats")}
+
+
+def _boosted_regressor(mesh):
+    from sntc_tpu.models import GBTRegressor
+
+    rng = np.random.default_rng(33)
+    X = rng.normal(size=(2000, 6)).astype(np.float32)
+    y = (np.sin(X[:, 0]) + X[:, 1] * X[:, 2]
+         + 0.1 * rng.normal(size=2000)).astype(np.float32)
+    m = GBTRegressor(mesh=mesh, maxIter=5, maxDepth=3, seed=7).fit(
+        Frame({"features": X, "label": y})
+    )
+    return {k: getattr(m.forest, k)
+            for k in ("feature", "threshold", "leaf_stats")}
+
+
+_BOOSTED_FITS = {"ovr": _boosted_ovr, "binary": _boosted_binary,
+                 "regressor": _boosted_regressor}
+
+
+@pytest.mark.parametrize("name", sorted(_BOOSTED_FITS))
+def test_boosted_fits_give_the_parents_trees(mesh8, name):
+    """The boosted fits on lane-dense per-tree statistics and the
+    compare-and-select walk give the trees the ``[T, N, 3]`` statistics and
+    the gather walk gave (``testdata/boosted_parent_expected.npz``, written
+    on the CPU from the commit before the change by running ``_BOOSTED_FITS``
+    against that checkout)."""
+    got = _BOOSTED_FITS[name](mesh8)
+    assert (got["feature"] >= 0).sum() > 10  # real trees, not stumps
+    with np.load(_BOOSTED_EXPECTED) as want:
+        for k in ("feature", "threshold"):
+            np.testing.assert_array_equal(got[k], want[f"{name}/{k}"], k)
+        np.testing.assert_allclose(
+            got["leaf_stats"], want[f"{name}/leaf_stats"],
+            rtol=1e-6, atol=1e-6,
+        )
+
+
+@pytest.mark.parametrize("program", [
+    "label_stats", "residual_stats", "grow", "walk",
+])
+def test_no_stat_minor_operand_on_the_one_vs_rest_path(
+    mesh8, program, monkeypatch
+):
+    """None of the device programs of a one-vs-rest round is handed, or
+    builds, an array whose minor dimension is the 3 statistics and whose
+    other dimension is the rows (such an array lies tiled to 128 lanes on
+    the TPU: 31 GB at the benchmark cell's size).  The grower is lowered as
+    the TPU takes it, with the kernel (``segment_sum``, the CPU's, scatters
+    ``[N, 3]`` rows)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sntc_tpu.models.tree import gbt, grower
+
+    K, n, F, D, B = 5, 4104, 9, 3, 16  # n: no other size of the programs
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if program == "label_stats":
+        low = gbt._label_stats.lower(sds(K, n), sds(n))
+    elif program == "residual_stats":
+        low = gbt._residual_stats.lower(sds(K, n), sds(n), sds(K, n))
+    elif program == "grow":
+        monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
+        plan = grower._level_plan(K, F, B, 3, D, mesh8)
+        assert plan.hist_impl == "pallas"
+        low = grower._grow_fused.lower(
+            sds(F, n, dtype=jnp.int32), sds(K, 3, n), None, None, sds(K, n),
+            sds(F, B - 1), jax.random.split(jax.random.PRNGKey(0), D),
+            jnp.float32(1.0), jnp.float32(0.0), max_depth=D, n_bins=B,
+            impurity="variance", subset_k=F, plan=plan, mesh=mesh8,
+        )
+    else:
+        H = (1 << (D + 1)) - 1
+        low = grower.forest_leaf_stats.lower(
+            sds(n, F), sds(K, H, dtype=jnp.int32), sds(K, H), sds(K, H, 3),
+            max_depth=D, value=True,
+        )
+    txt = low.as_text()
+    assert f"x{n}x" in txt or f"<{n}x" in txt  # the rows are in there
+    assert f"{n}x3x" not in txt and f"{n}x3>" not in txt
