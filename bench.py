@@ -4249,8 +4249,8 @@ def bench_mfu(n_rows, mesh):
         weight = jnp.ones((n_loc,), jnp.float32)
         call = jax.jit(
             lambda bt, ni, st, w: level_histogram_pallas(
-                bt, ni, st, w, n_nodes=n_nodes, n_bins=B
-            )
+                bt, ni[None], st, w[None], n_nodes=n_nodes, n_bins=B
+            )[0]  # one tree's level
         )
         call(binned_t, node_idx, stats_t, weight).block_until_ready()
         reps = 10

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sntc_tpu.obs import module_of, span
+from sntc_tpu.obs.metrics import inc
 from sntc_tpu.parallel.collectives import _put_sharded
 from sntc_tpu.parallel.mesh import map_at, payload_nbytes, record_collective
 
@@ -281,7 +282,7 @@ class LevelPlan(NamedTuple):
 
 
 def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
-                mesh) -> LevelPlan:
+                mesh, per_tree_stats: bool = False) -> LevelPlan:
     """The fit's histogram decisions, made once from its shapes and what
     :func:`~sntc_tpu.ops.pallas_histogram.tree_hist_impl` observes.
 
@@ -304,10 +305,24 @@ def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
     O(N) whatever the width, so there the kept histogram's traffic is
     pure overhead: 2.1x slower on CPU at the depth-10 bench shapes), with
     groups of at least a left/right pair, and while the kept histogram
-    fits ``_SIBLING_BYTES``."""
+    fits ``_SIBLING_BYTES``.
+
+    Under the kernel a node group's histograms are ONE call, the level's
+    ``T`` trees stacked as columns of one product (column order, tree
+    block rule and the measured cost law: ``ops/pallas_histogram.py``).
+    What the fit's calls will multiply is counted here from the plan, as
+    the kernel's dispatch is: ``sntc_kernel_tree_hist_column_tiles_total``
+    (128-column array tiles, which the kernel's time follows) and
+    ``sntc_kernel_tree_hist_columns_total`` (columns among them that carry
+    a term of a statistic); columns / (128 x tiles) is the array's fill.
+    ``per_tree_stats`` (boosting) only decides how the columns lie."""
     # imported where used: Pallas costs a second to import, and a process
     # that grows no tree (the MLP's fit, the serve path) should not pay it
-    from sntc_tpu.ops.pallas_histogram import hist_fits_pallas, tree_hist_impl
+    from sntc_tpu.ops.pallas_histogram import (
+        column_tiles,
+        hist_fits_pallas,
+        tree_hist_impl,
+    )
 
     hist_bytes = T * F * n_bins * S * 4  # one node's histogram
     raw = max(1, _NODE_GROUP_BYTES // (5 * hist_bytes))
@@ -323,6 +338,18 @@ def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
         siblings and d < max_depth - 1 and (hist_bytes << d) <= _SIBLING_BYTES
         for d in range(max_depth)
     )
+    if hist_impl == "pallas":
+        tiles = columns = 0
+        for d in range(max_depth):
+            g = min(1 << d, group)
+            # a level whose parent's histogram was kept histograms the
+            # left children only (:func:`_eval_node_group`)
+            halved = d > 0 and keep_hists[d - 1] and g >= 2
+            call = column_tiles(T, S, per_tree_stats, g // 2 if halved else g)
+            tiles += ((1 << d) // g) * call[0]
+            columns += ((1 << d) // g) * call[1]
+        inc("sntc_kernel_tree_hist_column_tiles_total", tiles)
+        inc("sntc_kernel_tree_hist_columns_total", columns)
     return LevelPlan(hist_impl, group, keep_hists)
 
 
@@ -341,8 +368,8 @@ def _level_core(
     min_info_gain,  # f32 scalar
     parent_hist,  # [T, n_nodes/2, F, B, S] previous level's histograms
     #             (sibling-subtraction path) or None (direct)
-    stats_t,  # [S_pad, N] / [T, S_pad, N] f32: ``row_stats`` with the rows
-    #         along lanes (:func:`_lane_dense_stats`), the pallas kernel's
+    stats_t,  # [S, N] / [S, T, N] f32: ``row_stats`` with the rows along
+    #         lanes (:func:`_lane_dense_stats`), the pallas kernel's
     #         operand; None when the fit takes ``segment_sum``
     *,
     n_nodes: int,
@@ -563,7 +590,7 @@ def _eval_node_group(
 def _group_hist(
     binned_t, row_stats, row_label, row_weight, w_trees,
     node_idx,  # [T, N] int32 GROUP-LOCAL ids in [0, g_eff) (-1 = dead)
-    stats_t,  # [S_pad, N] / [T, S_pad, N] f32 (the kernel's) or None
+    stats_t,  # [S, N] / [S, T, N] f32 (the kernel's) or None
     *,
     g_eff: int,
     n_bins: int,
@@ -590,7 +617,7 @@ def _group_hist(
         # MXU factored one-hot matmul kernel per shard, explicit psum over
         # the mesh (sntc_tpu/ops/pallas_histogram.py).  Every operand has
         # the rows along lanes: the statistics arrive transposed once a
-        # fit, the tree's weight as it lies, and the kernel multiplies
+        # fit, the trees' weights as they lie, and the kernel multiplies
         # the two on its own tile; dead rows (id -1) match no node
         from jax.sharding import PartitionSpec as P
 
@@ -598,23 +625,15 @@ def _group_hist(
 
         axis = mesh.axis_names[0]
         st_spec = (
-            P(None, None, axis) if per_tree_stats else P(None, axis)
+            P(None, None, axis) if stats_t.ndim == 3 else P(None, axis)
         )
 
         def shard_fn(bt, st, wt, ni):
-            def hist_one(w_t, node_t, st_t):
-                return level_histogram_pallas(
-                    bt, node_t, st_t, w_t, n_nodes=n_nodes, n_bins=n_bins
-                )[..., :S]  # [F, nodes*B, S]
-
-            if per_tree_stats:
-                hs = jax.lax.map(lambda a: hist_one(*a), (wt, ni, st))
-            else:
-                # shared stats stay closure-captured (no [T, S, n]
-                # broadcast materialized per shard)
-                hs = jax.lax.map(
-                    lambda a: hist_one(a[0], a[1], st), (wt, ni)
-                )  # [T, F, nodes*B, S]
+            # one call a node group: the level's trees are columns of one
+            # product, over one bin one-hot
+            hs = level_histogram_pallas(
+                bt, ni, st, wt, n_nodes=n_nodes, n_bins=n_bins
+            )  # [T, F, nodes*B, S]
             return jax.lax.psum(hs, axis)
 
         hists = map_at(
@@ -747,16 +766,20 @@ def _stats_width(row_stats) -> int:
 
 
 def _lane_dense_stats(row_stats):
-    """``row_stats`` as the pallas kernel takes it, ``[S_pad, N]`` /
-    ``[T, S_pad, N]``: rows along lanes and the statistics padded with
-    zero rows to the float32 sublane tile of 8.  Shared ``[N, S]``
-    statistics are transposed here, once a fit (``[N, 15]`` lies tiled to
-    128 lanes in HBM, eight times its bytes; this lies dense); per-tree
-    ones arrive lane-dense and only gain the zero rows."""
-    S = _stats_width(row_stats)
-    if row_stats.ndim == 3:
-        return jnp.pad(row_stats, ((0, 0), (0, -S % 8), (0, 0)))
-    return jnp.pad(row_stats, ((0, 0), (0, -S % 8))).T
+    """``row_stats`` as the pallas kernel takes it, the rows along lanes:
+    shared ``[N, S]`` statistics transposed to ``[S, N]`` (``[N, 15]``
+    lies tiled to 128 lanes in HBM, eight times its bytes; this lies
+    dense), per-tree ``[T, S, N]`` ones with the statistic outermost,
+    ``[S, T, N]``, so that one statistic of a block of trees is whole
+    sublane tiles (the kernel stacks the trees of a level as columns of
+    one product).  One tree's own statistics are shared ones.  Made once
+    a fit; the kernel reads past the last statistic or tree of a block
+    itself, so nothing is padded here."""
+    if row_stats.ndim == 2:
+        return row_stats.T
+    if row_stats.shape[0] == 1:
+        return row_stats[0]
+    return jnp.swapaxes(row_stats, 0, 1)
 
 
 @jax.jit
@@ -814,7 +837,9 @@ def grow_forest(
         return Forest(feature, threshold, leaf_stats, max_depth,
                       np.zeros((T, H), np.float32), np.zeros((T, H), np.float32))
 
-    plan = _level_plan(T, binned.shape[1], n_bins, S, max_depth, mesh)
+    plan = _level_plan(
+        T, binned.shape[1], n_bins, S, max_depth, mesh, row_stats.ndim == 3
+    )
     keys = jax.random.split(jax.random.PRNGKey(seed), max_depth)
     if row_label is not None:
         # out-of-range labels (e.g. a -1 sentinel) must contribute ZERO,

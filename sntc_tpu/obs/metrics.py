@@ -494,6 +494,20 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "segment / mesh — a dispatch sharded over the serve mesh: the "
         "kernel tier is single-device).",
     ),
+    "sntc_kernel_tree_hist_column_tiles_total": dict(
+        type=COUNTER, labels=(),
+        help="128-column MXU array tiles the planned tree_hist products "
+        "of the fits issue (a level's trees stacked as columns of one "
+        "product; counted once a fit from the static plan, "
+        "grower._level_plan). The kernel's time follows this count.",
+    ),
+    "sntc_kernel_tree_hist_columns_total": dict(
+        type=COUNTER, labels=(),
+        help="Columns among those tiles that carry a term of a statistic "
+        "(3 bfloat16 terms x trees x histogrammed nodes x statistics); "
+        "over 128 x sntc_kernel_tree_hist_column_tiles_total it is the "
+        "array's fill.",
+    ),
     "sntc_kernel_poisoned_signatures": dict(
         type=GAUGE, labels=(),
         help="(kernel, signature) pairs poisoned onto the XLA twin "

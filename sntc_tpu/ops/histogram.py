@@ -54,15 +54,15 @@ def binned_contingency_onehot(
 
     # class indicators with the rows along lanes, a whole sublane tile of
     # them (the classes past ``n_classes`` match no label); the kernel
-    # multiplies the row weight in on its own tile
+    # multiplies the row weight in on its own tile.  One "tree", one node
     yoh_t = jax.nn.one_hot(
         y, -(-n_classes // 8) * 8, dtype=jnp.float32, axis=0
     )
-    node0 = jnp.zeros(y.shape[0], jnp.int32)
+    node0 = jnp.zeros((1, y.shape[0]), jnp.int32)
     return level_histogram_pallas(
-        binned.T, node0, yoh_t, w,
+        binned.T, node0, yoh_t, w[None, :],
         n_nodes=1, n_bins=n_bins,
-    )[..., :n_classes]  # [F, B, C]
+    )[0, ..., :n_classes]  # [F, B, C]
 
 
 def chi_square(observed: np.ndarray) -> tuple:
