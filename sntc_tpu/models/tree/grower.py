@@ -42,7 +42,7 @@ import numpy as np
 from sntc_tpu.obs import module_of, span
 from sntc_tpu.obs.metrics import inc
 from sntc_tpu.parallel.collectives import _put_sharded
-from sntc_tpu.parallel.mesh import map_at, payload_nbytes, record_collective
+from sntc_tpu.parallel.mesh import map_at, record_collective
 
 _MODULE = module_of(__name__)
 
@@ -315,7 +315,16 @@ def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
     (128-column array tiles, which the kernel's time follows) and
     ``sntc_kernel_tree_hist_columns_total`` (columns among them that carry
     a term of a statistic); columns / (128 x tiles) is the array's fill.
-    ``per_tree_stats`` (boosting) only decides how the columns lie."""
+    ``per_tree_stats`` (boosting) only decides how the columns lie.
+
+    The kernel runs per shard of the mesh and every node-group pass sums
+    its histogram over the shards (:func:`_group_hist`'s ``psum``, the
+    fit's one collective).  Those are counted here too, outside the trace
+    of :func:`_grow_fused`, so they count fits and not compilations:
+    ``sntc_kernel_tree_hist_psum_total`` and
+    ``sntc_kernel_tree_hist_psum_bytes_total`` (the summed histograms'
+    bytes), both 0 on a mesh of one, and the fit's one dispatch in the
+    ``sntc_collective_*`` series (``op="tree.histogram"``)."""
     # imported where used: Pallas costs a second to import, and a process
     # that grows no tree (the MLP's fit, the serve path) should not pay it
     from sntc_tpu.ops.pallas_histogram import (
@@ -339,17 +348,29 @@ def _level_plan(T: int, F: int, n_bins: int, S: int, max_depth: int,
         for d in range(max_depth)
     )
     if hist_impl == "pallas":
-        tiles = columns = 0
+        tiles = columns = passes = hist_nodes = 0
         for d in range(max_depth):
             g = min(1 << d, group)
             # a level whose parent's histogram was kept histograms the
             # left children only (:func:`_eval_node_group`)
             halved = d > 0 and keep_hists[d - 1] and g >= 2
-            call = column_tiles(T, S, per_tree_stats, g // 2 if halved else g)
-            tiles += ((1 << d) // g) * call[0]
-            columns += ((1 << d) // g) * call[1]
+            g_eff = g // 2 if halved else g
+            call = column_tiles(T, S, per_tree_stats, g_eff)
+            n_pass = (1 << d) // g  # node-group passes of the level
+            tiles += n_pass * call[0]
+            columns += n_pass * call[1]
+            passes += n_pass
+            hist_nodes += n_pass * g_eff
         inc("sntc_kernel_tree_hist_column_tiles_total", tiles)
         inc("sntc_kernel_tree_hist_columns_total", columns)
+        axis = mesh.axis_names[0]
+        n_shards = int(mesh.shape[axis])
+        payload = hist_nodes * hist_bytes
+        inc("sntc_kernel_tree_hist_psum_total",
+            passes if n_shards > 1 else 0)
+        inc("sntc_kernel_tree_hist_psum_bytes_total",
+            payload if n_shards > 1 else 0)
+        record_collective("tree.histogram", axis, n_shards, payload)
     return LevelPlan(hist_impl, group, keep_hists)
 
 
@@ -634,7 +655,9 @@ def _group_hist(
             hs = level_histogram_pallas(
                 bt, ni, st, wt, n_nodes=n_nodes, n_bins=n_bins
             )  # [T, F, nodes*B, S]
-            return jax.lax.psum(hs, axis)
+            # the fit's one collective, counted by :func:`_level_plan`
+            with jax.named_scope("tree.histogram.psum"):
+                return jax.lax.psum(hs, axis)
 
         hists = map_at(
             mesh, shard_fn,
@@ -643,9 +666,6 @@ def _group_hist(
             check_vma=False,  # pallas_call outputs carry no vma metadata
             jit=False,  # rebuilt per level; an outer jit would recompile
         )(binned_t, stats_t, w_trees, node_idx)
-        record_collective(
-            "tree.histogram", axis, mesh.shape[axis], payload_nbytes(hists)
-        )
     elif (
         row_label is not None
         and row_weight is not None

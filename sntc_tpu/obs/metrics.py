@@ -508,6 +508,19 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "over 128 x sntc_kernel_tree_hist_column_tiles_total it is the "
         "array's fill.",
     ),
+    "sntc_kernel_tree_hist_psum_total": dict(
+        type=COUNTER, labels=(),
+        help="All-reduces (psum over the mesh's row axis) the planned "
+        "tree_hist histograms of the fits take: one a node-group pass a "
+        "level, counted once a fit from the static plan "
+        "(grower._level_plan), outside the trace; 0 on a mesh of one.",
+    ),
+    "sntc_kernel_tree_hist_psum_bytes_total": dict(
+        type=COUNTER, labels=(),
+        help="Payload bytes of those all-reduces: the float32 histograms "
+        "summed over the shards ([trees, nodes, features, bins, "
+        "statistics] a pass); 0 on a mesh of one.",
+    ),
     "sntc_kernel_poisoned_signatures": dict(
         type=GAUGE, labels=(),
         help="(kernel, signature) pairs poisoned onto the XLA twin "

@@ -37,6 +37,7 @@ the recursion depth bounded by the domain's ``oom_split_depth``.
 
 from __future__ import annotations
 
+import math
 import os
 from collections import OrderedDict
 from typing import Callable, Optional
@@ -155,8 +156,9 @@ def _ledger_movement(nbytes: int) -> None:
 # WEAK reference to the host array: a live array re-used is a hit; once the
 # caller drops the array the entry dies with it (no pinning of throwaway
 # uploads) and a recycled ``id`` can never false-hit because the dead
-# weakref invalidates the entry.  Byte-bounded LRU on the device side;
-# ``SNTC_DEVICE_CACHE_MB=0`` disables.
+# weakref invalidates the entry.  Byte-bounded LRU on the device side, the
+# bytes counted a device (:func:`_device_bytes`); ``SNTC_DEVICE_CACHE_MB=0``
+# disables.
 # ---------------------------------------------------------------------------
 
 _DEVICE_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
@@ -164,6 +166,14 @@ _DEVICE_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
 
 def _device_cache_max_bytes() -> int:
     return int(os.environ.get("SNTC_DEVICE_CACHE_MB", "2048")) * (1 << 20)
+
+
+def _device_bytes(dev) -> int:
+    """Bytes ``dev`` takes on the fullest device that holds a part of it:
+    what the cache's budget bounds.  A row-sharded copy costs each device
+    its shard, so a mesh of four keeps what a mesh of one keeps of a
+    quarter of the rows."""
+    return math.prod(dev.sharding.shard_shape(dev.shape)) * dev.dtype.itemsize
 
 
 def _spans_processes(mesh: Mesh) -> bool:
@@ -191,6 +201,20 @@ def _global_shard_put(arr_p, sharding):
     )
 
 
+def _shard_attrs(arr, sharding) -> dict:
+    """``shards`` / ``shard_bytes`` of the ``h2d.*`` spans: the devices
+    ``sharding`` cuts ``arr`` over (the mesh axes its spec names; 1 for a
+    replicated placement or a mesh of one) and the bytes one of them
+    takes."""
+    shards = 1
+    mesh = getattr(sharding, "mesh", None)
+    for entry in getattr(sharding, "spec", ()):
+        for axis in (entry,) if isinstance(entry, str) else (entry or ()):
+            shards *= int(mesh.shape[axis])
+    nbytes = int(getattr(arr, "nbytes", 0))
+    return {"shards": shards, "shard_bytes": -(-nbytes // shards)}
+
+
 def _put_sharded(arr, sharding):
     """The one routing point: global construction when the mesh spans
     processes, plain ``device_put`` otherwise.  Every byte that crosses
@@ -198,7 +222,8 @@ def _put_sharded(arr, sharding):
     collective dispatches undercounting the ``sntc_transfer_*``
     series."""
     nbytes = getattr(arr, "nbytes", 0)
-    with span("h2d.put", bytes=int(nbytes), module=_MODULE):
+    with span("h2d.put", bytes=int(nbytes), module=_MODULE,
+              **_shard_attrs(arr, sharding)):
         if _spans_processes(sharding.mesh):
             out = _global_shard_put(arr, sharding)
         else:
@@ -238,7 +263,8 @@ def _cached_shard_put(arr, n_pad: int, sharding):
             arr_p = jnp.concatenate([arr, pad_block], axis=0)
         else:
             # a host copy of the whole array for the sake of its last rows
-            with span("h2d.pad", bytes=int(arr.nbytes), module=_MODULE):
+            with span("h2d.pad", bytes=int(arr.nbytes), module=_MODULE,
+                      **_shard_attrs(arr, sharding)):
                 pad_block = np.broadcast_to(
                     arr[:1], (n_pad - n,) + arr.shape[1:]
                 )
@@ -252,10 +278,10 @@ def _cached_shard_put(arr, n_pad: int, sharding):
         except TypeError:  # non-weakref-able array subclass
             return dev
         _DEVICE_CACHE[key] = (ref, dev)
-        total = sum(e[1].nbytes for e in _DEVICE_CACHE.values())
+        total = sum(_device_bytes(e[1]) for e in _DEVICE_CACHE.values())
         while total > _device_cache_max_bytes() and len(_DEVICE_CACHE) > 1:
             _, old = _DEVICE_CACHE.popitem(last=False)
-            total -= old[1].nbytes
+            total -= _device_bytes(old[1])
     return dev
 
 
@@ -287,7 +313,11 @@ def shard_batch(mesh: Mesh, *arrays: np.ndarray, axis_name: str = DATA_AXIS):
     in this framework uses (SURVEY.md §7.2 mitigation for static shapes).
     Padding replicates row 0 (not zeros) so padded rows stay numerically
     benign under ops like log/σ; their weight removes them from results.
+
+    Where a fit takes its mesh: the mesh gauge
+    (``sntc_collective_mesh_devices``) is set here.
     """
+    record_mesh_shape(mesh)
     n = arrays[0].shape[0]
     n_shards = mesh.shape[axis_name]
     n_pad = pad_rows(n, n_shards)

@@ -272,8 +272,13 @@ def collective_wire_bytes(n_shards: int, payload_bytes: int) -> int:
 def record_collective(
     op: str, axis_name: str, n_shards: int, payload_bytes: int
 ) -> None:
-    """Host-side evidence for one collective dispatch (never inside a
-    trace — these are python counters)."""
+    """Host-side evidence for one collective dispatch.  These are python
+    counters: call it where the program is DISPATCHED, never inside a
+    ``jit``'s trace (there it would count compilations).  Callers:
+    ``collectives.make_tree_aggregate`` after each dispatch, the
+    dispatch sites of kmeans / lda / pic, and ``grower._level_plan``,
+    once a ``grow_forest`` call, with the summed payload of the level
+    histograms that call's one program all-reduces."""
     try:
         from sntc_tpu.obs.metrics import inc
 

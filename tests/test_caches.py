@@ -50,6 +50,22 @@ def test_shard_batch_cache_entry_dies_with_array(mesh8):
     assert all(e[0]() is not None for e in _DEVICE_CACHE.values())
 
 
+def test_device_cache_budget_counts_bytes_a_device(mesh8, monkeypatch):
+    """A row-sharded copy costs each device its shard: under a 2 MiB budget
+    two 4 MiB matrices sharded over 8 devices (0.5 MiB a device each) both
+    stay; on a mesh of one the second evicts the first."""
+    from sntc_tpu.parallel import default_mesh
+
+    monkeypatch.setenv("SNTC_DEVICE_CACHE_MB", "2")
+    A, B = (np.full((16_384, 64), v, np.float32) for v in (1.0, 2.0))
+    for mesh, kept in ((mesh8, True), (default_mesh(1), False)):
+        _DEVICE_CACHE.clear()
+        a1, _ = shard_batch(mesh, A)
+        shard_batch(mesh, B)
+        a2, _ = shard_batch(mesh, A)
+        assert (a1 is a2) is kept
+
+
 def test_device_cache_kill_switch(mesh8, monkeypatch):
     monkeypatch.setenv("SNTC_DEVICE_CACHE_MB", "0")
     X = np.random.default_rng(3).normal(size=(5000, 60)).astype(np.float32)

@@ -4,7 +4,10 @@ a small size on the CPU: on seeded frames every number the benchmark's
 ``compare`` reads lies under the configuration's limit, the bfloat16 control
 (the reference in the program's place with its statistics rounded to one
 bfloat16 term before they are summed) lies over at least one, and the
-harness's CPU rehearsal of the cell comes out ``correct``."""
+harness's CPU rehearsal of the cell comes out ``correct``.  The same seeds
+again as the four-chip deployment runs them (``cicflow_gbt_whole``): rows
+sharded over ``default_mesh(4)``, the ``tree_hist`` kernel per shard (the
+Pallas interpreter here) and its histograms summed by ``psum``."""
 
 import json
 import os
@@ -28,9 +31,12 @@ def bench():
     import run
 
     cell, cfg, traffic = run.resolve_pair("cicflow_gbt", "fit_full")
+    whole = run.resolve_pair("cicflow_gbt_whole", "fit_full")[1]
     adapter = run.load_module("estimators", cfg["estimator"])
     return {"run": run, "gen": gen, "cfg": cfg, "adapter": adapter,
-            "limits": cfg["limits"]["fit"], "rows": cfg["rehearse_rows"]}
+            "limits": cfg["limits"]["fit"], "rows": cfg["rehearse_rows"],
+            # by the chips the configuration's deployment takes
+            "cfgs": {1: cfg, 4: whole}}
 
 
 @pytest.fixture(scope="module")
@@ -38,19 +44,32 @@ def frames(bench):
     return {s: bench["gen"].generate_columns(bench["rows"], s) for s in SEEDS}
 
 
+@pytest.mark.parametrize("chips", (1, 4))
 @pytest.mark.parametrize("seed", SEEDS)
-def test_program_reads_under_every_limit(bench, frames, seed):
+def test_program_reads_under_every_limit(bench, frames, seed, chips,
+                                         monkeypatch):
+    from sntc_tpu.obs import registry
     from sntc_tpu.parallel.mesh import default_mesh
 
-    run, adapter, cfg = bench["run"], bench["adapter"], bench["cfg"]
+    run, adapter, cfg = bench["run"], bench["adapter"], bench["cfgs"][chips]
+    limits = cfg["limits"]["fit"]
+    if chips > 1:  # as on the chips: the kernel per shard, then the psum
+        monkeypatch.setenv("SNTC_TREE_HIST", "pallas")
+    def counted():
+        return registry().get("sntc_kernel_tree_hist_psum_total") or 0
+
+    psums = counted()
     s = run.model_seed(seed)
-    res = run.KINDS["fit"](adapter, cfg, frames[seed], default_mesh(1), s)()
+    res = run.KINDS["fit"](adapter, cfg, frames[seed], default_mesh(chips),
+                           s)()
     product = adapter.extract_product("fit", res)
     assert product["feature"].shape == (15, 20, 63)
     numbers = adapter.compare("fit", product, cfg, frames[seed], s)
-    correct, checks = run.judge(numbers, bench["limits"])
+    correct, checks = run.judge(numbers, limits)
     assert correct, checks
-    assert set(bench["limits"]) <= set(numbers)
+    assert set(limits) <= set(numbers)
+    # one all-reduce a level a round, counted a fit and not a compilation
+    assert counted() - psums == (cfg["maxIter"] * cfg["maxDepth"] if chips > 1 else 0)
 
 
 @pytest.mark.parametrize("seed", SEEDS)

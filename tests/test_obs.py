@@ -473,6 +473,12 @@ def test_uploads_cross_the_one_routing_point(mesh8):
     ]
     assert all(s["attrs"]["module"] in ("models", "parallel")
                for s in t.spans())
+    # a placement says how many devices it cuts the array over and what
+    # one of them takes: rows over the 8 devices of the mesh
+    puts = [s["attrs"] for s in t.spans() if s["name"] == "h2d.put"]
+    assert [(a["shards"], a["shard_bytes"]) for a in puts] == [
+        (8, 4 * 64 * 4 // 8), (8, 64 * 4 // 8),
+    ]
     # the bagging weights used to bypass the ledger (a bare device_put)
     assert (_get("sntc_transfer_upload_bytes_total") - before
             == 4 * 64 * 4 + 64 * 4)

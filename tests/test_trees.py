@@ -575,7 +575,7 @@ def test_gbt_regressor_validated_early_stop(mesh8):
     ("tpu", (100, 78, 256, 15, 6), "mesh",
      ("pallas", 2, (True, True, True, True, False, False))),
 ])
-def test_level_plan(monkeypatch, backend, shapes, mesh, want):
+def test_level_plan(monkeypatch, mesh8, backend, shapes, mesh, want):
     """The fit's one decision point: implementation, node group and
     sibling gate from the shapes, the mesh and the backend."""
     import jax
@@ -584,7 +584,8 @@ def test_level_plan(monkeypatch, backend, shapes, mesh, want):
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.delenv("SNTC_TREE_HIST", raising=False)
-    plan = _level_plan(*shapes, mesh and object())
+    # a real mesh: the plan counts the psums its histograms take over it
+    plan = _level_plan(*shapes, mesh and mesh8)
     assert plan == LevelPlan(*want)
     hash(plan)  # a static jit argument
 
