@@ -254,6 +254,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         type=COUNTER, labels=("tenant",),
         help="Bytes materialized device→host by fused finalizes.",
     ),
+    "sntc_transfer_pad_bytes_total": dict(
+        type=COUNTER, labels=("where",),
+        help="Bytes touched to pad a row-sharded placement to its shard "
+        "multiple (parallel/collectives.py:_cached_shard_put): "
+        "where=host counts the whole host array each time it is copied "
+        "on the host for the pad's sake (arrays under 1 MiB, and meshes "
+        "that span processes), where=device the shard padded on its own "
+        "chip (fit-scale arrays: no host copy).",
+    ),
     # -- host copies of a whole vector column on the fit path (feature/) ----
     "sntc_feature_copy_bytes_total": dict(
         type=COUNTER, labels=("site", "layout"),

@@ -37,16 +37,20 @@ the recursion depth bounded by the domain's ``oom_split_depth``.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
+import weakref
 from collections import OrderedDict
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sntc_tpu.obs import module_of, span
+from sntc_tpu.obs.metrics import inc
 from sntc_tpu.parallel.mesh import (
     DATA_AXIS,
     map_reduce_at,
@@ -232,16 +236,88 @@ def _put_sharded(arr, sharding):
     return out
 
 
-def _cached_shard_put(arr, n_pad: int, sharding):
-    """Pad ``arr`` to ``n_pad`` rows (replicating row 0) and device_put it
-    under ``sharding``, memoized on the identity of the UNPADDED array."""
-    import weakref
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _pad_shard_rows(row0, real=None, *, rows: int):
+    """One shard's ``rows`` rows, made on the shard's own chip: row 0 of
+    the whole array replicated, with the real rows the shard was sent
+    (none for a shard past the array's end) written over its head.
+    Module-level on purpose: a fresh ``jit`` object a call would miss the
+    compile cache every fit (see ``gbt._broadcast_classes``).  An
+    update-slice and not a ``concatenate``: the TPU compiler turns a
+    concatenate into a ``maximum`` of two padded operands, which flushes
+    denormals and rewrites NaN payloads (read on the chip, PERF.md §6
+    PR 36); this form only moves bytes, so the shard is bit for bit what
+    ``device_put`` of a host-padded copy gives."""
+    out = jnp.broadcast_to(row0, (rows,) + row0.shape[1:])
+    if real is None:
+        return out
+    return jax.lax.dynamic_update_slice(out, real, (0,) * real.ndim)
 
-    cacheable = (
-        isinstance(arr, np.ndarray)
-        and arr.nbytes >= (1 << 20)
-        and _device_cache_max_bytes() > 0
-    )
+
+def _put_row_shards(arr: np.ndarray, n_pad: int, sharding):
+    """Place a host array row-sharded and padded to ``n_pad`` rows without
+    copying it on the host first: every device is sent a VIEW of its real
+    rows (strided or not: the runtime reads a feature-major ``base.T`` in
+    place, as it reads ``device_put``'s own shard views), and a shard
+    short of its rows is padded where it lands, by
+    :func:`_pad_shard_rows` and a one-row put of row 0.  The result is the
+    array ``device_put(np.concatenate([arr, row 0 ...]), sharding)`` gives:
+    same shape, dtype, sharding and values.  A short shard's unpadded
+    buffer lives on its chip beside the padded one for the length of that
+    one program: nothing here keeps it past the call."""
+    n = arr.shape[0]
+    shape = (n_pad,) + arr.shape[1:]
+    rows = sharding.shard_shape(shape)[0]
+    index_map = sharding.addressable_devices_indices_map(shape)
+    attrs = _shard_attrs(arr, sharding)
+    # Each shard's copy is waited for.  Between shards: four 1.3 GB copies
+    # issued together leave the last 4.7-5.0 s behind the others' 0.18 s;
+    # one after another they take 0.14 s each.  After the last: the host
+    # would otherwise run ahead and enqueue the fit's next programs, whose
+    # buffers are allocated at enqueue, while the short shard's unpadded
+    # rows still wait for their pad program: +0.55 GB at a boosted fit's
+    # peak (four and one v5e chips: PERF.md §7 (vi), (xv)).  Nothing is
+    # copied on the host meanwhile, and ``h2d.put`` times the copy.
+    with span("h2d.put", bytes=int(arr.nbytes), module=_MODULE, **attrs):
+        parts = []  # a device's real rows; None for a shard past the end
+        for dev, idx in index_map.items():
+            start = idx[0].indices(n_pad)[0]
+            if start >= n:
+                parts.append(None)
+                continue
+            parts.append(jax.device_put(arr[start:start + rows], dev))
+            parts[-1].block_until_ready()  # numpy clipped the slice at n
+    short = [
+        (i, dev) for i, dev in enumerate(index_map)
+        if parts[i] is None or parts[i].shape[0] < rows
+    ]
+    row_bytes = arr.nbytes // n
+    padded_bytes = len(short) * rows * row_bytes
+    with span("h2d.pad", bytes=padded_bytes, where="device",
+              module=_MODULE, **attrs):
+        for i, dev in short:
+            row0 = jax.device_put(arr[:1], dev)
+            parts[i] = _pad_shard_rows(row0, parts[i], rows=rows)
+    inc("sntc_transfer_pad_bytes_total", padded_bytes, where="device")
+    # what crossed: the array once, and row 0 again for every padded shard
+    _ledger_movement(int(arr.nbytes) + len(short) * row_bytes)
+    return jax.make_array_from_single_device_arrays(shape, sharding, parts)
+
+
+def _cached_shard_put(arr, n_pad: int, sharding):
+    """Pad ``arr`` to ``n_pad`` rows (replicating row 0) and place it under
+    ``sharding``, memoized on the identity of the UNPADDED array.
+
+    Where the pad happens: a fit-scale host array (1 MiB and over) is
+    never copied on the host; its shards are put from views and the short
+    one is padded on its chip (:func:`_put_row_shards`, ``h2d.pad``
+    ``where="device"``).  A smaller host array, and any host array on a
+    mesh that spans processes, is padded by a host ``np.concatenate``
+    (``where="host"``: microseconds for kilobytes, where a device pad
+    costs a dispatch and a compile a distinct shape).  A ``jax.Array`` is
+    padded on the device it lives on."""
+    fit_scale = isinstance(arr, np.ndarray) and arr.nbytes >= (1 << 20)
+    cacheable = fit_scale and _device_cache_max_bytes() > 0
     # sweep entries whose host array was garbage-collected
     for k in [k for k, e in _DEVICE_CACHE.items() if e[0]() is None]:
         del _DEVICE_CACHE[k]
@@ -252,36 +328,43 @@ def _cached_shard_put(arr, n_pad: int, sharding):
             _DEVICE_CACHE.move_to_end(key)
             return hit[1]
     n = arr.shape[0]
-    if n_pad != n:
-        if isinstance(arr, jax.Array):
-            # device-resident input: pad on device, never revisit the host
-            import jax.numpy as jnp
-
-            pad_block = jnp.broadcast_to(
+    if cacheable:
+        # make room BEFORE the new copy lands, not after: the placement
+        # below holds a short shard twice for the length of one program,
+        # and an entry the budget is about to evict must not stand beside
+        # that (same survivors as evicting after the insert)
+        total = math.prod(
+            sharding.shard_shape((n_pad,) + arr.shape[1:])
+        ) * jax.dtypes.canonicalize_dtype(arr.dtype).itemsize
+        total += sum(_device_bytes(e[1]) for e in _DEVICE_CACHE.values())
+        while total > _device_cache_max_bytes() and _DEVICE_CACHE:
+            total -= _device_bytes(_DEVICE_CACHE.popitem(last=False)[1][1])
+    if n_pad == n:
+        dev = _put_sharded(arr, sharding)
+    elif isinstance(arr, jax.Array):
+        # device-resident input: pad on device, never revisit the host
+        pad_block = jnp.broadcast_to(arr[:1], (n_pad - n,) + arr.shape[1:])
+        dev = _put_sharded(
+            jnp.concatenate([arr, pad_block], axis=0), sharding
+        )
+    elif fit_scale and not _spans_processes(sharding.mesh):
+        dev = _put_row_shards(arr, n_pad, sharding)
+    else:
+        # a host copy of the whole array for the sake of its last rows
+        with span("h2d.pad", bytes=int(arr.nbytes), where="host",
+                  module=_MODULE, **_shard_attrs(arr, sharding)):
+            pad_block = np.broadcast_to(
                 arr[:1], (n_pad - n,) + arr.shape[1:]
             )
-            arr_p = jnp.concatenate([arr, pad_block], axis=0)
-        else:
-            # a host copy of the whole array for the sake of its last rows
-            with span("h2d.pad", bytes=int(arr.nbytes), module=_MODULE,
-                      **_shard_attrs(arr, sharding)):
-                pad_block = np.broadcast_to(
-                    arr[:1], (n_pad - n,) + arr.shape[1:]
-                )
-                arr_p = np.concatenate([arr, pad_block], axis=0)
-    else:
-        arr_p = arr
-    dev = _put_sharded(arr_p, sharding)
+            arr_p = np.concatenate([arr, pad_block], axis=0)
+        inc("sntc_transfer_pad_bytes_total", int(arr.nbytes), where="host")
+        dev = _put_sharded(arr_p, sharding)
     if cacheable:
         try:
             ref = weakref.ref(arr)
         except TypeError:  # non-weakref-able array subclass
             return dev
         _DEVICE_CACHE[key] = (ref, dev)
-        total = sum(_device_bytes(e[1]) for e in _DEVICE_CACHE.values())
-        while total > _device_cache_max_bytes() and len(_DEVICE_CACHE) > 1:
-            _, old = _DEVICE_CACHE.popitem(last=False)
-            total -= _device_bytes(old[1])
     return dev
 
 
@@ -313,6 +396,10 @@ def shard_batch(mesh: Mesh, *arrays: np.ndarray, axis_name: str = DATA_AXIS):
     in this framework uses (SURVEY.md §7.2 mitigation for static shapes).
     Padding replicates row 0 (not zeros) so padded rows stay numerically
     benign under ops like log/σ; their weight removes them from results.
+    Where the pad happens (:func:`_cached_shard_put`): a host array of
+    1 MiB and over is placed from views of its unpadded rows and the short
+    shard is padded on its own chip, so a fit-scale matrix is never copied
+    on the host; a smaller one is padded by a host copy before the put.
 
     Where a fit takes its mesh: the mesh gauge
     (``sntc_collective_mesh_devices``) is set here.

@@ -66,6 +66,54 @@ def test_device_cache_budget_counts_bytes_a_device(mesh8, monkeypatch):
         assert (a1 is a2) is kept
 
 
+def test_device_cache_makes_room_before_the_new_copy_lands(monkeypatch):
+    """The entry the budget evicts goes before the new array is placed,
+    not after: the placement holds a short shard twice for the length of
+    one program, and on the chip the evicted copy stood beside that."""
+    import sntc_tpu.parallel.collectives as C
+    from sntc_tpu.parallel import default_mesh
+
+    monkeypatch.setenv("SNTC_DEVICE_CACHE_MB", "2")
+    mesh1 = default_mesh(1)
+    A, B = (np.full((16_385, 64), v, np.float32) for v in (1.0, 2.0))
+    _DEVICE_CACHE.clear()
+    a1, _ = shard_batch(mesh1, A)  # 4 MiB: over the budget, kept alone
+    assert [e[1] is a1 for e in _DEVICE_CACHE.values()] == [True]
+    held = []
+    place = C._put_row_shards
+    monkeypatch.setattr(
+        C, "_put_row_shards",
+        lambda *a: (held.append(len(_DEVICE_CACHE)), place(*a))[1],
+    )
+    b1, _ = shard_batch(mesh1, B)
+    assert held == [0]  # A's copy was already out when B's went in
+    assert [e[1] is b1 for e in _DEVICE_CACHE.values()] == [True]
+
+
+def test_second_placement_of_a_shape_builds_no_program(mesh8):
+    """The program that pads the short shard on its chip is one
+    module-level ``jit``: a fit's second pass places fresh arrays of the
+    same shapes and must trace and compile nothing (what keeps
+    ``compiles_in_window.fit`` at 0); another row count is one more small
+    program an array."""
+    from sntc_tpu.parallel.collectives import _pad_shard_rows
+
+    def place(n, fill):
+        X = np.full((n, 60), fill, np.float32)   # over 1 MiB: device pad
+        y = np.full(n, int(fill), np.int32)
+        xs, ys, _ = shard_batch(mesh8, X, y)
+        assert xs.shape[0] == ys.shape[0] == pad_rows(n, 8) > n
+        return float(np.asarray(xs)[-1, 0])
+
+    assert place(300_001, 1.0) == 1.0
+    size = _pad_shard_rows._cache_size()
+    assert size >= 2  # the matrix's and the label vector's
+    assert place(300_001, 2.0) == 2.0
+    assert _pad_shard_rows._cache_size() == size
+    place(150_001, 4.0)
+    assert _pad_shard_rows._cache_size() > size
+
+
 def test_device_cache_kill_switch(mesh8, monkeypatch):
     monkeypatch.setenv("SNTC_DEVICE_CACHE_MB", "0")
     X = np.random.default_rng(3).normal(size=(5000, 60)).astype(np.float32)

@@ -484,6 +484,41 @@ def test_uploads_cross_the_one_routing_point(mesh8):
             == 4 * 64 * 4 + 64 * 4)
 
 
+def test_fit_scale_placement_is_one_put_span_and_one_device_pad(mesh8):
+    """A fit-scale ``shard_batch`` call: one ``h2d.put`` an array over all
+    its shard puts (``bytes`` the unpadded array's), then one ``h2d.pad``
+    that says the pad ran on the device, then the weights' put."""
+    from sntc_tpu.parallel import pad_rows, shard_batch
+
+    n = 200_001
+    X = np.ones((n, 8), np.float32)  # 6.4 MB: over the 1 MiB floor
+    n_pad = pad_rows(n, 8)
+    per_bytes = n_pad // 8 * 8 * 4
+    t = enable_tracing(capacity=64)
+    before = _get("sntc_transfer_upload_bytes_total")
+    shard_batch(mesh8, X)
+    spans = sorted(t.spans(), key=lambda s: s["t0"])
+    assert [(s["name"], s["attrs"]["bytes"]) for s in spans] == [
+        ("h2d.put", X.nbytes), ("h2d.pad", per_bytes), ("h2d.put", n_pad * 4),
+    ]
+    assert all(s["attrs"]["module"] == "parallel" for s in spans)
+    assert all(s["parent"] is None for s in spans)
+    assert [s["attrs"]["shards"] for s in spans] == [8, 8, 8]
+    assert spans[0]["attrs"]["shard_bytes"] == -(-X.nbytes // 8)
+    assert spans[1]["attrs"]["where"] == "device"
+    assert "where" not in spans[0]["attrs"]
+    # what crossed: the matrix, row 0 for the padded shard, the weights
+    assert (_get("sntc_transfer_upload_bytes_total") - before
+            == X.nbytes + 8 * 4 + n_pad * 4)
+    # under the floor the host copy still runs, and says so
+    t.clear()
+    shard_batch(mesh8, np.ones((17, 8), np.float32))
+    assert [(s["name"], s["attrs"].get("where")) for s in
+            sorted(t.spans(), key=lambda s: s["t0"])] == [
+        ("h2d.pad", "host"), ("h2d.put", None), ("h2d.put", None),
+    ]
+
+
 def test_one_vs_rest_boosting_opens_a_span_and_counts_each_round(mesh8):
     """``maxIter`` ``gbt.round`` spans (``module=models``, the round's
     number, K class trees), the grower's fetch nested in each, and

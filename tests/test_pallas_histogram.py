@@ -1,6 +1,8 @@
 """Pallas histogram kernel vs the segment-sum reference (interpret mode on
 CPU; the same kernel compiles for TPU via mosaic)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -617,3 +619,33 @@ def test_kernel_compiles_for_the_chip(
         sds((t, n), jnp.float32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4_058_236, 78), jnp.float32),  # the cells' matrix on one chip
+    ((4_043_247, 78), jnp.float32),  # the whole set's last shard of four
+    ((4_058_236, 40), jnp.float32),  # the forest's selected columns
+    ((4_058_236,), jnp.float32),     # a label vector
+    ((4_058_236,), jnp.int32),
+])
+def test_shard_pad_program_only_moves_bytes_on_the_chip(
+    v5e_chip, shape, dtype
+):
+    """``collectives._pad_shard_rows`` at the cells' sizes, compiled for
+    the chip: no arithmetic (the compiler turns a ``concatenate`` into a
+    ``maximum``, which flushes denormals and rewrites NaN payloads on the
+    TPU: read there, PR 36; no CPU run can see it), no temporaries, the
+    padded shard out."""
+    from sntc_tpu.parallel.collectives import _pad_shard_rows
+
+    rows = 4_063_232
+    compiled = _pad_shard_rows.lower(
+        jax.ShapeDtypeStruct((1,) + shape[1:], dtype, sharding=v5e_chip),
+        jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip),
+        rows=rows,
+    ).compile()
+    text = compiled.as_text()
+    assert "maximum" not in text and "minimum" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes >= rows * math.prod(shape[1:]) * 4
