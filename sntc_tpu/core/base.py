@@ -17,16 +17,20 @@ stages are plain Python objects whose numeric inner loops dispatch to JAX/XLA.
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Any, Dict, List, Optional
 
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import NO_DEFAULT, Param, Params
-from sntc_tpu.obs import module_of, span
+from sntc_tpu.obs import module_of, set_gauge, span
 
 _MODULE = module_of(__name__)
 #: per-process count of ``Pipeline.fit`` / ``PipelineModel.transform``
 #: calls: the ``run=`` attribute that tells one root span from the next
 _RUNS = itertools.count(1)
+#: whether this process has entered a ``Pipeline.fit`` yet (``_RUNS``
+#: counts transforms too, so ``run=1`` does not say it)
+_first_fit_claimed = False
 
 
 def _stage_span(op: str, stage, index: int):
@@ -168,8 +172,11 @@ class Pipeline(Estimator):
             (i for i, s in enumerate(stages) if isinstance(s, Estimator)),
             default=-1,
         )
+        global _first_fit_claimed
+        first, _first_fit_claimed = not _first_fit_claimed, True
         fitted: List[Transformer] = []
         current = frame
+        t0 = time.perf_counter()
         with span("pipeline.fit", stages=len(stages), run=next(_RUNS),
                   module=_MODULE):
             for i, stage in enumerate(stages):
@@ -181,6 +188,9 @@ class Pipeline(Estimator):
                 if i < last_est:
                     with _stage_span("stage.transform", model, i):
                         current = model.transform(current)
+        if first:
+            set_gauge("sntc_pipeline_first_fit_seconds",
+                      time.perf_counter() - t0)
         return PipelineModel(stages=fitted)
 
 

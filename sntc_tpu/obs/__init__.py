@@ -47,6 +47,8 @@ from sntc_tpu.obs.trace import (
     device_trace,
     disable_tracing,
     enable_tracing,
+    interval,
+    marker,
     module_of,
     span,
     tracer,
@@ -64,6 +66,8 @@ __all__ = [
     "observe",
     "SpanTracer",
     "span",
+    "interval",
+    "marker",
     "module_of",
     "tracer",
     "enable_tracing",

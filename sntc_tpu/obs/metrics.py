@@ -202,9 +202,41 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "(utils/compile_cache.py listener on jax.monitoring).",
     ),
     "sntc_xla_compile_seconds_total": dict(
-        type=COUNTER, labels=(),
+        type=COUNTER, labels=("outcome", "program"),
         help="Seconds jax reported for those builds and loads "
-        "(backend_compile_duration).",
+        "(backend_compile_duration), by outcome and by program (jax's "
+        "fun_name): for outcome=cache_loaded the retrieval and "
+        "deserialisation.  program is bounded by the number of distinct "
+        "jitted programs of a process; past the registry's cap the rest "
+        "fold into overflow=\"true\", so the sum stays right.",
+    ),
+    "sntc_xla_trace_seconds_total": dict(
+        type=COUNTER, labels=("program",),
+        help="Seconds of jaxpr tracing (jaxpr_trace_duration) by program, "
+        "each span's own: an inner jit traced inside an outer one is "
+        "charged to the inner, so the series add up to wall seconds.  "
+        "program is bounded as on sntc_xla_compile_seconds_total.",
+    ),
+    "sntc_xla_lower_seconds_total": dict(
+        type=COUNTER, labels=("program",),
+        help="Seconds of lowering jaxpr to MLIR "
+        "(jaxpr_to_mlir_module_duration; a Pallas kernel's Mosaic "
+        "lowering is in here) by program, each span's own as on "
+        "sntc_xla_trace_seconds_total.",
+    ),
+    "sntc_pipeline_first_fit_seconds": dict(
+        type=GAUGE, labels=(),
+        help="Wall seconds of the process's first Pipeline.fit (the body "
+        "of its pipeline.fit span), set once when it returns: the fit "
+        "that pays every program's trace, lowering and compile or load "
+        "(core/base.py).",
+    ),
+    "sntc_process_device_ready_seconds": dict(
+        type=GAUGE, labels=(),
+        help="Seconds from the process's start (the kernel's, not the "
+        "package's import) to its first mesh, set once: the interpreter, "
+        "the imports and the runtime reaching its devices "
+        "(parallel/mesh.py).",
     ),
     "sntc_predict_bucket_hits_total": dict(
         type=COUNTER, labels=(),

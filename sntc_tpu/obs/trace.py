@@ -15,6 +15,11 @@ A span goes to either, both or neither of:
 With neither on, the call returns one shared no-op — the hot paths
 carry the calls permanently (docs/OBSERVABILITY.md has the costs).
 
+Work that someone else clocked takes one sink each: :func:`interval`
+writes a finished interval onto the ring with its true start and length,
+:func:`marker` a zero-length ``sntc:<name>`` event into the profiler's
+trace (``utils/compile_cache.py``: jax's trace / lower / compile spans).
+
 :meth:`SpanTracer.export_chrome_trace` writes the ring as Chrome
 ``traceEvents`` JSON, loadable in ``chrome://tracing`` and
 https://ui.perfetto.dev — every span a complete ("X") event on its
@@ -148,6 +153,23 @@ class SpanTracer:
 
     def _record(self, name, t0, dur, wall0, tid, attrs, sid, parent) -> None:
         self._live.stack.pop()
+        self._append(name, t0, dur, wall0, tid, attrs, sid, parent)
+
+    def record_interval(self, name: str, wall_start: float,
+                        wall_end: float, **attrs: Any) -> None:
+        """A finished interval someone else clocked (``jax.monitoring``'s
+        time spans, stamped with ``time.time()``), written as it was: the
+        ring records at close, so its true start and length go in whole.
+        The wall stamps are moved onto the ring's monotonic clock by
+        their distance from now; the parent is this thread's live span."""
+        stack = getattr(self._live, "stack", None)
+        self._append(
+            name, self._clock() - (self._wall() - wall_start),
+            wall_end - wall_start, wall_start, threading.get_ident(),
+            attrs or None, next(self._ids), stack[-1] if stack else None,
+        )
+
+    def _append(self, name, t0, dur, wall0, tid, attrs, sid, parent) -> None:
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
@@ -261,6 +283,26 @@ def span(name: str, **attrs: Any):
             return _NULL_SPAN
         return t.span(name, **attrs)
     return _Span(t, name, attrs or None, annotation("sntc:" + name, **attrs))
+
+
+def interval(name: str, wall_start: float, wall_end: float,
+             **attrs: Any) -> None:
+    """A finished interval onto the ring when it is armed
+    (:meth:`SpanTracer.record_interval`); nothing otherwise, and nothing
+    into the profiler's trace, which takes no event after the fact."""
+    t = _tracer
+    if t is not None:
+        t.record_interval(name, wall_start, wall_end, **attrs)
+
+
+def marker(name: str, **attrs: Any) -> None:
+    """A zero-length ``sntc:<name>`` event in the profiler's trace while
+    a session runs, at this instant on the device trace's clock; nothing
+    otherwise, and nothing onto the ring."""
+    annotation = _session_annotation()
+    if annotation is not None:
+        with annotation("sntc:" + name, **attrs):
+            pass
 
 
 def module_of(where) -> str:

@@ -35,11 +35,15 @@ Dev/test: ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives
 
 from __future__ import annotations
 
+import os
+import time
 from typing import Callable, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from sntc_tpu.obs import set_gauge
 
 #: Axis-name registry — every mesh axis the framework may declare, with
 #: its role.  ``scripts/check_mesh_axes.py`` enforces that every
@@ -73,9 +77,34 @@ def device_report() -> dict:
     }
 
 
+_device_ready_noted = False
+
+
+def _note_device_ready() -> None:
+    """Set ``sntc_process_device_ready_seconds`` at the process's first
+    mesh, the one place every entry point passes before any placement,
+    with the devices already in hand: seconds since the kernel started
+    the process (``/proc/self/stat`` field 22, on the boot clock), so
+    the interpreter's start and the imports are in it.  Where the
+    kernel does not say, the gauge stays unset."""
+    global _device_ready_noted
+    if _device_ready_noted:
+        return
+    _device_ready_noted = True
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return
+    set_gauge("sntc_process_device_ready_seconds", age)
+
+
 def default_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over (the first ``n_devices``) available devices, axis "data"."""
     devices = jax.devices()
+    _note_device_ready()
     if n_devices is not None:
         if n_devices > len(devices):
             raise ValueError(
@@ -96,6 +125,7 @@ def make_mesh(
     the ``data`` axis, parameter shards the ``model`` axis.
     """
     devs = list(jax.devices() if devices is None else devices)
+    _note_device_ready()
     if data == -1:
         if len(devs) % model:
             raise ValueError(f"{len(devs)} devices not divisible by model={model}")
@@ -135,6 +165,7 @@ def hybrid_mesh(data: int = -1, model: int = 1) -> Mesh:
             "hybrid mesh stacks whole processes along the data axis"
         )
     devs = jax.devices()
+    _note_device_ready()
     slices = {getattr(d, "slice_index", None) for d in devs}
     if len(slices) > 1 and None not in slices:
         from jax.experimental import mesh_utils

@@ -1,10 +1,17 @@
 """Persistent XLA compilation cache (SURVEY.md §3.5 cold-start).
 
 Spark pays no per-process compile; JAX pays full XLA compilation on the
-first fit of every process (~8-13× the warm fit on the bench configs).
-JAX's persistent compilation cache closes most of that gap: compiled
-executables are written to a directory keyed by (HLO, flags, platform),
-so the SECOND process's "cold" fit only pays trace + cache lookup.
+first fit of every process.  On the chip (one TPU v5e, the benchmark's
+forest cell, 4,058,236 x 78 rows; PERF.md §5 "Where set-up goes", PR 37)
+a process's first fit takes 40.0 s from an empty cache against 8.0 s
+for a later one: 23.4 s of it building 17 executables (17.8 s for
+``_grow_fused`` alone).  JAX's persistent compilation cache closes that
+gap: compiled executables are written to a directory keyed by (HLO,
+flags, platform), so the SECOND process's first fit takes 16.7 s: it
+loads the 17 in 0.5 s and still pays 2.2 s of tracing, 1.9 s of lowering
+and 3.7 s of imports the fit makes on its way.  The key of a program
+that holds a Pallas kernel carries the kernel's source locations, so a
+checkout at another path builds those programs again (PERF.md §7 (x)).
 
 The directory is placed from OUTSIDE the program: where
 ``JAX_COMPILATION_CACHE_DIR`` is set, exactly that directory is used —
@@ -17,20 +24,27 @@ never comes from ``$HOME``, a temp name, a pid or the time.
 
 Opt-out with ``SNTC_NO_COMPILE_CACHE=1``.
 
-:func:`enable_persistent_cache` also installs the process's compile
-listener (cache or no cache): every executable XLA builds or loads from
-this cache — every miss of a jitted function's own in-memory cache —
-counts into ``sntc_xla_compiles_total{outcome}`` /
-``sntc_xla_compile_seconds_total`` and leaves an ``xla.compile`` marker
-span, so a compile inside a measured window shows on the trace's clock.
+:func:`enable_persistent_cache` also installs the process's first-call
+listener (cache or no cache).  jax reports the three phases of every
+miss of a jitted function's own in-memory cache as time spans: tracing,
+lowering, and the backend compile (a build, or a load from this cache).
+The listener counts each span's own seconds into
+``sntc_xla_trace_seconds_total{program}``,
+``sntc_xla_lower_seconds_total{program}`` and
+``sntc_xla_compile_seconds_total{outcome, program}`` and one executable
+into ``sntc_xla_compiles_total{outcome}``; with the span ring armed it
+writes ``xla.trace`` / ``xla.lower`` / ``xla.compile`` intervals, and in
+a profiler's trace an ``xla.compile`` marker, so a compile inside a
+measured window shows on the trace's clock.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+from collections import deque
 
-from sntc_tpu.obs import inc, module_of, span
+from sntc_tpu.obs import inc, interval, marker, module_of
 
 #: the in-checkout default: <repo>/.jax_cache, beside the package
 _DEFAULT_DIR = os.path.join(
@@ -146,36 +160,75 @@ def fsck_compile_cache(
     return report
 
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MODULE = module_of(__name__)
+#: a first call's phases, as jax names them: the span and the counter of each
+_PHASES = {
+    _TRACE_EVENT: ("xla.trace", "sntc_xla_trace_seconds_total"),
+    _LOWER_EVENT: ("xla.lower", "sntc_xla_lower_seconds_total"),
+    _BACKEND_COMPILE_EVENT: (
+        "xla.compile", "sntc_xla_compile_seconds_total"),
+}
+#: closed spans a thread keeps for an encloser still to come: an outer
+#: trace with more inner ones than this in a row counts the oldest twice
+_MAX_CLOSED = 4096
 
 
 class _CompileListener:
-    """``jax.monitoring`` listener pair.  jax reports the backend-compile
-    duration for an executable it built and for one it loaded from the
-    persistent cache alike; a load fires the cache-hit event first, on
-    the compiling thread, which is how the two are told apart."""
+    """``jax.monitoring`` listener pair.  jax reports each phase of a
+    first call (trace, lower, backend compile) as a time span when it
+    ENDS, with ``time.time()`` at its start and end and the program's
+    name; it reports the backend compile for an executable it built and
+    for one it loaded from the persistent cache alike, and a load fires
+    the cache-hit event first, on the compiling thread, which is how the
+    two are told apart.
+
+    Spans nest: an inner ``jit`` is traced inside the outer one's trace,
+    a lowering rule traces (and an eager op on a constant compiles)
+    inside a lowering.  The seconds counted are each span's OWN: an
+    instant of a thread goes to the innermost span that covers it,
+    whatever the phases, so the three counters add up to wall seconds."""
 
     def __init__(self):
-        self._loaded = threading.local()
+        self._thread = threading.local()  # .loaded, .closed
 
     def on_event(self, event: str, **kwargs) -> None:
         if event == _CACHE_HIT_EVENT:
-            self._loaded.flag = True
+            self._thread.loaded = True
 
-    def on_duration(self, event: str, duration_secs: float, **kwargs) -> None:
-        if event != _BACKEND_COMPILE_EVENT:
+    def _own_seconds(self, start: float, end: float) -> float:
+        """``end - start`` less the spans of this thread that closed
+        inside it (each taken whole: what closed inside THEM is already
+        off their own count)."""
+        closed = getattr(self._thread, "closed", None)
+        if closed is None:
+            closed = self._thread.closed = deque(maxlen=_MAX_CLOSED)
+        inner = 0.0
+        while closed and closed[-1][0] >= start:
+            inner += closed.pop()[1]
+        closed.append((start, end - start))
+        return max(end - start - inner, 0.0)
+
+    def on_time_span(self, event: str, start_time: float, end_time: float,
+                     **kwargs) -> None:
+        phase = _PHASES.get(event)
+        if phase is None:
             return
-        loaded = getattr(self._loaded, "flag", False)
-        self._loaded.flag = False
-        outcome = "cache_loaded" if loaded else "compiled"
-        inc("sntc_xla_compiles_total", outcome=outcome)
-        inc("sntc_xla_compile_seconds_total", duration_secs)
-        # a marker, opened and closed at once where the compile ENDED
-        with span("xla.compile", seconds=duration_secs, outcome=outcome,
-                  program=kwargs.get("fun_name", ""), module=_MODULE):
-            pass
+        name, counter = phase
+        labels = {"program": kwargs.get("fun_name", "")}
+        if event == _BACKEND_COMPILE_EVENT:
+            loaded = getattr(self._thread, "loaded", False)
+            self._thread.loaded = False
+            labels["outcome"] = "cache_loaded" if loaded else "compiled"
+            inc("sntc_xla_compiles_total", outcome=labels["outcome"])
+            # in the profiler's trace a marker, where the compile ENDED
+            marker(name, seconds=end_time - start_time, module=_MODULE,
+                   **labels)
+        inc(counter, self._own_seconds(start_time, end_time), **labels)
+        interval(name, start_time, end_time, module=_MODULE, **labels)
 
 
 _listener: _CompileListener | None = None
@@ -189,8 +242,8 @@ def _install_compile_listener() -> None:
 
     _listener = _CompileListener()
     jax.monitoring.register_event_listener(_listener.on_event)
-    jax.monitoring.register_event_duration_secs_listener(
-        _listener.on_duration
+    jax.monitoring.register_event_time_span_listener(
+        _listener.on_time_span
     )
 
 

@@ -552,6 +552,24 @@ def test_one_vs_rest_boosting_opens_a_span_and_counts_each_round(mesh8):
         - before[1] == rounds * K
 
 
+def _sum(name, **match):
+    """One counter summed over the series that carry ``match``."""
+    metric = registry().snapshot().get(name)
+    return sum(
+        row["value"] for row in (metric["series"] if metric else ())
+        if all(row["labels"].get(k) == v for k, v in match.items())
+    )
+
+
+@pytest.fixture
+def fresh_registry():
+    """A registry of this test's own: a worker that has traced 64 programs
+    already would fold the test's into the overflow series."""
+    previous = obs.set_registry(MetricsRegistry())
+    yield registry()
+    obs.set_registry(previous)
+
+
 def test_xla_compiles_counted_on_a_fresh_jit_not_on_a_repeat():
     import jax
     import jax.numpy as jnp
@@ -570,17 +588,17 @@ def test_xla_compiles_counted_on_a_fresh_jit_not_on_a_repeat():
     x.block_until_ready()
     t = enable_tracing(capacity=64)
     n0 = _get("sntc_xla_compiles_total", outcome="compiled")
-    s0 = _get("sntc_xla_compile_seconds_total")
+    s0 = _sum("sntc_xla_compile_seconds_total")
     fresh = jax.jit(body)
     fresh(x).block_until_ready()
     n1 = _get("sntc_xla_compiles_total", outcome="compiled")
     assert n1 == n0 + 1
-    assert _get("sntc_xla_compile_seconds_total") > s0
-    (marker,) = [s for s in t.spans() if s["name"] == "xla.compile"]
-    assert marker["attrs"]["outcome"] == "compiled"
-    assert marker["attrs"]["seconds"] > 0
-    assert marker["attrs"]["module"] == "utils"
-    assert "body" in marker["attrs"]["program"]
+    assert _sum("sntc_xla_compile_seconds_total") > s0
+    (compiled,) = [s for s in t.spans() if s["name"] == "xla.compile"]
+    assert compiled["attrs"]["outcome"] == "compiled"
+    assert compiled["dur_s"] > 0
+    assert compiled["attrs"]["module"] == "utils"
+    assert "body" in compiled["attrs"]["program"]
     fresh(x).block_until_ready()  # the same jitted object: its own cache
     assert _get("sntc_xla_compiles_total", outcome="compiled") == n1
     jax.jit(body)(x).block_until_ready()  # jax knows the function itself
@@ -590,6 +608,259 @@ def test_xla_compiles_counted_on_a_fresh_jit_not_on_a_repeat():
     jax.jit(lambda v: body(v))(x).block_until_ready()
     assert _get("sntc_xla_compiles_total", outcome="compiled") == n1 + 1
     assert _get("sntc_xla_compiles_total", outcome="cache_loaded") == 0
+
+
+_PHASE_COUNTERS = (
+    "sntc_xla_trace_seconds_total", "sntc_xla_lower_seconds_total",
+    "sntc_xla_compile_seconds_total",
+)
+
+
+def _first_call(nested: bool):
+    """A fresh program's first call, then a repeat: ``(phase seconds by
+    program after the first call, the same after the repeat, wall seconds
+    around the first call)``.  ``nested``: ``outer_fn`` calls the jitted
+    ``inner_fn``, which jax traces inside the outer's trace."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from sntc_tpu.utils.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
+
+    @jax.jit
+    def inner_fn(x):
+        for _ in range(40):  # a trace long enough to time
+            x = jnp.sin(x) * 2.0 + 1.0
+        return x
+
+    def outer_fn(x):
+        return (inner_fn(x) if nested else x) + 1.0
+
+    x = jnp.arange(8.0)
+    (x + 1.0).block_until_ready()  # the eager add's own program, up front
+    jitted = jax.jit(outer_fn)
+
+    def totals():
+        return {
+            name: {row["labels"].get("program"): row["value"]
+                   for row in registry().snapshot().get(
+                       name, {"series": ()})["series"]}
+            for name in _PHASE_COUNTERS
+        }
+
+    def seconds():
+        """By counter and program, what was added since ``before``."""
+        return {name: {program: v - before[name].get(program, 0.0)
+                       for program, v in now.items()
+                       if v > before[name].get(program, 0.0)}
+                for name, now in totals().items()}
+
+    before = totals()
+    t0 = time.time()
+    jitted(x).block_until_ready()
+    wall = time.time() - t0
+    first = seconds()
+    jitted(x).block_until_ready()
+    return first, seconds(), wall
+
+
+@pytest.mark.parametrize("case", [
+    "three_phases_under_the_program", "a_repeat_adds_nothing",
+    "compile_seconds_carry_the_outcome",
+])
+def test_first_call_of_a_fresh_jit_counts_its_phases(fresh_registry, case):
+    first, repeat, wall = _first_call(nested=False)
+    trace, lower, compiled = (first[name] for name in _PHASE_COUNTERS)
+    if case == "three_phases_under_the_program":
+        assert trace["outer_fn"] > 0
+        assert lower["jit(outer_fn)"] > 0
+        assert compiled["jit(outer_fn)"] > 0
+        assert sum(sum(p.values()) for p in first.values()) <= wall
+    elif case == "a_repeat_adds_nothing":
+        assert repeat == first
+    else:
+        assert _sum("sntc_xla_compile_seconds_total", outcome="compiled",
+                    program="jit(outer_fn)") == compiled["jit(outer_fn)"]
+        assert set(compiled) == {"jit(outer_fn)"}
+        assert _sum("sntc_xla_compile_seconds_total",
+                    outcome="cache_loaded") == 0
+        assert _sum("sntc_xla_compile_seconds_total") == \
+            _sum("sntc_xla_compile_seconds_total", outcome="compiled")
+
+
+@pytest.mark.parametrize("case", [
+    "both_programs_have_a_series", "the_union_not_the_sum",
+])
+def test_nested_trace_is_counted_once(fresh_registry, case):
+    first, _, wall = _first_call(nested=True)
+    trace = first["sntc_xla_trace_seconds_total"]
+    if case == "both_programs_have_a_series":
+        assert trace["outer_fn"] > 0 and trace["inner_fn"] > 0
+    else:
+        assert sum(trace.values()) <= wall
+        assert sum(sum(p.values()) for p in first.values()) <= wall
+
+
+def test_own_seconds_charge_each_instant_to_the_innermost_span():
+    """The listener's bookkeeping on hand-made spans, as jax reports them:
+    at their close, the inner before the outer, phases mixed."""
+    from sntc_tpu.utils.compile_cache import _CompileListener
+
+    own = _CompileListener()._own_seconds
+    assert own(1.0, 2.0) == 1.0            # an earlier, top-level span
+    assert own(11.0, 12.0) == 1.0          # inner a
+    assert own(12.5, 13.0) == 0.5          # a leaf inside inner b
+    assert own(12.0, 14.0) == 1.5          # inner b: its leaf taken off
+    assert own(10.0, 20.0) == 7.0          # outer: a and b off, whole
+    assert own(21.0, 22.0) == 1.0          # the next top-level span
+    assert own(0.0, 30.0) == 30.0 - 12.0   # and one around all of them
+
+
+@pytest.mark.parametrize("sink", ["ring", "profiler"])
+def test_first_call_spans_in_the_ring_and_the_marker_in_the_profiler(
+    tmp_path, fresh_registry, sink,
+):
+    import jax
+    import jax.numpy as jnp
+
+    from sntc_tpu.utils.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
+    x = jnp.arange(8.0)
+    x.block_until_ready()
+
+    def ring_body(v):
+        return jnp.cos(v) * 3.0
+
+    if sink == "ring":
+        t = enable_tracing(capacity=256)
+        with obs_span("stage.fit", stage="Fresh"):
+            jax.jit(ring_body)(x).block_until_ready()
+        spans = t.spans()
+        (enclosing,) = [s for s in spans if s["name"] == "stage.fit"]
+        lo, hi = enclosing["t0"], enclosing["t0"] + enclosing["dur_s"]
+        mine = {s["name"]: s for s in spans
+                if "ring_body" in s["attrs"].get("program", "")}
+        assert set(mine) == {"xla.trace", "xla.lower", "xla.compile"}
+        slack = 1e-3  # jax stamps time.time(), the ring perf_counter
+        for s in mine.values():
+            assert s["dur_s"] > 0
+            assert lo - slack <= s["t0"]
+            assert s["t0"] + s["dur_s"] <= hi + slack
+            assert s["parent"] == enclosing["id"]
+            assert s["attrs"]["module"] == "utils"
+        assert mine["xla.compile"]["attrs"]["outcome"] == "compiled"
+        assert "outcome" not in mine["xla.trace"]["attrs"]
+        order = sorted(mine.values(), key=lambda s: s["t0"])
+        assert [s["name"] for s in order] == \
+            ["xla.trace", "xla.lower", "xla.compile"]
+        # one after another: no phase of a program overlaps the next
+        for a, b in zip(order, order[1:]):
+            assert a["t0"] + a["dur_s"] <= b["t0"] + slack
+        return
+    with obs.device_trace(str(tmp_path)):
+        jax.jit(lambda v: ring_body(v) + 1.0)(x).block_until_ready()
+    events = _profiler_events(str(tmp_path))
+    (marker,) = events["sntc:xla.compile"]
+    # what benchmark/program_spans.py reads: a zero-length event with these
+    assert set(marker[2]) == {"seconds", "outcome", "program", "module"}
+    assert marker[2]["outcome"] == "compiled"
+    assert marker[2]["module"] == "utils"
+    assert marker[2]["seconds"] > 0
+    assert "lambda" in marker[2]["program"]
+    assert marker[1] - marker[0] < 1e6  # a marker: under a millisecond
+    assert "sntc:xla.trace" not in events and "sntc:xla.lower" not in events
+
+
+class _Identity(Transformer):
+    def transform(self, frame):
+        return frame
+
+
+@pytest.mark.parametrize("case", ["set_by_the_first_fit", "not_by_a_transform",
+                                  "unchanged_by_the_second"])
+def test_first_fit_seconds_gauge(fresh_registry, monkeypatch, case):
+    from sntc_tpu.core import base
+
+    monkeypatch.setattr(base, "_first_fit_claimed", False)
+    frame = Frame({"x": np.arange(4.0)})
+    pipe = Pipeline(stages=[_Identity()])
+    name = "sntc_pipeline_first_fit_seconds"
+    if case == "not_by_a_transform":
+        # _RUNS is shared with transform: run=1 here is no fit
+        base.PipelineModel(stages=[_Identity()]).transform(frame)
+        assert registry().get(name) is None
+        return
+    pipe.fit(frame)
+    first = registry().get(name)
+    assert first is not None and first > 0
+    if case == "unchanged_by_the_second":
+        pipe.fit(frame)
+        Pipeline(stages=[_Identity(), _Identity()]).fit(frame)
+        assert registry().get(name) == first
+
+
+@pytest.mark.parametrize("case", ["positive", "set_once"])
+def test_device_ready_seconds_gauge(fresh_registry, monkeypatch, case):
+    from sntc_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(mesh_mod, "_device_ready_noted", False)
+    name = "sntc_process_device_ready_seconds"
+    mesh_mod.default_mesh()
+    ready = registry().get(name)
+    # this process is older than its import of jax and younger than a day
+    assert ready is not None and 0 < ready < 86_400
+    if case == "set_once":
+        mesh_mod.default_mesh(1)
+        mesh_mod.make_mesh()
+        assert registry().get(name) == ready
+
+
+def test_the_manifests_set_up_readers_load_and_read_this_registry(
+    fresh_registry, monkeypatch,
+):
+    """Every per-layer metric of ``BENCHMARK.json`` that moves ``setup_s``
+    has a reader under ``benchmark/layer_metrics/`` that loads, gives no
+    number on an empty registry and reads what the program counts; the
+    manifest passes the benchmark's own check."""
+    bench = os.path.join(REPO, "benchmark")
+    monkeypatch.syspath_prepend(bench)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    names = [m["name"] for m in manifest["per_layer"]
+             if m["moves"] == "setup_s"]
+    assert len(names) == 8
+
+    def load(kind, name):
+        spec = importlib.util.spec_from_file_location(
+            "bench_" + name.replace(".", "_"),
+            os.path.join(bench, kind, name + ".py"),
+        )
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    assert load("", "check_manifest").check(manifest) == []
+    readers = {name: load("layer_metrics", name).read for name in names}
+    assert {name: read({}) for name, read in readers.items()} == \
+        dict.fromkeys(names)
+    obs.set_gauge("sntc_process_device_ready_seconds", 11.0)
+    obs.set_gauge("sntc_pipeline_first_fit_seconds", 19.5)
+    obs.inc("sntc_xla_trace_seconds_total", 1.5, program="f")
+    obs.inc("sntc_xla_trace_seconds_total", 0.5, program="g")
+    obs.inc("sntc_xla_lower_seconds_total", 3.0, program="jit(f)")
+    obs.inc("sntc_xla_compile_seconds_total", 0.25, outcome="cache_loaded",
+            program="jit(f)")
+    obs.inc("sntc_xla_compiles_total", outcome="cache_loaded")
+    assert {name: read({}) for name, read in readers.items()} == {
+        "device_ready_s": 11.0, "first_fit_s": 19.5,
+        "first_call_s.trace": 2.0, "first_call_s.lower": 3.0,
+        "first_call_s.compile": 0.0, "first_call_s.load": 0.25,
+        "first_call_programs": 1.0, "first_call_compiled": 0.0,
+    }
 
 
 # ---------------------------------------------------------------------------
