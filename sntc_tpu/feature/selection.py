@@ -21,6 +21,7 @@ from typing import List
 
 import numpy as np
 
+from sntc_tpu.feature.stack import pool_workers, stack_rows
 from sntc_tpu.obs import inc, module_of, span
 
 _MODULE = module_of(__name__)
@@ -33,7 +34,8 @@ def take_columns(X, idx) -> np.ndarray:
     as its ``(N, F)`` transpose): its columns are whole contiguous rows of
     the base, so taking them is ``len(idx)`` memcpys and the result stays
     feature-major, where a fancy index into row-major misses a cache line
-    on every element.  A row-major matrix takes ``np.take`` along axis 1;
+    on every element; at fit scale a pool of workers makes them
+    (``feature/stack.py``).  A row-major matrix takes ``np.take`` along axis 1;
     anything else (a strided slice, a device-resident column) the plain
     fancy index.  Same values, dtype and shape in every branch — only the
     strides of the result follow the input's.  The branch taken is the
@@ -49,8 +51,14 @@ def take_columns(X, idx) -> np.ndarray:
         layout = "base_rows"
     else:
         layout = "generic"
+    workers = pool_workers(
+        len(idx), len(idx) * X.shape[0] * X.itemsize
+    ) if layout == "base_rows" else 1
     with span("select.take", layout=layout, module=_MODULE):
-        if layout == "base_rows":
+        if workers > 1:
+            out = stack_rows([X.T[i] for i in idx], X.dtype,
+                             workers=workers)[0].T
+        elif layout == "base_rows":
             out = np.take(X.T, idx, axis=0).T
         elif layout == "columns":
             out = np.take(X, idx, axis=1)
@@ -58,6 +66,8 @@ def take_columns(X, idx) -> np.ndarray:
             out = np.ascontiguousarray(X[:, idx])
     inc("sntc_feature_copy_bytes_total", out.nbytes,
         site="select.take", layout=layout)
+    if workers > 1:
+        inc("sntc_feature_pooled_copies_total", site="select.take")
     return out
 
 

@@ -17,6 +17,7 @@ import numpy as np
 from sntc_tpu.core.base import Transformer
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.core.params import Param, validators
+from sntc_tpu.feature.stack import pool_workers, stack_rows
 from sntc_tpu.obs import inc, module_of, span
 
 _MODULE = module_of(__name__)
@@ -76,16 +77,28 @@ class VectorAssembler(Transformer):
             _ASSEMBLE_CACHE.move_to_end(key)
             X, invalid = hit[1], hit[2]
         else:
-            with span("assemble.stack", columns=len(cols), module=_MODULE):
-                if cols and all(c.ndim == 1 for c in cols):
-                    # all-1-D-columns fast path: ONE C-level stack+cast
-                    # (4× the per-column assign loop — this runs per
-                    # micro-batch on the serving hot path [B:11]); the
-                    # transposed view multiplies/converts downstream at
-                    # full speed, so no contiguity copy.  (N, 1) 2-D
-                    # columns must take the assign loop: np.array would
-                    # stack them to 3-D
-                    X = np.array(cols, dtype=np.float32).T
+            one_d = bool(cols) and all(c.ndim == 1 for c in cols)
+            # a fit-scale stack is shared out among a pool, a serving
+            # micro-batch [B:11] is one call: feature/stack.py
+            workers = pool_workers(
+                len(cols), frame.num_rows * len(cols) * 4
+            ) if one_d else 1
+            unchecked = mode != "keep"
+            with span("assemble.stack", columns=len(cols), workers=workers,
+                      module=_MODULE):
+                if one_d:
+                    # all-1-D-columns fast path: a C-level stack+cast into
+                    # the feature-major base (4× the per-column assign
+                    # loop), each column checked for NaN/Inf by the copy
+                    # that brought it in; the transposed view
+                    # multiplies/converts downstream at full speed, so no
+                    # contiguity copy.  (N, 1) 2-D columns must take the
+                    # assign loop: np.array would stack them to 3-D
+                    base, finite = stack_rows(
+                        cols, np.float32, finite=unchecked, workers=workers
+                    )
+                    unchecked = unchecked and not finite.all()
+                    X = base.T
                 else:
                     # single allocation, cast-on-assign — no per-column
                     # intermediate copies
@@ -99,9 +112,11 @@ class VectorAssembler(Transformer):
                         off += w
             inc("sntc_feature_copy_bytes_total", X.nbytes,
                 site="assemble.stack")
+            if workers > 1:
+                inc("sntc_feature_pooled_copies_total", site="assemble.stack")
 
             invalid = None
-            if mode != "keep":
+            if unchecked:  # some column holds a NaN/Inf: which rows
                 with span("assemble.finite_check", module=_MODULE):
                     bad = ~np.isfinite(X).all(axis=1)
                 if bad.any():

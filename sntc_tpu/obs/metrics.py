@@ -305,6 +305,13 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "layout=base_rows | columns | generic is the copy it chose from "
         "the input's strides).",
     ),
+    "sntc_feature_pooled_copies_total": dict(
+        type=COUNTER, labels=("site",),
+        help="Whole-column host copies a pool of workers made "
+        "(feature/stack.py:stack_rows, at and over POOL_MIN_BYTES of "
+        "result): site=assemble.stack | select.take; a serving "
+        "micro-batch adds 0.",
+    ),
     # -- boosting rounds on the fit path (models/tree/gbt*.py) ---------------
     "sntc_boost_rounds_total": dict(
         type=COUNTER, labels=("estimator",),

@@ -80,14 +80,180 @@ def test_vector_assembler_n_by_1_columns_use_assign_path():
     np.testing.assert_array_equal(out["features"], [[1, 4], [2, 5], [3, 6]])
 
 
-def test_vector_assembler_handle_invalid():
-    f = Frame({"a": np.array([1.0, np.nan, 3.0])})
-    with pytest.raises(ValueError, match="NaN/Inf"):
-        VectorAssembler(inputCols=["a"]).transform(f)
-    out = VectorAssembler(inputCols=["a"], handleInvalid="skip").transform(f)
-    assert out.num_rows == 2
-    out = VectorAssembler(inputCols=["a"], handleInvalid="keep").transform(f)
-    assert out.num_rows == 3 and np.isnan(out["features"][1, 0])
+def _pooled(site):
+    from sntc_tpu.obs import registry
+
+    return registry().get("sntc_feature_pooled_copies_total", site=site) or 0
+
+
+def _copy_bytes(**labels):
+    from sntc_tpu.obs import registry
+
+    return registry().get("sntc_feature_copy_bytes_total", **labels) or 0
+
+
+@pytest.fixture
+def eight_cores(monkeypatch):
+    """What ``pool_workers`` reads of the machine, held still."""
+    from sntc_tpu.feature import stack
+
+    monkeypatch.setattr(stack.os, "sched_getaffinity",
+                        lambda _pid: set(range(8)))
+
+
+def _frame_with_bad_rows(scale):
+    """``(frame, columns, rows with a NaN/Inf)``: the three-row frame, or
+    eight columns whose stack is just over the pool's threshold."""
+    from sntc_tpu.feature.stack import POOL_MIN_BYTES
+
+    if scale == "three_rows":
+        return Frame({"a": np.array([1.0, np.nan, 3.0])}), ["a"], [1]
+    n = POOL_MIN_BYTES // (8 * 4) + 1
+    cols = {f"c{j}": np.full(n, j, np.float32) for j in range(8)}
+    cols["c0"][0] = np.nan
+    cols["c3"][n // 2] = np.inf
+    cols["c7"][n - 1] = -np.inf
+    cols["c7"][0] = np.nan  # a row bad in two columns counts once
+    return Frame(cols), list(cols), [0, n // 2, n - 1]
+
+
+@pytest.mark.parametrize("scale", ["three_rows", "fit_scale"])
+def test_vector_assembler_handle_invalid(scale, eight_cores):
+    f, names, bad = _frame_with_bad_rows(scale)
+    fit_scale = scale == "fit_scale"
+    pooled0 = _pooled("assemble.stack")
+    bytes0 = _copy_bytes(site="assemble.stack")
+    with pytest.raises(ValueError, match=f"{len(bad)} rows contain NaN/Inf"):
+        VectorAssembler(inputCols=names).transform(f)
+    out = VectorAssembler(inputCols=names, handleInvalid="skip").transform(f)
+    assert out.num_rows == f.num_rows - len(bad)
+    assert np.isfinite(out["features"]).all()
+    keep = VectorAssembler(inputCols=names, handleInvalid="keep")
+    out = keep.transform(f)
+    X = out["features"]
+    assert out.num_rows == f.num_rows and X.dtype == np.float32
+    assert np.flatnonzero(~np.isfinite(X).all(axis=1)).tolist() == bad
+    assert np.isnan(X[bad[0], 0])
+    # the (N, F) transpose of a C-contiguous [F, N] base, pooled or not
+    assert X.flags.f_contiguous and X.base.flags.c_contiguous
+    # three stacks: each counted whole, each through the pool at fit scale
+    assert _pooled("assemble.stack") - pooled0 == (3 if fit_scale else 0)
+    assert (_copy_bytes(site="assemble.stack") - bytes0
+            == 3 * f.num_rows * len(names) * 4)
+    # the same columns again: the memo's matrix, no fourth stack
+    again = keep.transform(f.with_column("extra", np.zeros(f.num_rows)))
+    assert (again["features"] is X) == fit_scale
+    assert _pooled("assemble.stack") - pooled0 == (3 if fit_scale else 0)
+
+
+def test_a_serving_batch_goes_through_no_pool(eight_cores):
+    """A 4,096-row micro-batch stacks and takes by the single calls."""
+    from sntc_tpu.data.schema import CICIDS2017_FEATURES
+    from sntc_tpu.feature.selection import take_columns
+    from sntc_tpu.obs import disable_tracing, enable_tracing
+
+    rng = np.random.default_rng(8)
+    names = list(CICIDS2017_FEATURES)
+    f = Frame({c: rng.random(4096, dtype=np.float32) for c in names})
+    before = _pooled("assemble.stack"), _pooled("select.take")
+    t = enable_tracing(capacity=16)
+    try:
+        X = VectorAssembler(inputCols=names).transform(f)["features"]
+        taken = take_columns(X, list(range(0, 78, 2)))
+        spans = {s["name"]: s["attrs"] for s in t.spans()}
+    finally:
+        disable_tracing()
+    assert (_pooled("assemble.stack"), _pooled("select.take")) == before
+    assert spans["assemble.stack"] == {
+        "columns": 78, "workers": 1, "module": "feature"}
+    assert "assemble.finite_check" not in spans  # every column was clean
+    assert spans["select.take"]["layout"] == "base_rows"
+    np.testing.assert_array_equal(X, np.array([f[c] for c in names]).T)
+    np.testing.assert_array_equal(taken, X[:, ::2])
+
+
+# ---------------- stack_rows: the feature stages' pooled copy ----------------
+
+_STACK_MIN_BYTES = 4096  # the threshold the helper is handed in this test
+
+
+@pytest.mark.parametrize("planted", [None, np.nan, np.inf, -np.inf],
+                         ids=["clean", "nan", "pos_inf", "neg_inf"])
+@pytest.mark.parametrize("workers", [None, 1, 3],
+                         ids=["planned", "one_worker", "three_workers"])
+@pytest.mark.parametrize("size", ["under", "over"])
+@pytest.mark.parametrize(
+    "src", [np.float32, np.float64, np.int32, np.int64, np.bool_],
+    ids=["float32", "float64", "int32", "int64", "bool"],
+)
+def test_stack_rows_is_np_array_with_a_finite_flag_a_row(
+    src, size, workers, planted, eight_cores
+):
+    from sntc_tpu.feature.stack import pool_workers, stack_rows
+
+    n_rows = 7
+    width = _STACK_MIN_BYTES // (n_rows * 4) + (1 if size == "over" else 0)
+    nbytes = n_rows * width * 4
+    assert (nbytes >= _STACK_MIN_BYTES) == (size == "over")
+    rng = np.random.default_rng(9)
+    rows = [(rng.integers(-1000, 1000, width) * 1.5).astype(src)
+            for _ in range(n_rows)]
+    bad = []
+    if planted is not None:
+        # the first, a middle and the last row, at its first, a middle
+        # and its last value
+        for j, at in ((0, 0), (n_rows // 2, width // 2),
+                      (n_rows - 1, width - 1)):
+            rows[j] = rows[j].astype(np.float64)
+            rows[j][at] = planted
+            bad.append(j)
+    planned = pool_workers(n_rows, nbytes, min_bytes=_STACK_MIN_BYTES)
+    assert planned == (7 if size == "over" else 1)  # min(cores, rows, cap)
+    want = np.array(rows, dtype=np.float32)
+    got, finite = stack_rows(rows, np.float32, finite=True,
+                             workers=planned if workers is None else workers)
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == want.dtype and got.strides == want.strides
+    assert got.flags.c_contiguous and got.flags.owndata
+    assert got.flags.writeable and got.flags.aligned
+    assert finite.dtype == bool
+    assert np.flatnonzero(~finite).tolist() == bad
+    unasked, none = stack_rows(rows, np.float32, workers=3)
+    np.testing.assert_array_equal(unasked, want)
+    assert none is None
+
+
+def test_stack_rows_with_more_workers_than_cores_loses_no_row():
+    """Every row is written by exactly one worker, whatever the
+    interleaving: 32 workers, 203 rows, a thread switch every 1 us."""
+    import sys
+
+    from sntc_tpu.feature.stack import stack_rows
+
+    rng = np.random.default_rng(10)
+    rows = [rng.normal(size=257) for _ in range(203)]
+    bad = [0, 17, 101, 202]
+    for j in bad:
+        rows[j][j] = np.inf
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(20):
+            got, finite = stack_rows(rows, np.float32, finite=True,
+                                     workers=32)
+            np.testing.assert_array_equal(got, np.array(rows, np.float32))
+            assert np.flatnonzero(~finite).tolist() == bad
+    finally:
+        sys.setswitchinterval(interval)
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_stack_rows_raises_a_worker_s_error(workers):
+    from sntc_tpu.feature.stack import stack_rows
+
+    rows = [np.zeros(8), np.zeros(8), np.zeros(5), np.zeros(8)]
+    with pytest.raises(ValueError):
+        stack_rows(rows, np.float32, workers=workers)
 
 
 # ---------------- StandardScaler ----------------
@@ -168,15 +334,16 @@ def test_chisq_selector_fpr_mode(mesh8):
 
 # ---------------- take_columns: the package's one column-take ----------------
 
-def _copy_bytes(**labels):
-    from sntc_tpu.obs import registry
-
-    return registry().get("sntc_feature_copy_bytes_total", **labels) or 0
-
-
 def _matrix_in(layout):
     """An ``(N, 6)`` float32 matrix laid out as named, and the branch
     ``take_columns`` has to choose for it from what it can observe."""
+    if layout == "feature_major_fit_scale":
+        # three of its columns are just over the pool's threshold
+        from sntc_tpu.feature.stack import POOL_MIN_BYTES
+
+        n = POOL_MIN_BYTES // (3 * 4) + 1
+        return (np.arange(6 * n, dtype=np.float32).reshape(6, n).T,
+                "base_rows")
     base = np.arange(6 * 50, dtype=np.float32).reshape(6, 50)  # [F, N]
     row_major = np.ascontiguousarray(base.T)
     if layout == "jax_array":
@@ -202,9 +369,10 @@ def _matrix_in(layout):
 @pytest.mark.parametrize("layout", [
     "feature_major", "row_major", "strided_rows",
     "feature_major_strided_rows", "n_by_1", "zero_rows", "jax_array",
+    "feature_major_fit_scale",
 ])
 def test_take_columns_equals_the_fancy_index_in_every_layout(
-    layout, selection
+    layout, selection, eight_cores
 ):
     from sntc_tpu.feature.selection import take_columns
     from sntc_tpu.obs import disable_tracing, enable_tracing
@@ -213,6 +381,7 @@ def test_take_columns_equals_the_fancy_index_in_every_layout(
     idx = [0] * len(selection) if X.shape[1] == 1 else selection
     want = np.ascontiguousarray(np.asarray(X)[:, np.asarray(idx, np.intp)])
     before = _copy_bytes(site="select.take", layout=branch)
+    pooled = _pooled("select.take")
     t = enable_tracing(capacity=16)
     try:
         got = take_columns(X, idx)
@@ -225,6 +394,9 @@ def test_take_columns_equals_the_fancy_index_in_every_layout(
     assert taken["attrs"] == {"layout": branch, "module": "feature"}
     assert (_copy_bytes(site="select.take", layout=branch) - before
             == want.nbytes)
+    # a pool made the copy only where it is fit-scale
+    assert _pooled("select.take") - pooled == int(
+        layout == "feature_major_fit_scale" and len(idx) > 1)
     if branch == "base_rows" and len(idx) > 1:
         # the result stays feature-major: whole rows of a new base
         assert got.flags.f_contiguous and not got.flags.c_contiguous
@@ -413,12 +585,14 @@ def test_one_fit_counts_the_copies_it_made(four_stage_fits, layout, branch):
 def test_observability_doc_lists_the_take():
     import os
 
-    doc = open(os.path.join(
+    with open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "docs", "OBSERVABILITY.md",
-    )).read()
+    )) as f:
+        doc = f.read()
     assert "`sntc_feature_copy_bytes_total` | counter | layout, site" in doc
     assert "| `select.take` | `feature/selection.py:take_columns`" in doc
+    assert "`sntc_feature_pooled_copies_total` | counter | site" in doc
 
 
 def test_chisq_selector_fdr_and_fwe_modes(mesh8):
