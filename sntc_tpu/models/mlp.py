@@ -7,6 +7,23 @@ softmax output with cross-entropy, full-batch LBFGS by default (``solver=
 "l-bfgs"``, ``maxIter=100``) or gradient descent (``solver="gd"``), seeded
 weight init, optional ``initialWeights`` vector.
 
+``solver="gd"`` is Spark's ``FeedForwardTrainer.SGDOptimizer``:
+``GradientDescent`` at ``miniBatchFraction`` 1.0 under ``ANNUpdater``, so a
+step is ``w -= stepSize * grad`` over the whole batch (no ``1/sqrt(t)``
+decay), and the fit stops after ``maxIter`` steps or once
+``||w_t - w_{t-1}|| < tol * max(||w_t||, 1)``, tested from the second step
+on.  One fit makes one loss-and-gradient evaluation a step and one loss
+evaluation (forward only) at the final weights, so ``objectiveHistory``
+holds the loss at every iterate, ``n_iters + 1`` values, as under L-BFGS.
+The gradient is the mean over the rows (Spark averages the means of its
+``blockSize``-row blocks; the two agree when the blocks are full).
+
+Precision on a TPU (``computeDtype``): ``float32`` multiplies at float32
+precision (``Precision.HIGHEST``: several bfloat16 passes on the MXU),
+forward and backward, in fit and in predict; ``bfloat16`` rounds the
+operands to bfloat16 and multiplies in one pass, accumulating in float32.
+Spark computes in double.
+
 TPU design: where Spark stacks ``blockSize`` rows per partition to call JNI
 BLAS gemms (§3.3 ⟦JVM→NATIVE⟧), here the whole dataset is device-resident
 and the forward/backward chain is XLA ``dot_general`` on the MXU — the
@@ -33,9 +50,12 @@ from sntc_tpu.models.base import (
     ClassificationModel,
     ClassifierEstimator,
 )
+from sntc_tpu.obs import inc, module_of, span
 from sntc_tpu.ops.lbfgs import minimize_lbfgs
 from sntc_tpu.parallel.collectives import shard_batch, shard_weights
 from sntc_tpu.parallel.context import get_default_mesh
+
+_MODULE = module_of(__name__)
 
 
 def _layer_sizes(layers: Tuple[int, ...]) -> List[Tuple[int, int]]:
@@ -66,9 +86,16 @@ def _forward(
 ):
     """Margins (pre-softmax) of the final layer.
 
-    ``compute_dtype=bfloat16`` feeds the MXU its native input width
-    (double the f32 matmul throughput on v5e) while accumulating in f32
-    (``preferred_element_type``); activations/params stay f32 elsewhere."""
+    ``compute_dtype=float32`` multiplies at float32 precision (HIGHEST:
+    without it a TPU rounds float32 operands to one bfloat16 pass);
+    ``bfloat16`` feeds the MXU its native input width in one pass.  Both
+    accumulate in f32 (``preferred_element_type``); activations and
+    params stay f32 elsewhere.  Autodiff carries ``precision`` to the
+    backward products."""
+    precision = (
+        jax.lax.Precision.HIGHEST
+        if jnp.dtype(compute_dtype) == jnp.float32 else None
+    )
     h = X
     wbs = _unpack(theta, layers)
     for i, (W, b) in enumerate(wbs):
@@ -76,6 +103,7 @@ def _forward(
             jax.lax.dot(
                 h.astype(compute_dtype),
                 W.astype(compute_dtype),
+                precision=precision,
                 preferred_element_type=jnp.float32,
             )
             + b[None, :]
@@ -98,16 +126,15 @@ def _mlp_optimize(
 ):
     w_sum = jnp.sum(ws)
 
-    def value_and_grad(theta):
-        def loss_fn(theta):
-            margins = _forward(theta, xs, layers, compute_dtype)
-            logp = jax.nn.log_softmax(margins, axis=1)
-            picked = jnp.take_along_axis(
-                logp, ys[:, None].astype(jnp.int32), axis=1
-            )[:, 0]
-            return -jnp.sum(ws * picked) / w_sum
+    def loss_fn(theta):
+        margins = _forward(theta, xs, layers, compute_dtype)
+        logp = jax.nn.log_softmax(margins, axis=1)
+        picked = jnp.take_along_axis(
+            logp, ys[:, None].astype(jnp.int32), axis=1
+        )[:, 0]
+        return -jnp.sum(ws * picked) / w_sum
 
-        return jax.value_and_grad(loss_fn)(theta)
+    value_and_grad = jax.value_and_grad(loss_fn)
 
     if solver == "l-bfgs":
         return minimize_lbfgs(
@@ -116,28 +143,34 @@ def _mlp_optimize(
             return_state=True, iter_limit=iter_limit,
         )
 
-    # solver == "gd": full-batch gradient descent with constant step
-    def gd_step(i, carry):
-        theta, hist = carry
+    # solver == "gd": Spark's GradientDescent (miniBatchFraction 1.0) under
+    # ANNUpdater: constant full-batch steps, stopped by its solution-change
+    # test, which first compares the second step's weights with the first's
+    def gd_step(carry):
+        i, theta, hist, _ = carry
         f, g = value_and_grad(theta)
-        hist = hist.at[i].set(f)
-        return theta - step_size * g, hist
+        new = theta - step_size * g
+        moved = jnp.linalg.norm(new - theta)
+        done = (i >= 1) & (moved < tol * jnp.maximum(jnp.linalg.norm(new), 1.0))
+        return i + 1, new, hist.at[i].set(f), done
+
+    def running(carry):
+        i, _, _, done = carry
+        return (i < max_iter) & ~done
 
     hist0 = jnp.zeros((max_iter + 1,), theta0.dtype)
-    theta, hist = jax.lax.fori_loop(
-        0, max_iter, gd_step, (theta0, hist0)
+    n_iters, theta, hist, converged = jax.lax.while_loop(
+        running, gd_step,
+        (jnp.asarray(0, jnp.int32), theta0, hist0, jnp.asarray(False)),
     )
-    f_final, _ = value_and_grad(theta)
-    hist = hist.at[max_iter].set(f_final)
+    f_final = loss_fn(theta)  # the summary's loss at the final iterate
+    hist = jnp.where(jnp.arange(max_iter + 1) >= n_iters, f_final, hist)
     from sntc_tpu.ops.lbfgs import LbfgsResult
 
     return (
         LbfgsResult(
-            x=theta,
-            loss=f_final,
-            n_iters=jnp.asarray(max_iter, jnp.int32),
-            history=hist,
-            converged=jnp.asarray(True),
+            x=theta, loss=f_final, n_iters=n_iters, history=hist,
+            converged=converged,
         ),
         None,  # gd has no resumable state (mid-fit checkpointing is l-bfgs)
     )
@@ -161,9 +194,10 @@ class _MlpParams:
         validator=validators.gt(0),
     )
     computeDtype = Param(
-        "matmul input dtype: float32 | bfloat16 (bf16 feeds the MXU its "
-        "native width — ~2x f32 throughput on v5e — accumulating in f32; "
-        "beyond Spark parity, which is f64 on JVM)",
+        "matmul precision of the head: float32 (float32 products, fit and "
+        "predict: Precision.HIGHEST, several bfloat16 passes on a TPU) | "
+        "bfloat16 (operands rounded to bfloat16, one MXU pass, f32 "
+        "accumulation; fit only); Spark computes in f64",
         default="float32",
         validator=validators.one_of("float32", "bfloat16"),
     )
@@ -209,6 +243,8 @@ class MultilayerPerceptronClassifier(_MlpParams, CheckpointParams, ClassifierEst
                 parts.append(np.zeros(d_out, np.float32))
             theta0 = np.concatenate(parts)
 
+        solver = self.getSolver()
+
         def opt_call(init_state, resume, iter_limit):
             init_dev = (
                 None if init_state is None
@@ -220,7 +256,7 @@ class MultilayerPerceptronClassifier(_MlpParams, CheckpointParams, ClassifierEst
                 layers=layers,
                 max_iter=self.getMaxIter(),
                 tol=self.getTol(),
-                solver=self.getSolver(),
+                solver=solver,
                 step_size=self.getStepSize(),
                 resume=resume,
                 compute_dtype=jnp.dtype(self.getComputeDtype()),
@@ -229,18 +265,23 @@ class MultilayerPerceptronClassifier(_MlpParams, CheckpointParams, ClassifierEst
         fingerprint = {
             "algo": "mlp", "layers": list(layers), "seed": self.getSeed(),
             "maxIter": self.getMaxIter(), "tol": self.getTol(),
-            "solver": self.getSolver(), "n_rows": int(X.shape[0]),
+            "solver": solver, "n_rows": int(X.shape[0]),
             "computeDtype": self.getComputeDtype(),
         }
         interval = (
             self.getCheckpointInterval()
-            if self.getSolver() == "l-bfgs"
+            if solver == "l-bfgs"
             else -1  # gd state is just theta; not checkpointed
         )
-        res = run_segmented(
-            opt_call, self.getMaxIter(), interval,
-            self.getCheckpointDir(), fingerprint,
-        )
+        with span("mlp.optimize", solver=solver, rows=int(X.shape[0]),
+                  iterations=self.getMaxIter(), module=_MODULE):
+            res = run_segmented(
+                opt_call, self.getMaxIter(), interval,
+                self.getCheckpointDir(), fingerprint,
+            )
+            n_iters = int(res.n_iters)  # waits for the device
+        if solver == "gd":  # one loss-and-gradient evaluation a step
+            inc("sntc_mlp_grad_evals_total", n_iters)
 
         model = MultilayerPerceptronClassificationModel(
             weights=np.asarray(res.x), layers=list(layers)
@@ -250,7 +291,6 @@ class MultilayerPerceptronClassifier(_MlpParams, CheckpointParams, ClassifierEst
         )
         from sntc_tpu.models.summary import ClassificationTrainingSummary
 
-        n_iters = int(res.n_iters)
         model.summary = ClassificationTrainingSummary(
             np.asarray(res.history)[: n_iters + 1], n_iters, model, frame,
             labelCol=self.getLabelCol(), mesh=mesh,

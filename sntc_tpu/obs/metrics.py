@@ -325,6 +325,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "K, the binary and the regression loops 1), same labels as "
         "sntc_boost_rounds_total.",
     ),
+    # -- the perceptron's optimiser on the fit path (models/mlp.py) ---------
+    "sntc_mlp_grad_evals_total": dict(
+        type=COUNTER, labels=(),
+        help="Loss-and-gradient evaluations made by "
+        "MultilayerPerceptronClassifier fits under solver=gd, one a step, "
+        "added once a fit from the optimiser's returned n_iters "
+        "(models/mlp.py, after the mlp.optimize span); an L-BFGS fit's "
+        "line search returns no count and adds nothing.",
+    ),
     # -- collective layer over the mesh substrate (parallel/mesh, r22) ------
     "sntc_collective_dispatches_total": dict(
         type=COUNTER, labels=("op", "axis"),
