@@ -9,6 +9,7 @@ rehearsal of the cell is ``correct`` and the bfloat16 control is refused."""
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -99,7 +100,7 @@ def test_a_large_tol_stops_both_at_the_same_step(bench, frames, tol):
                                rtol=2e-6)
 
 
-def _optimize(dtype, tol=1e-6, max_iter=3, lower=False):
+def _optimize(dtype, tol=1e-6, max_iter=3, lower=False, solver="gd"):
     from sntc_tpu.models.mlp import _mlp_optimize, _n_weights
 
     layers = (6, 5, 3)
@@ -110,7 +111,7 @@ def _optimize(dtype, tol=1e-6, max_iter=3, lower=False):
             jnp.asarray(rng.uniform(-0.5, 0.5, _n_weights(layers)),
                         jnp.float32),
             None, jnp.asarray(max_iter, jnp.int32))
-    kw = dict(layers=layers, max_iter=max_iter, tol=tol, solver="gd",
+    kw = dict(layers=layers, max_iter=max_iter, tol=tol, solver=solver,
               step_size=0.03, compute_dtype=jnp.dtype(dtype))
     return (_mlp_optimize.lower(*args, **kw) if lower
             else _mlp_optimize(*args, **kw))
@@ -138,6 +139,74 @@ def test_float32_products_are_highest_and_bfloat16_ones_are_not(dtype):
     assert len(dots) >= 6  # 2 forward, 2 + 2 backward, 1 final forward
     highest = [ln for ln in dots if "HIGHEST" in ln]
     assert len(highest) == (len(dots) if dtype == "float32" else 0), dots
+
+
+@pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+@pytest.mark.parametrize("solver", ("gd", "l-bfgs"))
+def test_the_loss_picks_labels_without_a_gather(solver, dtype):
+    """The lowered fit holds no gather op, and the compiled one neither a
+    gather nor a scatter instruction: a per-row pick lowers to a serial
+    gather on a TPU, and its gradient to a scatter-add.  (The StableHLO
+    keeps the scalar-indexed ``.at[i].set`` of the loss history and of
+    L-BFGS's pairs as scatters, which XLA compiles to
+    ``dynamic-update-slice``.)  Ops are matched, not substrings: the
+    metadata carries source file names."""
+    low = _optimize(dtype, lower=True, solver=solver)
+    assert not re.search(r"\bstablehlo\.gather\b", low.as_text())
+    compiled = low.compile().as_text()
+    assert not re.search(r"\s(gather|scatter)\(", compiled), [
+        ln for ln in compiled.splitlines() if re.search(r"(gather|scatter)\(", ln)
+    ]
+
+
+def _nll(margins, ys, ws, pick):
+    logp = jax.nn.log_softmax(margins, axis=1)
+    return -jnp.sum(ws * pick(logp, ys)) / jnp.sum(ws)
+
+
+def _gather_pick(logp, ys):
+    return jnp.take_along_axis(logp, ys[:, None], axis=1)[:, 0]
+
+
+@pytest.mark.parametrize("case", ("large_margins", "zero_weights",
+                                  "absent_class"))
+def test_the_masked_pick_is_the_gather_bit_for_bit(case):
+    """Loss and gradient, with respect to the margins and to ``theta``
+    through ``_forward``, equal the ``take_along_axis`` form to the bit."""
+    from sntc_tpu.models.mlp import _forward, _label_log_prob, _n_weights
+
+    layers, n = (6, 5, 15), 4096
+    rng = np.random.default_rng(40)
+    margins = rng.standard_normal((n, 15)).astype(np.float32) * 5
+    ys = rng.integers(0, 15, n)
+    ws = rng.uniform(0.5, 2.0, n).astype(np.float32)
+    if case == "large_margins":
+        hit = rng.random((n, 15)) < 0.1
+        margins[hit] = rng.choice([-80.0, 80.0], int(hit.sum()))
+    elif case == "zero_weights":
+        ws[-300:] = 0.0  # padded rows
+    else:
+        ys[ys == 7] = 8
+    margins, ys, ws = jnp.asarray(margins), jnp.asarray(ys, jnp.int32), \
+        jnp.asarray(ws)
+
+    def by(pick):
+        return jax.value_and_grad(_nll)(margins, ys, ws, pick)
+
+    for got, want in zip(by(_label_log_prob), by(_gather_pick)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    X = jnp.asarray(rng.standard_normal((n, 6)), jnp.float32)
+    theta = jnp.asarray(rng.uniform(-2, 2, _n_weights(layers)), jnp.float32)
+
+    def through_forward(pick):
+        return jax.value_and_grad(
+            lambda t: _nll(_forward(t, X, layers), ys, ws, pick)
+        )(theta)
+
+    for got, want in zip(through_forward(_label_log_prob),
+                         through_forward(_gather_pick)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("program", ("_mlp_margins", "_mlp_predict_fused",

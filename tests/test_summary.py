@@ -1,6 +1,9 @@
 """Training-summary parity (SURVEY.md §5.5) + evaluator Params system +
 tuning-spec persistence (Spark ``CrossValidatorModel.save`` round-trip)."""
 
+import gc
+import weakref
+
 import numpy as np
 import pytest
 
@@ -125,6 +128,28 @@ def test_tree_classifier_summaries(mesh8, binary_frame, multi_frame):
     assert isinstance(s2, BinaryClassificationTrainingSummary)
     assert s2.totalIterations == 5
     assert 0.5 < s2.areaUnderROC <= 1.0
+
+
+@pytest.mark.parametrize("solver", ("l-bfgs", "gd"))
+def test_a_dropped_perceptron_dies_without_the_cyclic_collector(
+    mesh8, multi_frame, solver
+):
+    """The perceptron's training summary keeps a copy of its model, not the
+    model: its ``summary`` closes no cycle, so a model dropped by its
+    caller frees its summary's frame (the scaler's device-resident features
+    among it) at once, not when the cyclic collector next runs."""
+    m = MultilayerPerceptronClassifier(
+        mesh=mesh8, layers=[5, 6, 3], maxIter=5, seed=0, solver=solver
+    ).fit(multi_frame)
+    assert 0.0 < m.summary.accuracy <= 1.0
+    ref = weakref.ref(m)
+    gc.collect()
+    gc.disable()
+    try:
+        del m
+        assert ref() is None
+    finally:
+        gc.enable()
 
 
 def test_model_evaluate(mesh8, binary_frame, multi_frame):
