@@ -34,7 +34,8 @@ _first_fit_claimed = False
 
 
 def _stage_span(op: str, stage, index: int):
-    """``stage.fit`` / ``stage.transform`` around one stage's call."""
+    """``stage.fit`` / ``stage.transform`` / ``stage.fit_transform``
+    around one stage's call."""
     cls = type(stage)
     return span(op, stage=cls.__name__, index=index, module=module_of(cls))
 
@@ -124,6 +125,16 @@ class Estimator(PipelineStage):
     def _fit(self, frame: Frame) -> "Model":
         raise NotImplementedError
 
+    def fit_transform(self, frame: Frame):
+        """``(model, model.transform(frame))``: what ``Pipeline.fit`` needs
+        of an estimator that feeds later stages.  An estimator that can
+        make both from one pass over ``frame`` overrides this;
+        ``Pipeline`` then opens one ``stage.fit_transform`` span for the
+        call where it opens ``stage.fit`` and ``stage.transform`` for the
+        two calls of this default."""
+        model = self.fit(frame)
+        return model, model.transform(frame)
+
 
 class Evaluator(PipelineStage):
     """Metric computer over a predictions Frame (Spark's
@@ -148,7 +159,8 @@ class Pipeline(Estimator):
     Spark semantics (SURVEY.md §3.1): stages before the last estimator are
     applied in order — transformers transform the running frame eagerly, each
     estimator is fit on the running frame and its fitted model then transforms
-    the frame for downstream stages.
+    the frame for downstream stages (one ``fit_transform`` call where the
+    estimator overrides it).
     """
 
     stages = Param("pipeline stages (Transformers and Estimators), applied in order")
@@ -181,13 +193,19 @@ class Pipeline(Estimator):
                   module=_MODULE):
             for i, stage in enumerate(stages):
                 model = stage
-                if isinstance(stage, Estimator):
-                    with _stage_span("stage.fit", stage, i):
-                        model = stage.fit(current)
+                if (i < last_est and isinstance(stage, Estimator)
+                        and type(stage).fit_transform
+                        is not Estimator.fit_transform):
+                    with _stage_span("stage.fit_transform", stage, i):
+                        model, current = stage.fit_transform(current)
+                else:
+                    if isinstance(stage, Estimator):
+                        with _stage_span("stage.fit", stage, i):
+                            model = stage.fit(current)
+                    if i < last_est:
+                        with _stage_span("stage.transform", model, i):
+                            current = model.transform(current)
                 fitted.append(model)
-                if i < last_est:
-                    with _stage_span("stage.transform", model, i):
-                        current = model.transform(current)
         if first:
             set_gauge("sntc_pipeline_first_fit_seconds",
                       time.perf_counter() - t0)

@@ -84,7 +84,7 @@ class RFormula(_RfParams, Estimator):
         # per-column encodings: numeric passthrough, string -> ordered
         # category list — REUSING StringIndexer's frequencyDesc ordering
         # (one label-ordering contract in the codebase, not two)
-        from sntc_tpu.feature.string_indexer import _order_labels
+        from sntc_tpu.feature.string_indexer import _learn_labels
 
         encodings: Dict[str, List[str]] = {}
 
@@ -93,7 +93,7 @@ class RFormula(_RfParams, Estimator):
                 return
             arr = frame[col]
             if arr.dtype.kind in "OUS":
-                encodings[col] = _order_labels(arr, "frequencyDesc")
+                encodings[col] = _learn_labels(arr, "frequencyDesc")[0]
 
         for t in terms:
             for c in (t.split(":") if ":" in t else [t]):

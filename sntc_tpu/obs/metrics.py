@@ -312,6 +312,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "result): site=assemble.stack | select.take; a serving "
         "micro-batch adds 0.",
     ),
+    "sntc_feature_index_passes_total": dict(
+        type=COUNTER, labels=("site",),
+        help="Hash passes a StringIndexer made over a column "
+        "(feature/string_indexer.py): site=fit (a bare fit), transform "
+        "(a fitted model indexing a frame), fit_transform (a pipeline "
+        "fit: the vocabulary and the index column from one pass).  A "
+        "fit of the benchmark's cells adds fit_transform 1 and nothing "
+        "else.",
+    ),
     # -- boosting rounds on the fit path (models/tree/gbt*.py) ---------------
     "sntc_boost_rounds_total": dict(
         type=COUNTER, labels=("estimator",),

@@ -1,12 +1,14 @@
 import numpy as np
 import pytest
 
+from sntc_tpu.core.base import Estimator, Model, Pipeline
 from sntc_tpu.core.frame import Frame
 from sntc_tpu.feature import (
     ChiSqSelector,
     IndexToString,
     StandardScaler,
     StringIndexer,
+    StringIndexerModel,
     VectorAssembler,
 )
 
@@ -51,6 +53,218 @@ def test_index_to_string_roundtrip():
     out = model.transform(f)
     back = IndexToString(inputCol="idx", outputCol="orig", labels=model.labels).transform(out)
     assert list(back["orig"]) == list(f["label"])
+
+
+# ---------------- StringIndexer inside a Pipeline fit ----------------
+
+
+class _Recorder(Estimator):
+    """A stub estimator that records what it is asked to do."""
+
+    def __init__(self, log=None, **kwargs):
+        super().__init__(**kwargs)
+        self.log = [] if log is None else log
+
+    def _fit(self, frame):
+        self.log.append(("fit", self, frame))
+        return _RecorderModel(self.log, self)
+
+
+class _RecorderModel(Model):
+    def __init__(self, log, owner, **kwargs):
+        super().__init__(**kwargs)
+        self.log, self.owner = log, owner
+
+    def transform(self, frame):
+        self.log.append(("transform", self.owner, frame))
+        return frame.with_column("seen", np.ones(frame.num_rows))
+
+
+def _index_passes(site):
+    from sntc_tpu.obs import registry
+
+    return registry().get("sntc_feature_index_passes_total", site=site) or 0
+
+
+def _reference_index(values, order):
+    """The indexer's contract by hand: a label is ``str()`` of a value,
+    ordered by ``order`` with ties broken by the string ascending."""
+    from collections import Counter
+
+    counts = Counter(str(v) for v in values)
+    key = {
+        "frequencyDesc": lambda kv: (-kv[1], kv[0]),
+        "frequencyAsc": lambda kv: (kv[1], kv[0]),
+        "alphabetAsc": lambda kv: kv[0],
+        "alphabetDesc": lambda kv: kv[0],
+    }[order]
+    labels = [l for l, _ in sorted(counts.items(), key=key,
+                                   reverse=order == "alphabetDesc")]
+    pos = {l: float(i) for i, l in enumerate(labels)}
+    return labels, np.array([pos[str(v)] for v in values], np.float64)
+
+
+_S = ["b", "a", "c", "b", "a", "d", "c", "e"]  # a, b, c tie at 2
+_LABEL_COLUMNS = {
+    "object": np.array(_S, dtype=object),
+    "object_untied": np.array(_S + ["b", "b", "c"], dtype=object),
+    "object_none": np.array(_S + [None, "a", None], dtype=object),
+    "object_nan": np.array(_S + [np.nan, float("nan"), "e"], dtype=object),
+    "object_none_nan": np.array(_S + [None, np.nan, None], dtype=object),
+    "object_none_nan_and_str_none": np.array(
+        _S + [None, "None", np.nan, "nan", None], dtype=object),
+    "int64": np.array([3, 1, 2, 3, 1, 10, 2, 7], dtype=np.int64),
+    "object_int": np.array([3, 1, 2, 3, 1, 10, 2, 7, None], dtype=object),
+    "object_float": np.array([3.0, 1.5, 2.0, 3.0, 1.5, np.nan, 2.0, 7.25],
+                             dtype=object),
+    "float64": np.array([3.0, 1.5, 2.0, 3.0, 1.5, 10.0, 2.0, 7.25]),
+    "float64_nan": np.array([3.0, np.nan, 2.0, 3.0, np.nan, 0.5, 2.0, 7.0]),
+}
+
+
+@pytest.mark.parametrize("multi", [False, True], ids=["single", "multi"])
+@pytest.mark.parametrize("column", sorted(_LABEL_COLUMNS))
+@pytest.mark.parametrize(
+    "order", ["frequencyDesc", "frequencyAsc", "alphabetDesc", "alphabetAsc"]
+)
+def test_string_indexer_pipeline_fit_is_fit_then_transform(order, column,
+                                                           multi):
+    """A pipeline fit indexes its label column from one hash pass; the
+    vocabulary and the column are those of ``fit`` then ``transform``."""
+    values = _LABEL_COLUMNS[column]
+    other = np.array(["x", "y", "x", "z", "y", "x", "z", "x"] * 2,
+                     dtype=object)[: len(values)]
+    f = Frame({"lab": values, "other": other, "v": np.arange(len(values))})
+    if multi:
+        cols = dict(inputCols=["lab", "other"], outputCols=["i0", "i1"])
+        ins, outs = ["lab", "other"], ["i0", "i1"]
+    else:
+        cols = dict(inputCol="lab", outputCol="i0")
+        ins, outs = ["lab"], ["i0"]
+    separate = StringIndexer(stringOrderType=order, **cols).fit(f)
+    expected = separate.transform(f)
+
+    before = {s: _index_passes(s) for s in ("fit", "transform",
+                                            "fit_transform")}
+    log = []
+    model = Pipeline(stages=[StringIndexer(stringOrderType=order, **cols),
+                             _Recorder(log)]).fit(f)
+    passes = {s: _index_passes(s) - n for s, n in before.items()}
+    assert passes == {"fit": 0, "transform": 0, "fit_transform": len(ins)}
+
+    fitted = model.getStages()[0]
+    assert isinstance(fitted, StringIndexerModel)
+    assert fitted.labelsArray == separate.labelsArray
+    assert fitted.getStringOrderType() == order
+    ((what, _, seen),) = log
+    assert what == "fit"
+    np.testing.assert_array_equal(seen["v"], f["v"])  # row order kept
+    for c, o, labels in zip(ins, outs, fitted.labelsArray):
+        ref_labels, ref_out = _reference_index(f[c], order)
+        assert labels == ref_labels
+        assert seen[o].dtype == np.float64
+        np.testing.assert_array_equal(seen[o], expected[o])
+        np.testing.assert_array_equal(seen[o], ref_out)
+
+
+@pytest.mark.parametrize("mode", ["error", "skip", "keep"])
+def test_string_indexer_pipeline_fit_under_handle_invalid(mode):
+    """On the frame it was fitted on no label is unseen: every mode
+    gives every row its index, as ``fit`` then ``transform`` does."""
+    f = Frame({"lab": _LABEL_COLUMNS["object_none_nan_and_str_none"]})
+    stage = StringIndexer(inputCol="lab", outputCol="i", handleInvalid=mode)
+    expected = stage.fit(f).transform(f)
+    log = []
+    model = Pipeline(stages=[stage, _Recorder(log)]).fit(f)
+    seen = log[0][2]
+    assert seen.num_rows == f.num_rows
+    np.testing.assert_array_equal(seen["i"], expected["i"])
+    assert model.getStages()[0].getHandleInvalid() == mode
+    # the fitted model still refuses, drops or keeps an unseen label
+    unseen = Frame({"lab": np.array(["a", "zz"], dtype=object)})
+    fitted = model.getStages()[0]
+    if mode == "error":
+        with pytest.raises(ValueError, match="unseen labels \\['zz'\\]"):
+            fitted.transform(unseen)
+    else:
+        out = fitted.transform(unseen)["i"]
+        n = float(len(fitted.labels))
+        assert out.tolist() == ([fitted.labels.index("a")] if mode == "skip"
+                                else [fitted.labels.index("a"), n])
+
+
+@pytest.mark.parametrize("values", [
+    np.array([1, 1.0, "1", True, 1, 2], dtype=object),
+    np.array([0.0, -0.0, 0.0, 1.0]),
+    np.array([0.0, -0.0, 0.0, 1.0, None], dtype=object),
+], ids=["equal_python_numbers", "signed_zeros", "object_signed_zeros"])
+def test_string_indexer_labels_are_what_str_prints(values):
+    """Values that compare equal but print differently are two labels,
+    in the fit, the fused fit and a fitted model's transform alike."""
+    f = Frame({"lab": values})
+    ref_labels, ref_out = _reference_index(values, "frequencyDesc")
+    model, out = StringIndexer(inputCol="lab", outputCol="i").fit_transform(f)
+    assert model.labels == ref_labels
+    np.testing.assert_array_equal(out["i"], ref_out)
+    again = StringIndexer(inputCol="lab", outputCol="i").fit(f)
+    assert again.labels == ref_labels
+    np.testing.assert_array_equal(again.transform(f)["i"], ref_out)
+
+
+def test_estimator_default_fit_transform_is_fit_then_transform():
+    """An estimator without its own ``fit_transform`` is fitted, then its
+    model transforms the same frame; the last estimator is only fitted;
+    the spans are ``stage.fit`` and ``stage.transform`` as before."""
+    from sntc_tpu.obs import disable_tracing, enable_tracing
+
+    f = Frame({"x": np.arange(4.0)})
+    log = []
+    first, last = _Recorder(log), _Recorder(log)
+    model, out = first.fit_transform(f)
+    assert [(w, o) for w, o, _ in log] == [("fit", first), ("transform", first)]
+    assert log[0][2] is f and log[1][2] is f
+    assert isinstance(model, _RecorderModel) and "seen" in out.columns
+
+    log.clear()
+    t = enable_tracing(capacity=256)
+    t.clear()
+    try:
+        Pipeline(stages=[first, last]).fit(f)
+        spans = t.spans()
+    finally:
+        disable_tracing()
+    assert [(w, o) for w, o, _ in log] == [
+        ("fit", first), ("transform", first), ("fit", last)]
+    assert log[0][2] is f and log[1][2] is f
+    assert "seen" in log[2][2].columns
+    assert [(s["name"], s["attrs"]["stage"], s["attrs"]["index"])
+            for s in sorted(spans, key=lambda s: s["t0"])
+            if s["name"].startswith("stage.")] == [
+        ("stage.fit", "_Recorder", 0),
+        ("stage.transform", "_RecorderModel", 0),
+        ("stage.fit", "_Recorder", 1),
+    ]
+
+
+def test_string_indexer_pipeline_fit_is_one_span():
+    from sntc_tpu.obs import disable_tracing, enable_tracing, module_of
+
+    f = _label_frame()
+    t = enable_tracing(capacity=256)
+    t.clear()
+    try:
+        Pipeline(stages=[StringIndexer(inputCol="label", outputCol="i"),
+                         _Recorder()]).fit(f)
+        spans = t.spans()
+    finally:
+        disable_tracing()
+    assert [(s["name"], s["attrs"]["stage"], s["attrs"]["index"],
+             s["attrs"]["module"])
+            for s in sorted(spans, key=lambda s: s["t0"])
+            if s["name"].startswith("stage.")] == [
+        ("stage.fit_transform", "StringIndexer", 0, "feature"),
+        ("stage.fit", "_Recorder", 1, module_of(_Recorder)),
+    ]
 
 
 # ---------------- VectorAssembler ----------------
